@@ -172,7 +172,6 @@ def relay_phase_times(state, cfg, params, starts, *, seed: int = 0,
     exchange_ms is the number that says how much a round COULD gain
     from overlapping them (perfect overlap hides min(seg, exch)); the
     measured ``round_ms`` ratio says how much it DID."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core.backend import get_backend
     from repro.distributed.relay import relay_view, slot_count
@@ -208,13 +207,13 @@ def relay_phase_times(state, cfg, params, starts, *, seed: int = 0,
 
     sspec = jax.tree.map(lambda _: P("data"), state,
                          is_leaf=lambda x: hasattr(x, "ndim"))
-    seg = jax.jit(shard_map(seg_local, mesh=mesh,
-                            in_specs=(sspec, P()), out_specs=P("data"),
-                            check_rep=False))
-    exch = jax.jit(shard_map(exch_local, mesh=mesh,
-                             in_specs=(P("data"), P("data")),
-                             out_specs=(P("data"), P("data"), P()),
-                             check_rep=False))
+    seg = jax.jit(jax.shard_map(seg_local, mesh=mesh,
+                                in_specs=(sspec, P()), out_specs=P("data"),
+                                check_vma=False))
+    exch = jax.jit(jax.shard_map(exch_local, mesh=mesh,
+                                 in_specs=(P("data"), P("data")),
+                                 out_specs=(P("data"), P("data"), P()),
+                                 check_vma=False))
 
     sd = seed_from_key(jax.random.key(seed))
     wpay = jnp.stack([starts % cfg.num_vertices,

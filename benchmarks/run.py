@@ -18,6 +18,7 @@ import json
 import os
 import time
 import traceback
+from pathlib import Path
 
 from benchmarks import (bench_batched, bench_complexity, bench_fp_bias,
                         bench_group_adapt, bench_piecewise, bench_serving,
@@ -280,6 +281,8 @@ def main() -> None:
                          "be diffed against a full-scale snapshot")
     args = ap.parse_args()
     only = [s for s in args.only.split(",") if s]
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache(Path(__file__).resolve().parent.parent)
     from benchmarks import common as _common
     _common.set_mode(compiled=args.compiled, micro=args.micro)
 

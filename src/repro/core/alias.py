@@ -2,7 +2,7 @@
 
 BINGO keeps one *inter-group* alias table per vertex over its K radix groups
 (+1 decimal group in fp mode).  K <= 33, so a table row fits in a vector
-register; construction is a K-step masked small/large pairing, vmapped over
+register; construction is a K-step masked small/large pairing, row-parallel over
 vertices.  The same code builds the O(d)-entry tables of the KnightKing-style
 alias *baseline* (core/baselines.py).
 
@@ -18,6 +18,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.lanes import first_lane
+
 __all__ = ["AliasTable", "build_alias", "sample_alias", "alias_probs"]
 
 
@@ -30,11 +32,11 @@ def _row_total(w: jax.Array) -> jax.Array:
     """Row sum as explicit left-to-right lane adds (shape ``(..., n)``).
 
     ``jnp.sum``'s reduction order is implementation-defined and changes
-    with the surrounding fusion context — the update megakernel
-    (``kernels/update_fused.py``) rebuilds alias rows *inside* a Pallas
-    body and must reproduce this construction bit-for-bit, so both sides
-    spell the order out.  n <= 33 (the K+1 inter-group lanes), so the
-    unrolled chain is trivial.
+    with the surrounding fusion context — alias rows must come out bit
+    for bit the same from every program that builds them (the update
+    paths, ``from_edges``, each shard of a mesh), so the order is spelled
+    out.  n <= 33 (the K+1 inter-group lanes), so the unrolled chain is
+    trivial.
     """
     total = w[..., 0]
     for j in range(1, w.shape[-1]):
@@ -42,32 +44,76 @@ def _row_total(w: jax.Array) -> jax.Array:
     return total
 
 
-def _build_row(w: jax.Array) -> AliasTable:
-    """Vose's algorithm on one weight row ``w`` (n,) -> alias table row."""
-    n = w.shape[-1]
-    total = _row_total(w)
-    scaled = jnp.where(total > 0, w * n / jnp.maximum(total, 1e-30), 0.0)
-    prob0 = jnp.ones((n,), jnp.float32)
-    alias0 = jnp.arange(n, dtype=jnp.int32)
-    done0 = jnp.zeros((n,), bool)
+def _div_rn(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a / b`` for float32 ``a >= 0``, normal ``b > 0`` and a finite
+    quotient, rounded to nearest even from integer ops alone.
+
+    The TPU's float division is not correctly rounded (alias entries
+    taken straight from the quotient came out one ulp off the CPU's), so
+    the same weights gave different alias rows on different backends.
+    This long division of the 24-bit significands gives IEEE's quotient
+    on every backend; results below the normal range flush to zero, as
+    XLA's arithmetic does.
+    """
+    i32 = jnp.int32
+    ai = jax.lax.bitcast_convert_type(a, i32)
+    bi = jax.lax.bitcast_convert_type(b, i32)
+    ea, eb = ai >> 23, (bi >> 23) & 0xFF
+    ma = (ai & 0x7FFFFF) | 0x800000
+    mb = (bi & 0x7FFFFF) | 0x800000
+    lt = (ma < mb).astype(i32)          # put ma/mb in [1, 2)
+    r = ma << lt
+    e = ea - eb + 127 - lt              # biased exponent of the quotient
+    q = jnp.zeros_like(ai)
+    for _ in range(25):                 # 24 significand bits + round bit
+        bit = (r >= mb).astype(i32)
+        q = (q << 1) | bit
+        r = (r - bit * mb) << 1
+    up = (q & 1) & ((r != 0) | ((q >> 1) & 1)).astype(i32)
+    q = (q >> 1) + up
+    carry = q >> 24                     # rounding overflowed to 2^24
+    q, e = q >> carry, e + carry
+    out = jax.lax.bitcast_convert_type((e << 23) | (q & 0x7FFFFF),
+                                       jnp.float32)
+    return jnp.where((ea > 0) & (e > 0), out, 0.0)
+
+
+def _build_rows(w: jax.Array) -> AliasTable:
+    """Vose's algorithm on weight rows ``w`` (N, n) -> alias table rows.
+
+    Row-parallel: each of the n steps retires the first "small" entry of
+    every row against its first "large" one, with lane selects and
+    one-hot updates only.  The per-row ``argmax`` + gather/scatter form
+    of the same loop came out wrong on the TPU — rows stopped pairing
+    early, and differently for different row counts — while this form
+    gives the same bits on every backend and at every batch size.
+    """
+    N, n = w.shape
+    total = _row_total(w)[:, None]
+    scaled = jnp.where(total > 0, _div_rn(w * n, jnp.maximum(total, 1e-30)),
+                       0.0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (N, n), 1)
+    prob0 = jnp.ones((N, n), jnp.float32)
+    done0 = jnp.zeros((N, n), bool)
 
     def body(_, carry):
         scaled, prob, alias, done = carry
         small = (~done) & (scaled < 1.0)
         large = (~done) & (scaled >= 1.0)
-        do = jnp.any(small) & jnp.any(large)
-        s = jnp.argmax(small)
-        l = jnp.argmax(large)
+        do = (jnp.any(small, -1) & jnp.any(large, -1))[:, None]
+        at_s = do & (col == first_lane(small))
+        at_l = do & (col == first_lane(large))
+        # the one selected lane plus zeros: exact in any order
+        sval = jnp.sum(jnp.where(at_s, scaled, 0.0), -1, keepdims=True)
         # retire small s against large l
-        prob = jnp.where(do, prob.at[s].set(scaled[s]), prob)
-        alias = jnp.where(do, alias.at[s].set(l), alias)
-        scaled = jnp.where(do, scaled.at[l].add(scaled[s] - 1.0), scaled)
-        done = jnp.where(do, done.at[s].set(True), done)
+        prob = jnp.where(at_s, sval, prob)
+        alias = jnp.where(at_s, first_lane(large), alias)
+        scaled = jnp.where(at_l, scaled + (sval - 1.0), scaled)
+        done = done | at_s
         return scaled, prob, alias, done
 
-    scaled, prob, alias, done = jax.lax.fori_loop(
-        0, n, body, (scaled, prob0, alias0, done0)
-    )
+    _, prob, alias, _ = jax.lax.fori_loop(
+        0, n, body, (scaled, prob0, col, done0))
     # Entries never retired as "small" (the final larges / near-1 smalls)
     # keep prob=1, alias=self — the textbook termination.  Zero-total rows
     # (empty vertices) degrade to prob=1 uniform; callers must not sample
@@ -78,8 +124,7 @@ def _build_row(w: jax.Array) -> AliasTable:
 def build_alias(w: jax.Array) -> AliasTable:
     """Build alias tables for a batch of weight rows ``(..., n)``."""
     w = jnp.asarray(w, jnp.float32)
-    flat = w.reshape((-1, w.shape[-1]))
-    t = jax.vmap(_build_row)(flat)
+    t = _build_rows(w.reshape((-1, w.shape[-1])))
     return AliasTable(
         t.prob.reshape(w.shape), t.alias.reshape(w.shape)
     )

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.core import radix
 from repro.core.alias import AliasTable, build_alias
+from repro.core.lanes import lane_cumsum
 
 __all__ = [
     "EMPTY", "DENSE", "ONE", "SPARSE", "REGULAR",
@@ -112,10 +113,14 @@ class BingoConfig:
         Adaptive mode: any group larger than ``alpha * deg`` is DENSE and
         unmaterialized, so materialized groups never exceed
         ``ceil(alpha * C) + 1`` slots (DESIGN.md §2) — a real >2x saving on
-        the dominant intra-group storage, mirroring paper Fig. 11.
+        the dominant intra-group storage, mirroring paper Fig. 11.  The
+        bound is rounded up to a whole 128-lane tile: the TPU stores a
+        (K, Cg) row in such tiles anyway, and the update kernel's row
+        DMA must cover whole tiles.
         """
         if self.adaptive:
-            return min(self.capacity, int(math.ceil(self.alpha * self.capacity)) + 1)
+            need = int(math.ceil(self.alpha * self.capacity)) + 1
+            return min(self.capacity, -(-need // 128) * 128)
         return self.capacity
 
     @property
@@ -146,12 +151,15 @@ class BingoState(NamedTuple):
         return self.nbr.shape[0]
 
 
-def classify(gsize, deg, cfg: BingoConfig):
-    """Eq. 9 group classification, vectorized over ``(..., K)`` sizes."""
+def classify(gsize, deg, cfg: BingoConfig, dtype=jnp.int8):
+    """Eq. 9 group classification, vectorized over ``(..., K)`` sizes.
+
+    ``dtype`` is the code type: int8 in the state; the update kernel asks
+    for int32, since Mosaic cannot narrow vectors to 8 bits."""
     deg = deg[..., None].astype(jnp.float32)
     g = gsize.astype(jnp.float32)
     if not cfg.adaptive:
-        return jnp.where(gsize > 0, REGULAR, EMPTY).astype(jnp.int8)
+        return jnp.where(gsize > 0, REGULAR, EMPTY).astype(dtype)
     t = jnp.where(
         g > cfg.alpha * deg,  # |G|/d > alpha (paper: alpha% = 40%)
         DENSE,
@@ -161,7 +169,7 @@ def classify(gsize, deg, cfg: BingoConfig):
             jnp.where(g < cfg.beta * deg, SPARSE, REGULAR),
         ),
     )
-    return jnp.where(gsize == 0, EMPTY, t).astype(jnp.int8)
+    return jnp.where(gsize == 0, EMPTY, t).astype(dtype)
 
 
 def build_vertex_groups(cfg: BingoConfig, bias_row, frac_row, deg):
@@ -198,7 +206,13 @@ def build_vertex_groups(cfg: BingoConfig, bias_row, frac_row, deg):
     else:
         ginv = jnp.where(member, pos, -1).T.astype(jnp.int32)  # (K, C)
 
-    wdec = jnp.sum(jnp.where(valid, frac_row, 0.0), dtype=jnp.float32)
+    # Decimal-group weight: the last lane of the log-step prefix sum, so
+    # the update kernel (which adds in the same order) matches it bit for
+    # bit.  Integer-bias states keep every frac at 0.
+    if cfg.fp_bias:
+        wdec = lane_cumsum(jnp.where(valid, frac_row, 0.0), jnp.roll)[-1]
+    else:
+        wdec = jnp.zeros((), jnp.float32)
     return gmem, ginv, gsize, digitsum, gtype, wdec
 
 
@@ -254,11 +268,43 @@ def _scatter_adjacency(cfg: BingoConfig, src, dst, w_int, w_frac):
     return nbr, bias, frac, deg
 
 
-def from_edges(cfg: BingoConfig, src, dst, bias) -> BingoState:
+def _group_rows(cfg: BingoConfig, bias, frac, deg, chunk: int):
+    """``build_vertex_groups`` over every row of ``(N, C)`` tables.
+
+    Above ``chunk`` rows the build runs as a ``lax.map`` over
+    ``chunk``-row tiles (the row axis zero-padded to a whole number of
+    tiles), so the ``(N, C, K)`` digit intermediates never exist for more
+    than one tile at a time.  Each row's output depends on that row alone,
+    so the tiling never changes a bit of it.
+    """
+    N = deg.shape[0]
+
+    def build(args):
+        return jax.vmap(lambda b, f, d: build_vertex_groups(cfg, b, f, d)
+                        )(*args)
+
+    if N <= chunk:
+        return build((bias, frac, deg))
+    n = -(-N // chunk)
+    pad = n * chunk - N
+
+    def tiles(x):
+        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+        return x.reshape((n, chunk) + x.shape[1:])
+
+    outs = jax.lax.map(build, (tiles(bias), tiles(frac), tiles(deg)))
+    return jax.tree.map(lambda t: t.reshape((n * chunk,) + t.shape[2:])[:N],
+                        outs)
+
+
+def from_edges(cfg: BingoConfig, src, dst, bias, *,
+               chunk: int = 4096) -> BingoState:
     """Construct the full BINGO sampling space from an edge list.
 
     ``bias`` is int for integer mode; float for fp mode (λ-scaled per §4.3).
-    Fully vectorized — no per-edge host loop.
+    Fully vectorized — no per-edge host loop; the group rows are built in
+    ``chunk``-row tiles (``_group_rows``) so a one-chip-sized graph builds
+    on the chip without materializing ``(V, C, K)`` intermediates.
     """
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
@@ -268,9 +314,8 @@ def from_edges(cfg: BingoConfig, src, dst, bias) -> BingoState:
         w_int = jnp.asarray(bias, jnp.int32)
         w_frac = jnp.zeros_like(src, dtype=jnp.float32)
     nbr, b, f, deg = _scatter_adjacency(cfg, src, dst, w_int, w_frac)
-    gmem, ginv, gsize, digitsum, gtype, wdec = jax.vmap(
-        lambda br, fr, dg: build_vertex_groups(cfg, br, fr, dg)
-    )(b, f, deg)
+    gmem, ginv, gsize, digitsum, gtype, wdec = _group_rows(cfg, b, f, deg,
+                                                           chunk)
     itable = build_itable_rows(cfg, digitsum, wdec)
     return BingoState(nbr, b, f, deg, gmem, ginv, gsize, digitsum, wdec,
                       gtype, itable)
@@ -346,29 +391,13 @@ def regrow_state(state: BingoState, cfg: BingoConfig,
                                   cfg.adaptive, cfg.fp_bias):
         raise ValueError("regrow may only change capacity; every other "
                          "sampling-space field must match")
-    V = cfg.num_vertices
     pad = ((0, 0), (0, C2 - C))
     nbr = jnp.pad(state.nbr, pad, constant_values=-1)
     bias = jnp.pad(state.bias, pad, constant_values=0)
     frac = jnp.pad(state.frac, pad, constant_values=0.0)
     deg = state.deg
-
-    def build_rows(args):
-        br, fr, dg = args
-        return jax.vmap(
-            lambda b, f, d: build_vertex_groups(cfg_next, b, f, d)
-        )(br, fr, dg)
-
-    if V > chunk and V % chunk == 0:
-        shape = (V // chunk, chunk)
-        outs = jax.lax.map(build_rows, (bias.reshape(shape + (C2,)),
-                                        frac.reshape(shape + (C2,)),
-                                        deg.reshape(shape)))
-        gmem, ginv, gsize, digitsum, gtype, wdec = jax.tree.map(
-            lambda t: t.reshape((V,) + t.shape[2:]), outs)
-    else:
-        gmem, ginv, gsize, digitsum, gtype, wdec = build_rows(
-            (bias, frac, deg))
+    gmem, ginv, gsize, digitsum, gtype, wdec = _group_rows(
+        cfg_next, bias, frac, deg, chunk)
     itable = build_itable_rows(cfg_next, digitsum, wdec)
     return BingoState(nbr, bias, frac, deg, gmem, ginv, gsize, digitsum,
                       wdec, gtype, itable)

@@ -17,6 +17,7 @@ second alias hierarchy (documented in DESIGN.md).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = [
@@ -55,10 +56,13 @@ def group_weights(digitsum, base_log2: int = 1):
     """W(p_k) (Eq. 4) from per-group digit sums: ``digitsum[k] * B^k``.
 
     Returned as float32 — these feed the inter-group alias table.  ``B^k``
-    is exact in f32 for the bases/bit-widths we use (B^k <= 2^31).
+    is built from its exponent bits, so it is exact on every backend
+    (``exp2`` is not, on some) for the bases/bit-widths we use
+    (B^k <= 2^31).
     """
-    num_k = digitsum.shape[-1]
-    scale = jnp.exp2(jnp.arange(num_k, dtype=jnp.float32) * base_log2)
+    k = jax.lax.broadcasted_iota(jnp.int32, digitsum.shape, digitsum.ndim - 1)
+    scale = jax.lax.bitcast_convert_type((127 + k * base_log2) << 23,
+                                         jnp.float32)
     return digitsum.astype(jnp.float32) * scale
 
 

@@ -74,6 +74,21 @@ def _is_neighbor(state: BingoState, cfg: BingoConfig, src, cand):
     return jnp.any((row == cand[:, None]) & valid, axis=-1)
 
 
+def _row_neighbors(state: BingoState, cfg: BingoConfig, src, cands):
+    """``cands[b, j] ∈ N(src[b])`` for (B, C) candidate rows at once.
+
+    A binary search in ``src``'s sorted live row — O(B·C·log C), where
+    ``_is_neighbor`` per candidate column would build a (B, C, C)
+    compare.  Dead slots sort as INT32_MIN, which no candidate equals.
+    """
+    C = cfg.capacity
+    row = jnp.sort(jnp.where(
+        jnp.arange(C, dtype=jnp.int32)[None, :] < state.deg[src][:, None],
+        state.nbr[src], jnp.iinfo(jnp.int32).min), axis=-1)
+    at = jax.vmap(jnp.searchsorted)(row, cands)
+    return jnp.take_along_axis(row, jnp.minimum(at, C - 1), axis=-1) == cands
+
+
 def _n2v_factor(state, cfg, prev, cand, p, q):
     dist0 = cand == prev
     dist1 = _is_neighbor(state, cfg, prev, cand)
@@ -118,9 +133,7 @@ def _n2v_accept(state, cfg, prev, cur, has_prev, key, params, bk=None):
         w = state.bias[cur].astype(jnp.float32) + state.frac[cur]
         nbrs = state.nbr[cur]                               # (B, C)
         d0 = nbrs == prev[:, None]
-        d1 = jax.vmap(lambda pv, cd: _is_neighbor(state, cfg,
-                                                  jnp.broadcast_to(pv, cd.shape), cd)
-                      )(prev, nbrs)
+        d1 = _row_neighbors(state, cfg, prev, nbrs)
         f = jnp.where(d0, 1.0 / params.p, jnp.where(d1, 1.0, 1.0 / params.q))
         f = jnp.where(has_prev[:, None], f, 1.0)
         w = jnp.where(valid, w * f, 0.0)

@@ -648,7 +648,6 @@ def make_relay(bk, cfg, params, mesh, *, mailbox_cap: int | None = None,
     benchmarks and tests, so the divisibility validation and spec
     plumbing live in exactly one place.
     """
-    from jax.experimental.shard_map import shard_map
 
     axes = tuple(mesh.axis_names)
     waxes = _astuple(walker_axes)
@@ -700,8 +699,8 @@ def make_relay(bk, cfg, params, mesh, *, mailbox_cap: int | None = None,
             + ((P(),) if diagnostics else ()) \
             + ((P(), P(), P()) if census else ()) \
             + ((P(),) if with_pending else ())
-        f = shard_map(local, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+        f = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False)
         args = (state, walkers, seed) + (() if u is None else (u,))
         out = f(*args)
         if with_pending:

@@ -158,9 +158,9 @@ def make_walk_step(sample_local, shard_size: int, num_shards: int,
             payload, shard_size, num_shards, axis)
         return arrived, leftover, overflow
 
-    return jax.experimental.shard_map.shard_map(
+    return jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=(P(axis), P(axis), P()),
-        check_rep=False,
+        check_vma=False,
     )

@@ -6,8 +6,8 @@ claim).  Batched updates rebuild thousands of rows at once.
 
 TPU adaptation: one grid step owns a (Vt, K) weight tile in VMEM and runs
 Vose's small/large pairing as a K-iteration ``fori_loop`` where each
-iteration retires one "small" entry *per row in parallel* (lane-wise
-argmax + masked scatter across the Vt rows).  K <= 33, so the whole loop
+iteration retires one "small" entry *per row in parallel* (first set
+lane + masked scatter across the Vt rows).  K <= 33, so the whole loop
 is K VPU passes over a resident tile — no HBM traffic between steps.
 
 VMEM budget: 5 live (Vt, K) f32/i32 tiles ≈ 20·Vt·K B; Vt=512, K=33 is
@@ -21,6 +21,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.core.lanes import first_lane
 
 __all__ = ["alias_build_pallas"]
 
@@ -41,8 +43,8 @@ def _kernel(w_ref, prob_ref, alias_ref):
         small = (~done) & (scaled < 1.0)
         large = (~done) & (scaled >= 1.0)
         do = (small.any(-1) & large.any(-1))[:, None]     # (Vt, 1)
-        s = jnp.argmax(small, axis=-1)[:, None]           # (Vt, 1)
-        l = jnp.argmax(large, axis=-1)[:, None]
+        s = first_lane(small)                             # (Vt, 1)
+        l = first_lane(large)       # both unused where ``do`` is False
         at_s = col == s
         at_l = col == l
         sval = jnp.sum(jnp.where(at_s, scaled, 0.0), -1, keepdims=True)

@@ -5,6 +5,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.lanes import its_pick
+
 __all__ = ["walk_sample_ref", "walk_sample_uniform_ref", "walk_fused_ref",
            "walk_segment_ref", "hash_uniforms_ref",
            "alias_build_ref", "radix_hist_ref", "attention_ref"]
@@ -32,12 +34,10 @@ def alias_build_ref(w):
 
 
 def _its_pick_ref(w, x01):
-    """Exact ITS lane pass (mirrors walk_sample.py:_its_pick, row form)."""
-    c = jnp.cumsum(w, axis=-1)
-    total = c[:, -1:]
-    x = x01[:, None] * total
-    idx = jnp.sum((c <= x).astype(jnp.int32), axis=-1)
-    return jnp.minimum(idx, w.shape[-1] - 1)
+    """Exact ITS lane pass — the kernel's ``lanes.its_pick`` with
+    ``jnp.roll`` for the rotation, so float prefixes add in the same
+    order as in the kernel (row form: ``x01`` (B,), returns (B,))."""
+    return its_pick(w, x01[:, None], jnp.roll)[:, 0]
 
 
 def walk_sample_ref(prob, alias, bias, nbr, deg, u0, u1, u2,

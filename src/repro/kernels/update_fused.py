@@ -16,32 +16,38 @@ Staging per affected-vertex tile of Rt rows (paper Fig. 10(a)):
     "CPU-side ordering becomes an on-device sort": inserts sorted by
     vertex with segmented ranks, deletes lexsorted by (vertex, value)
     with duplicate ranks, both scattered into dense per-affected-row
-    *patches* (value + target-slot masks).  Ordering only — no
+    *patches* (values at their target slots).  Ordering only — no
     ``BingoState`` tensor is touched outside the kernel;
-  * **inserts** — conflict-free append: one lane select places each
-    patch value at its precomputed slot ``deg + rank`` (the scatter the
+  * **inserts** — conflict-free append: one lane select places the patch
+    values on the lanes ``[deg, deg + inserted)`` (the scatter the
     reference does in HBM happens on the VMEM-resident row);
   * **deletes** — in-kernel locate (the (rank+1)-th occurrence of each
-    doomed value, a masked lane cumsum per patch lane — deletes must see
-    the rows *after* this round's inserts) followed by the paper's
-    **two-phase delete-and-swap**: phase 1 kills doomed tail slots in
-    place, phase 2 moves the surviving tail slots into the front holes
-    (a one-hot move per hole index — gather-free, bit-identical to
-    ``updates.two_phase_delete``);
-  * **rebuild** — group membership, sizes, digit sums, Eq. 9 types, the
-    compacted ``gmem`` rows (one-hot compaction per radix position) and
-    the K(+1)-entry inter-group alias row (lane-parallel Vose, matching
-    ``alias._build_row`` float-for-float) are recomputed from the final
-    bias row, exactly like ``dyngraph.build_vertex_groups`` +
-    ``build_itable_rows``.
+    doomed value, a masked lane prefix count per patch lane — deletes
+    must see the rows *after* this round's inserts) followed by the
+    paper's **two-phase delete-and-swap**: phase 1 kills doomed tail
+    slots in place, phase 2 moves the surviving tail slots into the
+    front holes (a one-hot move per hole index — gather-free,
+    bit-identical to ``updates.two_phase_delete``).  Both loops run as
+    many passes as the tile's busiest row has delete lanes;
+  * **rebuild** — group membership, sizes, digit sums, Eq. 9 types and
+    the compacted ``gmem`` rows are recomputed from the final bias row
+    one radix group at a time, exactly like
+    ``dyngraph.build_vertex_groups``; a group's member slots reach the
+    front of its ``gmem`` row in log2(C) power-of-two lane hops.
 
 Rows travel HBM→VMEM→HBM once each; the gathers for tile i+1 are issued
 while tile i computes (same double-buffered ``make_async_copy``
 discipline as the walk megakernel).  Per-row results that are O(K)-sized
-(deg, gsize, digitsum, wdec, gtype, alias rows) come back as dense
-blocked outputs and are scattered outside the kernel — they are three
-orders of magnitude smaller than the row tables the kernel keeps
-in place.
+(deg, gsize, digitsum, wdec, gtype) come back as dense blocked outputs
+and are scattered outside the kernel, where the inter-group alias rows
+are built from them by the reference's own ``build_itable_rows`` — so
+both paths divide and sum with the same XLA ops on any backend.
+
+Mosaic constraints shape the layout (DESIGN.md §9): prefix counts are
+``core/lanes.py`` log-step rotations (no ``cumsum``/``argmax`` lowering),
+the row tables enter as ``(V, 1, C)`` so one vertex row is one
+tile-aligned DMA, and the per-tile ids ride a 128-lane row DMA'd into
+SMEM.
 
 Static bound: each affected vertex carries at most ``block_dels`` delete
 *patch* lanes per round (default ``min(B, 2·C)``).  When ``B <= 2·C``
@@ -70,87 +76,53 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import radix
 from repro.core.alias import AliasTable
-from repro.core.dyngraph import DENSE, BingoConfig, BingoState, classify
+from repro.core.dyngraph import (DENSE, BingoConfig, BingoState,
+                                 build_itable_rows, classify)
+from repro.core.lanes import lane_cumsum
 from repro.core.updates import (NUM_REASONS, R_ABSENT, R_CAPACITY, R_VERTEX,
                                 UpdateStats, _padded_unique)
 
 __all__ = ["update_fused_pallas"]
 
 
-def _vose_rows(w):
-    """Lane-parallel Vose pairing, bit-identical to ``alias._build_row``.
-
-    ``kernels/alias_build.py`` carries the same loop but folds the
-    ``-1.0`` into the broadcast add; the reference adds
-    ``scaled[s] - 1.0`` to ``scaled[l]``, and float addition is not
-    associative — this copy keeps the reference's parenthesization (and
-    ``alias._row_total``'s explicit left-to-right total, which a fused
-    reduce inside the kernel body would silently reassociate) so the
-    rebuilt itable rows match the jnp oracle bit-for-bit.
-    """
-    from repro.core.alias import _row_total
-    R, n = w.shape
-    total = _row_total(w)[:, None]
-    scaled = jnp.where(total > 0, w * n / jnp.maximum(total, 1e-30), 0.0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (R, n), 1)
-    prob0 = jnp.ones((R, n), jnp.float32)
-    done0 = jnp.zeros((R, n), bool)
-
-    def body(_, carry):
-        scaled, prob, alias, done = carry
-        small = (~done) & (scaled < 1.0)
-        large = (~done) & (scaled >= 1.0)
-        do = (jnp.any(small, -1) & jnp.any(large, -1))[:, None]
-        s = jnp.argmax(small, axis=-1)[:, None]
-        l = jnp.argmax(large, axis=-1)[:, None]
-        at_s = col == s
-        at_l = col == l
-        sval = jnp.sum(jnp.where(at_s, scaled, 0.0), -1, keepdims=True)
-        prob = jnp.where(do & at_s, sval, prob)
-        alias = jnp.where(do & at_s, l, alias)
-        scaled = jnp.where(do & at_l, scaled + (sval - 1.0), scaled)
-        done = jnp.where(do & at_s, True, done)
-        return scaled, prob, alias, done
-
-    _, prob, alias, _ = jax.lax.fori_loop(
-        0, n, body, (scaled, prob0, col, done0))
-    return prob, alias
+# Per-tile scalars ride one 128-lane int32 row per tile (a (nt, 1, 128)
+# table, tile-aligned for the DMA into SMEM): the tile's affected vertex
+# ids in lanes [0, Rt) and the delete-loop bound in the last lane.
+_IDS_W = 128
+_NDEL_LANE = _IDS_W - 1
 
 
 def _kernel(cfg: BingoConfig, Rt, Dp, *refs):
-    V, C, K = cfg.num_vertices, cfg.capacity, cfg.num_radix
-    Cg, Kin = cfg.group_capacity, cfg.num_inter
+    V = cfg.num_vertices
+    fp = cfg.fp_bias
     has_ginv = not cfg.adaptive
     refs = list(refs)
-    u_any = refs.pop(0)                        # (Bp,) ANY — affected rows
-    deg_ref = refs.pop(0)                      # (Rt, 1) deg after inserts
-    insm_ref, insn_ref = refs.pop(0), refs.pop(0)
-    insb_ref, insf_ref = refs.pop(0), refs.pop(0)
-    delo_ref, delv_ref, delr_ref = refs.pop(0), refs.pop(0), refs.pop(0)
-    nbr_any, bias_any, frac_any = refs.pop(0), refs.pop(0), refs.pop(0)
-    gmem_any = refs.pop(0)
-    ginv_any = refs.pop(0) if has_ginv else None
+    ids_any = refs.pop(0)                      # (nt, 1, 128) ANY
+    deg0_ref, deg1_ref = refs.pop(0), refs.pop(0)   # (Rt, 1) pre/post ins
+    insn_ref, insb_ref = refs.pop(0), refs.pop(0)   # (Rt, C) patches
+    insf_ref = refs.pop(0) if fp else None
+    delv_ref, delr_ref = refs.pop(0), refs.pop(0)   # (Rt, Dp) patches
+    row_any = [refs.pop(0) for _ in range(3 if fp else 2)]  # (V, 1, C)
+    refs = refs[2 if has_ginv else 1:]          # gmem/ginv: written only
     # outputs: aliased ANY row tables, then dense per-row blocks
-    nbr_o, bias_o, frac_o, gmem_o = (refs.pop(0), refs.pop(0),
-                                     refs.pop(0), refs.pop(0))
+    row_o = [refs.pop(0) for _ in range(3 if fp else 2)]
+    gmem_o = refs.pop(0)
     ginv_o = refs.pop(0) if has_ginv else None
     dego_ref, gsz_ref, dsum_ref = refs.pop(0), refs.pop(0), refs.pop(0)
-    wdec_ref, gt_ref = refs.pop(0), refs.pop(0)
-    prob_ref, alias_ref, delok_ref = refs.pop(0), refs.pop(0), refs.pop(0)
+    wdec_ref, gt_ref, ndel_ref = refs.pop(0), refs.pop(0), refs.pop(0)
     # scratch
-    nbr_b, bias_b, frac_b = refs.pop(0), refs.pop(0), refs.pop(0)
-    out_nbr, out_bias, out_frac = refs.pop(0), refs.pop(0), refs.pop(0)
+    row_b = [refs.pop(0) for _ in range(3 if fp else 2)]   # (2, Rt, 1, C)
+    row_out = [refs.pop(0) for _ in range(3 if fp else 2)]  # (Rt, 1, C)
     out_gmem = refs.pop(0)
     out_ginv = refs.pop(0) if has_ginv else None
-    u_sm, gsem, osem, usem = refs              # SMEM (2, Rt), DMA sems
+    ids_sm, gsem, osem, isem = refs            # SMEM (2, 1, 128), DMA sems
 
     i = pl.program_id(0)
     nt = pl.num_programs(0)
     slot = jax.lax.rem(i, 2)
 
-    def load_u(s, tile):
-        cp = pltpu.make_async_copy(u_any.at[pl.ds(tile * Rt, Rt)],
-                                   u_sm.at[s], usem)
+    def load_ids(s, tile):
+        cp = pltpu.make_async_copy(ids_any.at[tile], ids_sm.at[s], isem)
         cp.start()
         cp.wait()
 
@@ -158,14 +130,13 @@ def _kernel(cfg: BingoConfig, Rt, Dp, *refs):
         """Start/wait the row DMAs of every real (non-sentinel) row.
 
         The predicate is stable between the paired start/wait loops:
-        ``u_sm[s]`` is only rewritten when slot ``s`` is reloaded for a
+        ``ids_sm[s]`` is only rewritten when slot ``s`` is reloaded for a
         later tile, after this tile's wait."""
         def body(r, _):
-            @pl.when(u_sm[s, r] < V)
+            @pl.when(ids_sm[s, 0, r] < V)
             def _():
-                vtx = u_sm[s, r]
-                for tab, buf in ((nbr_any, nbr_b), (bias_any, bias_b),
-                                 (frac_any, frac_b)):
+                vtx = ids_sm[s, 0, r]
+                for tab, buf in zip(row_any, row_b):
                     getattr(pltpu.make_async_copy(
                         tab.at[vtx], buf.at[s, r], gsem.at[s]), action)()
             return 0
@@ -173,7 +144,7 @@ def _kernel(cfg: BingoConfig, Rt, Dp, *refs):
 
     @pl.when(i == 0)
     def _():
-        load_u(0, 0)
+        load_ids(0, 0)
         gather(0, "start")
 
     gather(slot, "wait")
@@ -182,124 +153,167 @@ def _kernel(cfg: BingoConfig, Rt, Dp, *refs):
     @pl.when(i + 1 < nt)
     def _():
         nslot = jax.lax.rem(i + 1, 2)
-        load_u(nslot, i + 1)
+        load_ids(nslot, i + 1)
         gather(nslot, "start")
 
-    # ---- stage 1: conflict-free inserts (patch lanes -> row slots) ----
-    insm = insm_ref[...] != 0
-    nbr1 = jnp.where(insm, insn_ref[...], nbr_b[slot])
-    bias1 = jnp.where(insm, insb_ref[...], bias_b[slot])
-    frac1 = jnp.where(insm, insf_ref[...], frac_b[slot])
-    d = deg_ref[...]                              # (Rt, 1) post-insert deg
+    # Affected ids are sorted with the sentinel V last, so a tile whose
+    # first row is the sentinel has no real row: skip its compute.  Its
+    # dense outputs are dropped by the caller's scatter, except the
+    # delete count, which is summed — zero it here.
+    ndel_ref[...] = jnp.zeros((Rt, 1), jnp.int32)
+
+    @pl.when(ids_sm[slot, 0, 0] < V)
+    def _():
+        _rebuild_tile(cfg, Rt, Dp, slot, ids_sm[slot, 0, _NDEL_LANE],
+                      deg0_ref, deg1_ref, insn_ref, insb_ref, insf_ref,
+                      delv_ref, delr_ref, row_b, row_out, out_gmem,
+                      out_ginv, dego_ref, gsz_ref, dsum_ref, wdec_ref,
+                      gt_ref, ndel_ref)
+
+        def put(action):
+            def body(r, _):
+                @pl.when(ids_sm[slot, 0, r] < V)
+                def _():
+                    vtx = ids_sm[slot, 0, r]
+                    pairs = list(zip(row_out, row_o)) + [(out_gmem, gmem_o)]
+                    if has_ginv:
+                        pairs.append((out_ginv, ginv_o))
+                    for src, dst in pairs:
+                        getattr(pltpu.make_async_copy(
+                            src.at[r], dst.at[vtx], osem), action)()
+                return 0
+            jax.lax.fori_loop(0, Rt, body, 0)
+
+        put("start")
+        put("wait")
+
+
+def _rebuild_tile(cfg, Rt, Dp, slot, nd, deg0_ref, deg1_ref, insn_ref,
+                  insb_ref, insf_ref, delv_ref, delr_ref, row_b, row_out,
+                  out_gmem, out_ginv, dego_ref, gsz_ref, dsum_ref, wdec_ref,
+                  gt_ref, ndel_ref):
+    """Insert → locate → two-phase delete → group rebuild for one tile.
+
+    Every lane op here is one Mosaic lowers: prefix counts are
+    ``lanes.lane_cumsum`` over ``pltpu.roll``, reductions are lane sums,
+    and bool vectors never cross a loop carry (int32 0/1 instead)."""
+    C, K, Cg = cfg.capacity, cfg.num_radix, cfg.group_capacity
+    fp = cfg.fp_bias
+    bl = cfg.base_log2
+    roll = pltpu.roll
+
+    def cum(x):
+        return lane_cumsum(x.astype(jnp.int32), roll)
+
+    rows = [b[slot].reshape(Rt, C) for b in row_b]
+
+    # ---- stage 1: conflict-free inserts: lanes [deg0, deg1) take the
+    # patch values (the prepass placed each at deg0 + its rank) ----
+    d0, d = deg0_ref[...], deg1_ref[...]            # (Rt, 1)
     colC = jax.lax.broadcasted_iota(jnp.int32, (Rt, C), 1)
+    insm = (colC >= d0) & (colC < d)
+    nbr1 = jnp.where(insm, insn_ref[...], rows[0])
+    bias1 = jnp.where(insm, insb_ref[...], rows[1])
+    frac1 = jnp.where(insm, insf_ref[...], rows[2]) if fp else None
     in_row = colC < d
 
-    # ---- stage 2a: locate — (rank+1)-th match of each doomed value ----
-    delo, delv, delr = delo_ref[...], delv_ref[...], delr_ref[...]
+    # ---- stage 2a: locate — (rank+1)-th match of each doomed value.
+    # ``nd`` bounds the tile's delete lanes, so the loop runs only as
+    # many passes as the busiest row needs ----
+    delv, delr = delv_ref[...], delr_ref[...]
     colD = jax.lax.broadcasted_iota(jnp.int32, (Rt, Dp), 1)
 
-    def locate(j, carry):
-        dmask, okv = carry
+    def locate(j, dmask):
         at_j = colD == j
-        on = jnp.sum(jnp.where(at_j, delo, 0), -1, keepdims=True) != 0
         dvj = jnp.sum(jnp.where(at_j, delv, 0), -1, keepdims=True)
         rkj = jnp.sum(jnp.where(at_j, delr, 0), -1, keepdims=True)
-        m = (nbr1 == dvj) & in_row & on
-        cnt = jnp.cumsum(m.astype(jnp.int32), axis=-1)
-        hit = m & (cnt == rkj + 1)
-        got = jnp.any(hit, axis=-1, keepdims=True)
-        okv = jnp.where(at_j & got, 1, okv)
-        return dmask | hit, okv
+        m = (nbr1 == dvj) & in_row & (dvj >= 0)     # off lanes carry -1
+        hit = m & (cum(m) == rkj + 1)
+        return jnp.maximum(dmask, hit.astype(jnp.int32))
 
-    dmask, delok = jax.lax.fori_loop(
-        0, Dp, locate, (jnp.zeros((Rt, C), bool),
-                        jnp.zeros((Rt, Dp), jnp.int32)))
-    delok_ref[...] = delok
+    dmask = jax.lax.fori_loop(0, nd, locate,
+                              jnp.zeros((Rt, C), jnp.int32)) != 0
 
     # ---- stage 2b: two-phase delete-and-swap (paper Fig. 10(b)) ----
     n = jnp.sum(dmask.astype(jnp.int32), -1, keepdims=True)
+    ndel_ref[...] = n
     front = d - n
-    is_tail = (colC >= front) & in_row
-    surv_tail = is_tail & ~dmask
+    surv_tail = (colC >= front) & in_row & ~dmask
     hole = dmask & (colC < front)
-    r_surv = jnp.cumsum(surv_tail.astype(jnp.int32), -1) - 1
-    r_hole = jnp.cumsum(hole.astype(jnp.int32), -1) - 1
+    r_surv = cum(surv_tail) - 1
+    r_hole = cum(hole) - 1
 
     def mv(j, vals):
         # phase 2, hole j: the j-th surviving tail slot fills the j-th
-        # front hole (a one-hot read + one-hot write — no gathers).
-        nbr2, bias2, frac2 = vals
+        # front hole (a one-hot read + one-hot write — no gathers).  A
+        # row has at most n <= nd holes.
         sel_h = hole & (r_hole == j)
         sel_s = surv_tail & (r_surv == j)
-        put = sel_h & jnp.any(sel_s, -1, keepdims=True)
-        vn = jnp.sum(jnp.where(sel_s, nbr1, 0), -1, keepdims=True)
-        vb = jnp.sum(jnp.where(sel_s, bias1, 0), -1, keepdims=True)
-        vf = jnp.sum(jnp.where(sel_s, frac1, 0.0), -1, keepdims=True)
-        return (jnp.where(put, vn, nbr2), jnp.where(put, vb, bias2),
-                jnp.where(put, vf, frac2))
+        put = sel_h & (jnp.sum(sel_s.astype(jnp.int32), -1,
+                               keepdims=True) > 0)
+        out = []
+        for val, old in zip(vals, (nbr1, bias1, frac1)):
+            zero = jnp.zeros((), old.dtype)
+            got = jnp.sum(jnp.where(sel_s, old, zero), -1, keepdims=True)
+            out.append(jnp.where(put, got, val))
+        return tuple(out)
 
-    nbr2, bias2, frac2 = jax.lax.fori_loop(0, C, mv, (nbr1, bias1, frac1))
+    moved = jax.lax.fori_loop(0, nd, mv, (nbr1, bias1, frac1)
+                              if fp else (nbr1, bias1))
     keep = colC < front
-    nbr3 = jnp.where(keep, nbr2, -1)
-    bias3 = jnp.where(keep, bias2, 0)
-    frac3 = jnp.where(keep, frac2, 0.0)
+    nbr3 = jnp.where(keep, moved[0], -1)
+    bias3 = jnp.where(keep, moved[1], 0)
+    row_out[0][...] = nbr3.reshape(Rt, 1, C)
+    row_out[1][...] = bias3.reshape(Rt, 1, C)
+    if fp:
+        frac3 = jnp.where(keep, moved[2], 0.0)
+        row_out[2][...] = frac3.reshape(Rt, 1, C)
+        wdec = jnp.max(lane_cumsum(frac3, roll), -1, keepdims=True)
+    else:
+        wdec = jnp.zeros((Rt, 1), jnp.float32)
 
-    # ---- stage 3: rebuild (dyngraph.build_vertex_groups, tile-wide) ----
-    digs = jnp.where(keep[..., None],
-                     radix.digits(bias3, K, cfg.base_log2), 0)  # (Rt, C, K)
-    member = digs != 0
-    gsize = jnp.sum(member.astype(jnp.int32), axis=1)           # (Rt, K)
-    digitsum = jnp.sum(digs, axis=1)
-    gtype = classify(gsize, front[:, 0], cfg)                   # (Rt, K) i8
-    pos = jnp.cumsum(member.astype(jnp.int32), axis=1) - 1
-    keepm = member & (pos < Cg)
-    if cfg.adaptive:
-        keepm = keepm & (gtype[:, None, :] != DENSE)
-    colG = jax.lax.broadcasted_iota(jnp.int32, (Rt, C, Cg), 2)
-    rows = []
+    # ---- stage 3: rebuild, one radix group at a time on (Rt, C) lanes
+    # (dyngraph.build_vertex_groups).  Group k's gmem row compacts its
+    # member slots to the front: each kept slot moves left by the count
+    # of non-kept slots before it, in log2(C) power-of-two hops (shifts
+    # are non-decreasing along the row, so no two slots ever collide) ----
+    colK = jax.lax.broadcasted_iota(jnp.int32, (Rt, K), 1)
+    gsize = jnp.zeros((Rt, K), jnp.int32)
+    digitsum = jnp.zeros((Rt, K), jnp.int32)
+    gtype = jnp.zeros((Rt, K), jnp.int32)
     for k in range(K):
-        onehot = keepm[:, :, k, None] & (pos[:, :, k, None] == colG)
-        val = jnp.sum(jnp.where(onehot, colC[:, :, None], 0), axis=1)
-        rows.append(jnp.where(jnp.any(onehot, axis=1), val, -1))
-    out_gmem[...] = jnp.stack(rows, axis=1)                     # (Rt, K, Cg)
-    if has_ginv:
-        out_ginv[...] = jnp.where(member, pos, -1).transpose(0, 2, 1)
-    wdec = jnp.sum(jnp.where(keep, frac3, 0.0), axis=-1, keepdims=True)
-
-    gw = radix.group_weights(digitsum, cfg.base_log2)           # (Rt, K) f32
-    if cfg.fp_bias:
-        gw = jnp.concatenate([gw, wdec], axis=-1)               # (Rt, Kin)
-    prob, alias = _vose_rows(gw)
+        dig = jnp.where(keep, (bias3 >> (k * bl)) & ((1 << bl) - 1), 0)
+        member = dig != 0
+        pos = cum(member) - 1
+        gs = jnp.sum(member.astype(jnp.int32), -1, keepdims=True)
+        gt = classify(gs, front[:, 0], cfg, jnp.int32)             # (Rt, 1)
+        gsize = jnp.where(colK == k, gs, gsize)
+        digitsum = jnp.where(colK == k, jnp.sum(dig, -1, keepdims=True),
+                             digitsum)
+        gtype = jnp.where(colK == k, gt, gtype)
+        if out_ginv is not None:
+            out_ginv[:, k, :] = jnp.where(member, pos, -1)
+        kept = member & (pos < Cg)
+        if cfg.adaptive:
+            kept = kept & (gt != DENSE)
+        val = jnp.where(kept, colC, -1)
+        shift = jnp.where(kept, colC - pos, 0)
+        s = 1
+        while s < C:
+            go = (shift & s) != 0
+            land = roll(go.astype(jnp.int32), C - s, 1) != 0
+            val = jnp.where(land, roll(val, C - s, 1),
+                            jnp.where(go, -1, val))
+            shift = jnp.where(land, roll(shift, C - s, 1),
+                              jnp.where(go, 0, shift))
+            s *= 2
+        out_gmem[:, k, :] = val[:, :Cg]
 
     dego_ref[...] = front
     gsz_ref[...] = gsize
     dsum_ref[...] = digitsum
     wdec_ref[...] = wdec
-    gt_ref[...] = gtype.astype(jnp.int32)
-    prob_ref[...] = prob
-    alias_ref[...] = alias
-
-    out_nbr[...] = nbr3
-    out_bias[...] = bias3
-    out_frac[...] = frac3
-
-    def put(action):
-        def body(r, _):
-            @pl.when(u_sm[slot, r] < V)
-            def _():
-                vtx = u_sm[slot, r]
-                pairs = [(out_nbr, nbr_o), (out_bias, bias_o),
-                         (out_frac, frac_o), (out_gmem, gmem_o)]
-                if has_ginv:
-                    pairs.append((out_ginv, ginv_o))
-                for src, dst in pairs:
-                    getattr(pltpu.make_async_copy(
-                        src.at[r], dst.at[vtx], osem), action)()
-            return 0
-        jax.lax.fori_loop(0, Rt, body, 0)
-
-    put("start")
-    put("wait")
+    gt_ref[...] = gtype
 
 
 @functools.partial(jax.jit,
@@ -323,7 +337,8 @@ def update_fused_pallas(state: BingoState, cfg: BingoConfig, is_insert,
     larger ones.
     """
     V, C, K = cfg.num_vertices, cfg.capacity, cfg.num_radix
-    Cg, Kin = cfg.group_capacity, cfg.num_inter
+    Cg = cfg.group_capacity
+    fp = cfg.fp_bias
     B = u.shape[0]
     u = jnp.asarray(u, jnp.int32)
     v = jnp.asarray(v, jnp.int32)
@@ -335,7 +350,7 @@ def update_fused_pallas(state: BingoState, cfg: BingoConfig, is_insert,
     lane_ok = (u >= 0) & (u < V) & (v >= 0)
     ins = is_insert & active & lane_ok
     dele = (~is_insert) & active & lane_ok
-    if cfg.fp_bias:
+    if fp:
         w_int, w_frac = radix.decompose_fp(w, cfg.lam)
     else:
         w_int = jnp.asarray(w, jnp.int32)
@@ -357,15 +372,13 @@ def update_fused_pallas(state: BingoState, cfg: BingoConfig, is_insert,
     n_ins = jnp.sum(okA, dtype=jnp.int32)
     rowA = jnp.where(okA, jnp.searchsorted(U, su_s).astype(jnp.int32), B)
     offA = jnp.where(okA, off, 0)
-    ins_mask = jnp.zeros((B, C), jnp.int32).at[rowA, offA].set(1, mode="drop")
     ins_nbr = jnp.zeros((B, C), jnp.int32).at[rowA, offA].set(
         v_s, mode="drop")
     ins_bias = jnp.zeros((B, C), jnp.int32).at[rowA, offA].set(
         wi_s, mode="drop")
-    ins_frac = jnp.zeros((B, C), jnp.float32).at[rowA, offA].set(
-        wf_s, mode="drop")
     ins_cnt = jnp.zeros((B,), jnp.int32).at[rowA].add(1, mode="drop")
-    deg_ins = state.deg[Uc] + ins_cnt
+    deg0 = state.deg[Uc]
+    deg_ins = deg0 + ins_cnt
 
     du = jnp.where(dele, u, V)
     dv = jnp.where(dele, v, -1)
@@ -381,75 +394,78 @@ def update_fused_pallas(state: BingoState, cfg: BingoConfig, is_insert,
     rowD = jnp.where((du_s < V) & (lane < Dp),
                      jnp.searchsorted(U, du_s).astype(jnp.int32), B)
     laneD = jnp.minimum(lane, Dp - 1)
-    del_on = jnp.zeros((B, Dp), jnp.int32).at[rowD, laneD].set(
-        1, mode="drop")
+    # v >= 0 on every valid delete lane, so -1 marks an unused lane
     del_v = jnp.full((B, Dp), -1, jnp.int32).at[rowD, laneD].set(
         dv_s, mode="drop")
     del_rank = jnp.zeros((B, Dp), jnp.int32).at[rowD, laneD].set(
         rankD, mode="drop")
+    del_cnt = jnp.zeros((B,), jnp.int32).at[rowD].add(1, mode="drop")
 
     # ---- pad the affected-row axis to the tile size ----
     Rt = max(1, min(block_rows, B))
+    if Rt >= _NDEL_LANE:
+        raise ValueError(f"block_rows {Rt} does not fit a {_IDS_W}-lane "
+                         "id row")
     nt = -(-B // Rt)
     pad = nt * Rt - B
+    Bp = nt * Rt
 
     def padr(x, fill):
         return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1),
                        constant_values=fill)
 
     Up = padr(U, V)
+    tile_ndel = jnp.max(padr(del_cnt, 0).reshape(nt, Rt), axis=1)
+    ids = jnp.concatenate(
+        [Up.reshape(nt, Rt), jnp.zeros((nt, _NDEL_LANE - Rt), jnp.int32),
+         tile_ndel[:, None]], axis=1).reshape(nt, 1, _IDS_W)
     has_ginv = state.ginv is not None
+    # Row tables go in as (V, 1, C) so each vertex row is one tile-aligned
+    # DMA (see walk_fused.META_W); frac only in fp mode — an integer-bias
+    # state keeps it all zero, and so does every round.
+    tabs = [state.nbr, state.bias] + ([state.frac] if fp else [])
+    tabs = [t.reshape(V, 1, C) for t in tabs]
+    ntab = len(tabs) + 1 + has_ginv
 
     def row_spec(lane):
         return pl.BlockSpec((Rt, lane), lambda i: (i, 0))
 
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    in_specs = ([any_spec, row_spec(1)] + [row_spec(C)] * 4
-                + [row_spec(Dp)] * 3
-                + [any_spec] * (5 if has_ginv else 4))
-    args = [Up, padr(deg_ins[:, None], 0), padr(ins_mask, 0),
-            padr(ins_nbr, 0), padr(ins_bias, 0), padr(ins_frac, 0),
-            padr(del_on, 0), padr(del_v, -1), padr(del_rank, 0),
-            state.nbr, state.bias, state.frac, state.gmem]
-    if has_ginv:
-        args.append(state.ginv)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    args = ([ids, padr(deg0[:, None], 0), padr(deg_ins[:, None], 0),
+             padr(ins_nbr, 0), padr(ins_bias, 0)]
+            + ([padr(jnp.zeros((B, C), jnp.float32).at[rowA, offA].set(
+                wf_s, mode="drop"), 0)] if fp else [])
+            + [padr(del_v, -1), padr(del_rank, 0)]
+            + tabs + [state.gmem] + ([state.ginv] if has_ginv else []))
+    first_tab = len(args) - ntab
+    in_specs = ([any_spec] + [row_spec(1)] * 2 + [row_spec(C)] * (3 if fp
+                                                                  else 2)
+                + [row_spec(Dp)] * 2 + [any_spec] * ntab)
 
-    Bp = nt * Rt
     sds = jax.ShapeDtypeStruct
-    out_specs = [any_spec] * (5 if has_ginv else 4) + [
+    out_specs = [any_spec] * ntab + [
         row_spec(1), row_spec(K), row_spec(K), row_spec(1), row_spec(K),
-        row_spec(Kin), row_spec(Kin), row_spec(Dp)]
-    out_shape = [sds((V, C), jnp.int32), sds((V, C), jnp.int32),
-                 sds((V, C), jnp.float32), sds((V, K, Cg), jnp.int32)]
-    if has_ginv:
-        out_shape.append(sds((V, K, C), jnp.int32))
+        row_spec(1)]
+    out_shape = [sds(t.shape, t.dtype) for t in args[first_tab:]]
     out_shape += [sds((Bp, 1), jnp.int32), sds((Bp, K), jnp.int32),
                   sds((Bp, K), jnp.int32), sds((Bp, 1), jnp.float32),
-                  sds((Bp, K), jnp.int32), sds((Bp, Kin), jnp.float32),
-                  sds((Bp, Kin), jnp.int32), sds((Bp, Dp), jnp.int32)]
+                  sds((Bp, K), jnp.int32), sds((Bp, 1), jnp.int32)]
     # aliased in-place tables: untouched vertices are never copied
-    first_tab = 9
-    aliases = {first_tab + t: t for t in range(5 if has_ginv else 4)}
+    aliases = {first_tab + t: t for t in range(ntab)}
 
-    scratch = [
-        pltpu.VMEM((2, Rt, C), jnp.int32),      # nbr rows, double-buffered
-        pltpu.VMEM((2, Rt, C), jnp.int32),      # bias rows
-        pltpu.VMEM((2, Rt, C), jnp.float32),    # frac rows
-        pltpu.VMEM((Rt, C), jnp.int32),         # out nbr
-        pltpu.VMEM((Rt, C), jnp.int32),         # out bias
-        pltpu.VMEM((Rt, C), jnp.float32),       # out frac
-        pltpu.VMEM((Rt, K, Cg), jnp.int32),     # out gmem
-    ]
+    scratch = ([pltpu.VMEM((2, Rt, 1, C), t.dtype) for t in tabs]
+               + [pltpu.VMEM((Rt, 1, C), t.dtype) for t in tabs]
+               + [pltpu.VMEM((Rt, K, Cg), jnp.int32)])
     if has_ginv:
         scratch.append(pltpu.VMEM((Rt, K, C), jnp.int32))
     scratch += [
-        pltpu.SMEM((2, Rt), jnp.int32),         # affected ids (DMA scalars)
+        pltpu.SMEM((2, 1, _IDS_W), jnp.int32),  # tile ids (DMA scalars)
         pltpu.SemaphoreType.DMA((2,)),          # row gathers, per slot
         pltpu.SemaphoreType.DMA(()),            # row write-backs
-        pltpu.SemaphoreType.DMA(()),            # id mirror
+        pltpu.SemaphoreType.DMA(()),            # id row
     ]
 
-    outs = pl.pallas_call(
+    outs = list(pl.pallas_call(
         functools.partial(_kernel, cfg, Rt, Dp),
         grid=(nt,),
         in_specs=in_specs,
@@ -458,27 +474,31 @@ def update_fused_pallas(state: BingoState, cfg: BingoConfig, is_insert,
         scratch_shapes=scratch,
         input_output_aliases=aliases,
         interpret=interpret,
-    )(*args)
-    outs = list(outs)
-    nbr_n, bias_n, frac_n, gmem_n = outs[:4]
-    ginv_n = outs[4] if has_ginv else None
-    (dego, gsz, dsum, wdec, gt, prob, alias, delok) = \
-        outs[5:] if has_ginv else outs[4:]
+    )(*args))
+    rows_n = [t.reshape(V, C) for t in outs[:len(tabs)]]
+    gmem_n = outs[len(tabs)]
+    ginv_n = outs[len(tabs) + 1] if has_ginv else None
+    dego, gsz, dsum, wdec, gt, ndel = outs[ntab:]
+    # The inter-group alias rows are built outside the kernel by the
+    # very function the reference uses, so both paths divide and sum
+    # with the same XLA ops on any backend.
+    itab = build_itable_rows(cfg, dsum, wdec[:, 0])
 
     st = state._replace(
-        nbr=nbr_n, bias=bias_n, frac=frac_n, gmem=gmem_n, ginv=ginv_n,
+        nbr=rows_n[0], bias=rows_n[1], frac=rows_n[2] if fp else state.frac,
+        gmem=gmem_n, ginv=ginv_n,
         deg=state.deg.at[Up].set(dego[:, 0], mode="drop"),
         gsize=state.gsize.at[Up].set(gsz, mode="drop"),
         digitsum=state.digitsum.at[Up].set(dsum, mode="drop"),
         wdec=state.wdec.at[Up].set(wdec[:, 0], mode="drop"),
         gtype=state.gtype.at[Up].set(gt.astype(jnp.int8), mode="drop"),
         itable=AliasTable(
-            prob=state.itable.prob.at[Up].set(prob, mode="drop"),
-            alias=state.itable.alias.at[Up].set(alias, mode="drop"),
+            prob=state.itable.prob.at[Up].set(itab.prob, mode="drop"),
+            alias=state.itable.alias.at[Up].set(itab.alias, mode="drop"),
         ),
     )
 
-    n_del = jnp.sum(delok, dtype=jnp.int32)
+    n_del = jnp.sum(jnp.where(Up < V, ndel[:, 0], 0), dtype=jnp.int32)
     old_gtype = state.gtype[Uc]
     new_gtype = gt[:B].astype(jnp.int8)
     valid_row = (U < V)[:, None]

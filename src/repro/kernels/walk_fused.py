@@ -32,19 +32,21 @@ Structure per walker tile of Bt:
     VMEM slots (slot c is only rewritten after cohort c's sample
     consumed it, so one slot per cohort suffices — total row scratch
     *shrinks* from 2·Bt to Bt rows); per-cohort alive flags live in the
-    same SMEM mirror, synced one cohort-slice at a time so a phase
-    never perturbs another cohort's DMA predicates.  Cohort assignment
+    same SMEM mirror, which every phase rewrites whole without moving
+    another cohort's DMA predicates (``sync_state``).  Cohort assignment
     provably cannot change any walker's stream: uniforms are keyed by
     ``(seed, wid, t)`` (below), never by lane, phase, or slot — so any
     K produces bit-identical paths (pinned by ``tests/test_kernels.py``
     against K=1 and the jnp oracle);
   * walker state (cur | alive) lives in VMEM scratch, mirrored to SMEM
-    once per step (one (Bt, 2) DMA) because DMA descriptors need scalar
-    indices; dead walkers (PPR termination, dead ends) skip their row
-    gathers entirely via ``pl.when`` on the SMEM alive flag;
+    after each phase (one (Bt, 2) DMA) because DMA descriptors need
+    scalar indices; dead walkers (PPR termination, dead ends) skip their
+    row gathers entirely via ``pl.when`` on the SMEM alive flag;
+  * the tables arrive as (V, 1, W) row tables (``META_W``), so every
+    row gather is one tile-aligned DMA;
   * the sample itself is the exact in-register two-stage pass shared
     with the per-step kernel (``walk_sample.sample_rows``): stage (i)
-    alias one-hot, stage (ii) masked lane cumsum, including the fp
+    alias one-hot, stage (ii) masked lane prefix count, including the fp
     decimal group and base > 2 digit-acceptance lanes — or the
     degree-based ``uniform_pick`` for the ``simple`` kind;
   * uniforms are counter-based (``uniforms_at``): step-t uniforms are a
@@ -91,6 +93,15 @@ __all__ = ["walk_fused_pallas", "uniforms_at", "NUM_UNIFORMS"]
 
 NUM_UNIFORMS = 6
 
+# Row tables reach the kernel as (V, 1, W): XLA lays such an array out in
+# (1, 128) tiles, so one vertex's row is one contiguous tile-aligned DMA
+# (a row of a (V, W) array sits inside an (8, 128) tile, which Mosaic
+# refuses to slice).  The K-wide alias/prob rows and ``deg`` ride in
+# 128-lane "meta" rows: alias (or prob) in lanes [0, Kin), deg in the
+# last lane of the int32 one.
+META_W = 128
+DEG_LANE = META_W - 1
+
 # murmur3 finalizer constants + distinct odd counter multipliers, as
 # wrapped int32 (XLA integer multiply wraps; shifts below are logical).
 _M1 = np.int32(np.uint32(0x85EBCA6B).astype(np.int32))
@@ -133,7 +144,7 @@ def uniforms_at(seed, wid, t, ncols: int = NUM_UNIFORMS):
 
 
 def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
-            segment, block_b, num_verts, cohorts, *refs):
+            segment, block_b, num_verts, cohorts, num_inter, *refs):
     Bt = block_b
     K = cohorts
     Bc = Bt // K                               # cohort lane count
@@ -144,19 +155,13 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
     t0_ref = refs.pop(0) if segment else None  # (Bt, 1) VMEM
     wid_ref = refs.pop(0) if segment else None  # (Bt, 1) VMEM slot→wid
     u_ref = refs.pop(0) if has_u else None     # (L, Bt, 6) VMEM
-    if uniform:
-        nbr_hbm, deg_hbm = refs.pop(0), refs.pop(0)
-        tabs = (nbr_hbm, deg_hbm)
-    else:
-        prob_hbm, alias_hbm = refs.pop(0), refs.pop(0)
-        bias_hbm, nbr_hbm, deg_hbm = refs.pop(0), refs.pop(0), refs.pop(0)
-        tabs = (prob_hbm, alias_hbm, bias_hbm, nbr_hbm, deg_hbm)
-        if has_frac:
-            frac_hbm = refs.pop(0)
-            tabs += (frac_hbm,)
+    # HBM (V, 1, W) row tables: uniform = (meta, nbr); otherwise
+    # (meta, prob, bias, nbr[, frac]) — see META_W.
+    ntab = 2 if uniform else 4 + has_frac
+    tabs = tuple(refs.pop(0) for _ in range(ntab))
     out_ref = refs.pop(0)                      # (Bt, L+1) VMEM
     fr_ref = refs.pop(0) if segment else None  # (Bt, 2) VMEM
-    bufs = tuple(refs.pop(0) for _ in tabs)    # (nslots, rows, ·) VMEM
+    bufs = tuple(refs.pop(0) for _ in tabs)    # (nslots, rows, 1, W) VMEM
     state_v, state_s, gsem, ssem = refs        # VMEM/SMEM (Bt,2), DMA sems
 
     # Walker identity for the counter-based PRNG, hoisted out of the
@@ -192,12 +197,16 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
             return 0
         jax.lax.fori_loop(0, Bc, body, 0)
 
-    def sync_state(lane0, n):
-        """Mirror lanes [lane0, lane0+n) of (cur | alive) to SMEM — DMA
-        indices must be scalars.  Cohort phases sync only their own
-        slice so they never perturb another cohort's DMA predicates."""
-        cp = pltpu.make_async_copy(state_v.at[pl.ds(lane0, n)],
-                                   state_s.at[pl.ds(lane0, n)], ssem)
+    def sync_state():
+        """Mirror (cur | alive) to SMEM — DMA indices must be scalars.
+
+        The whole (Bt, 2) tile goes at once: Mosaic refuses a row slice
+        of a 2-lane VMEM buffer (lane slices must be 128-aligned).  This
+        never perturbs another cohort's DMA predicates: a cohort's
+        ``state_v`` lanes change only in its own phase, which mirrors
+        them before it ends, so the other lanes are rewritten with the
+        values their SMEM copies already hold."""
+        cp = pltpu.make_async_copy(state_v, state_s, ssem)
         cp.start()
         cp.wait()
 
@@ -219,7 +228,7 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
         alive0 = jnp.ones((Bt, 1), jnp.bool_)
     state_v[:, 0:1] = jnp.maximum(starts, 0)
     state_v[:, 1:2] = alive0.astype(jnp.int32)
-    sync_state(0, Bt)
+    sync_state()
     if K == 1:
         gather(0, 0, "start")
     else:
@@ -236,6 +245,11 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
         lane0 = c * Bc
         sl = slice(lane0, lane0 + Bc)
         gather(slot, lane0, "wait")
+        rows = [b[slot].reshape(Bc, b.shape[-1]) for b in bufs]
+        meta = rows[0]
+        colM = jax.lax.broadcasted_iota(jnp.int32, meta.shape, 1)
+        deg = jnp.sum(jnp.where(colM == DEG_LANE, meta, 0), -1,
+                      keepdims=True)                         # (Bc, 1)
         cur = state_v[sl, 0:1]
         alive = state_v[sl, 1:2] != 0
         wid = wid_ref[sl] if segment else wid_all[sl]        # (Bc, 1)
@@ -244,14 +258,12 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
         else:
             u = uniforms_at(seed_ref[0], wid, t)
         if uniform:
-            nbr, deg = bufs[0][slot], bufs[1][slot]
-            nxt, _slt, ok = uniform_pick(nbr, deg, u[:, 2:3])
+            nxt, _slt, ok = uniform_pick(rows[1], deg, u[:, 2:3])
         else:
-            frac = bufs[5][slot] if has_frac else None
+            frac = rows[4] if has_frac else None
             nxt, _slt, ok = sample_rows(
-                bufs[0][slot], bufs[1][slot], bufs[2][slot], bufs[3][slot],
-                bufs[4][slot], u, frac, base_log2=base_log2)
-            deg = bufs[4][slot]
+                rows[1], meta, rows[2], rows[3], deg, u, frac,
+                base_log2=base_log2, num_inter=num_inter)
         # scan-step parity (core/walks.py): the deg check covers both this
         # step's deg[cur] > 0 and the previous step's deg[nxt] > 0.
         alive = alive & (deg > 0)
@@ -297,7 +309,7 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
         # samples, which is where the DMA latency actually hides.
         @pl.when(t + 1 < length)
         def _():
-            sync_state(lane0, Bc)
+            sync_state()
             gather(next_slot, lane0, "start")
 
     def step(t, _):
@@ -369,7 +381,7 @@ def walk_fused_pallas(prob, alias, bias, nbr, deg, frac, starts, seed,
         raise ValueError(
             f"fed uniforms must be (L, B, {NUM_UNIFORMS}); got {u.shape}")
     B = starts.shape[0]
-    V, C = nbr.shape
+    V = nbr.shape[0]
     has_frac = frac is not None and not uniform
     has_u = u is not None
     block_b = min(block_b, B)
@@ -401,27 +413,24 @@ def walk_fused_pallas(prob, alias, bias, nbr, deg, frac, starts, seed,
             pl.BlockSpec((length, block_b, NUM_UNIFORMS),
                          lambda i: (0, i, 0)))
         args.append(u)
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    deg2 = deg[:, None]
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    Kin = 0 if uniform else prob.shape[-1]
+    if Kin >= DEG_LANE:
+        raise ValueError(f"{Kin} inter-group entries do not fit a "
+                         f"{META_W}-lane meta row")
+    pad = jnp.zeros((V, DEG_LANE - Kin), jnp.int32)
+    meta = jnp.concatenate(
+        ([pad] if uniform else [alias.astype(jnp.int32), pad])
+        + [deg[:, None]], axis=-1)
+    tab_args = [meta] if uniform else [
+        meta, jnp.pad(prob, ((0, 0), (0, META_W - Kin)))]
+    tab_args += [nbr] if uniform else [bias, nbr]
+    if has_frac:
+        tab_args.append(frac)
+    tab_args = [t.reshape(V, 1, t.shape[-1]) for t in tab_args]
     # Per-slot scratch rows: the K=1 ping-pong needs 2 full-tile slots;
     # K >= 2 needs K cohort-sized slots — K·(Bt/K) = Bt rows total, a
     # 2x shrink of gather scratch vs. the ping-pong (DESIGN.md §8).
-    if uniform:
-        tab_args = [nbr, deg2]
-        buf_shapes = [(nslots, rows, C), (nslots, rows, 1)]
-        buf_dtypes = [jnp.int32, jnp.int32]
-    else:
-        Kin = prob.shape[-1]
-        tab_args = [prob, alias, bias, nbr, deg2]
-        buf_shapes = [(nslots, rows, Kin), (nslots, rows, Kin),
-                      (nslots, rows, C), (nslots, rows, C),
-                      (nslots, rows, 1)]
-        buf_dtypes = [jnp.float32, jnp.int32, jnp.int32, jnp.int32,
-                      jnp.int32]
-        if has_frac:
-            tab_args.append(frac)
-            buf_shapes.append((nslots, rows, C))
-            buf_dtypes.append(jnp.float32)
     in_specs += [any_spec] * len(tab_args)
     args += tab_args
 
@@ -431,7 +440,8 @@ def walk_fused_pallas(prob, alias, bias, nbr, deg, frac, starts, seed,
         out_specs.append(pl.BlockSpec((block_b, 2), lambda i: (i, 0)))
         out_shape.append(jax.ShapeDtypeStruct((B, 2), jnp.int32))
 
-    scratch = [pltpu.VMEM(s, d) for s, d in zip(buf_shapes, buf_dtypes)]
+    scratch = [pltpu.VMEM((nslots, rows, 1, t.shape[-1]), t.dtype)
+               for t in tab_args]
     scratch += [
         pltpu.VMEM((block_b, 2), jnp.int32),        # state_v: cur | alive
         pltpu.SMEM((block_b, 2), jnp.int32),        # state_s: DMA indices
@@ -440,7 +450,7 @@ def walk_fused_pallas(prob, alias, bias, nbr, deg, frac, starts, seed,
     ]
     kern = functools.partial(_kernel, length, base_log2, float(stop_prob),
                              uniform, has_frac, has_u, segment, block_b, V,
-                             cohorts)
+                             cohorts, Kin)
     out = pl.pallas_call(
         kern,
         grid=grid,

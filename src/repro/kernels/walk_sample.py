@@ -11,12 +11,13 @@ neighbor row) are gathered once into VMEM, and the whole two-stage sample
 happens in-register:
 
   stage (i)  one-hot select over the K-lane alias row (no gather unit);
-  stage (ii) *exact* intra-group pick via a bit-masked lane cumsum over the
-             C-lane bias row — selecting the ⌈u2·|G_k|⌉-th member of group
-             k in a single VPU pass.  This subsumes the paper's dense-group
-             rejection AND the gmem/inverted-index lookup: those structures
-             remain necessary for *updates*, but TPU sampling recomputes
-             membership faster than it could gather it.
+  stage (ii) *exact* intra-group pick via a bit-masked lane prefix count
+             over the C-lane bias row — selecting the ⌈u2·|G_k|⌉-th
+             member of group k in log2(C) VPU passes.  This subsumes
+             the paper's dense-group rejection AND the gmem/inverted-index
+             lookup: those structures remain necessary for *updates*, but
+             TPU sampling recomputes membership faster than it could
+             gather it.
 
 Beyond the base-2 integer fast path the kernel covers the full BINGO
 sampling space (DESIGN.md §7):
@@ -52,26 +53,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.lanes import first_lane, its_pick, lane_cumsum
 
 __all__ = ["walk_sample_pallas", "walk_sample_uniform_pallas",
            "sample_rows", "uniform_pick"]
 
 
-def _its_pick(w, x01):
-    """Exact ITS lane pass: first lane i with cumsum(w)[i] > x01·Σw.
-
-    ``w`` (Bt, C) float32 non-negative, ``x01`` (Bt, 1) in [0, 1).
-    One cumsum + one compare-reduce — a single VPU pass, no gather.
-    """
-    c = jnp.cumsum(w, axis=-1)
-    total = c[:, -1:]
-    x = x01 * total
-    idx = jnp.sum((c <= x).astype(jnp.int32), axis=-1, keepdims=True)
-    return jnp.minimum(idx, w.shape[-1] - 1)
-
-
 def sample_rows(prob, alias, bias, nbr, deg, u, frac=None, *,
-                base_log2: int = 1):
+                base_log2: int = 1, num_inter=None):
     """In-register two-stage BINGO sample on VMEM-resident rows.
 
     The shared kernel body: called on a (Bt, ·) walker tile by both the
@@ -80,10 +71,13 @@ def sample_rows(prob, alias, bias, nbr, deg, u, frac=None, *,
     freshly DMA'd rows every step.  All arguments are *values* (already
     loaded from refs): prob/alias (Bt, Kin), bias/nbr (Bt, C) int32,
     deg (Bt, 1) int32, u (Bt, ≥3|≥5) uniforms, frac (Bt, C) float32 in
-    fp mode.  Returns ``(nxt, slot, ok)`` each (Bt, 1); nxt/slot are -1
-    where ``ok`` is False (empty sampling space).
+    fp mode.  ``num_inter`` is the real alias-row width when prob/alias
+    arrive lane-padded (the megakernel's 128-lane meta rows); lanes past
+    it are never selected.  Returns ``(nxt, slot, ok)`` each (Bt, 1);
+    nxt/slot are -1 where ``ok`` is False (empty sampling space).
     """
-    Bt, Kin = prob.shape
+    Bt, W = prob.shape
+    Kin = W if num_inter is None else num_inter
     C = bias.shape[-1]
     has_frac = frac is not None
     u0, u1, u2 = u[:, 0:1], u[:, 1:2], u[:, 2:3]          # (Bt, 1)
@@ -91,7 +85,7 @@ def sample_rows(prob, alias, bias, nbr, deg, u, frac=None, *,
     # stage (i): alias pick over the Kin-lane row, gather-free one-hot
     # selects.  Kin counts the K radix groups plus, in fp mode, the
     # decimal group appended by build_itable_rows.
-    colK = jax.lax.broadcasted_iota(jnp.int32, (Bt, Kin), 1)
+    colK = jax.lax.broadcasted_iota(jnp.int32, (Bt, W), 1)
     i = jnp.minimum((u0 * Kin).astype(jnp.int32), Kin - 1)  # (Bt, 1)
     at_i = colK == i
     p_i = jnp.sum(jnp.where(at_i, prob, 0.0), -1, keepdims=True)
@@ -111,12 +105,12 @@ def sample_rows(prob, alias, bias, nbr, deg, u, frac=None, *,
     mi = member.astype(jnp.int32)
     gsize = mi.sum(-1, keepdims=True)
 
-    # uniform member pick via masked lane cumsum (exact for base 2 —
-    # every member carries the same sub-bias 2^k, Eq. 6)
+    # uniform member pick via masked lane prefix count (exact for base 2
+    # — every member carries the same sub-bias 2^k, Eq. 6).  No member
+    # is hit only when the group is empty, where ``ok`` masks the slot.
     target = jnp.minimum((u2 * gsize).astype(jnp.int32), gsize - 1) + 1
-    cum = jnp.cumsum(mi, axis=-1)
-    hit = member & (cum == target)
-    slot = jnp.argmax(hit, axis=-1)[:, None].astype(jnp.int32)  # (Bt, 1)
+    cum = lane_cumsum(mi, pltpu.roll)
+    slot = first_lane(member & (cum == target))                 # (Bt, 1)
 
     if base_log2 > 1:
         # digit-proportional acceptance (§9.2): the uniform pick is only a
@@ -126,7 +120,7 @@ def sample_rows(prob, alias, bias, nbr, deg, u, frac=None, *,
         dig_c = jnp.sum(jnp.where(colC == slot, dig, 0), -1, keepdims=True)
         accept = u3 * jnp.float32((1 << base_log2) - 1) < dig_c.astype(
             jnp.float32)
-        slot_its = _its_pick(dig.astype(jnp.float32), u4)
+        slot_its = its_pick(dig.astype(jnp.float32), u4, pltpu.roll)
         slot = jnp.where(accept, slot, slot_its)
     ok = gsize > 0
 
@@ -134,9 +128,10 @@ def sample_rows(prob, alias, bias, nbr, deg, u, frac=None, *,
         # decimal group (§4.3): exact ITS over the gathered frac row
         u4 = u[:, 4:5]
         wf = jnp.where(valid, frac, 0.0)
-        slot_dec = _its_pick(wf, u4)
+        slot_dec = its_pick(wf, u4, pltpu.roll)
         slot = jnp.where(is_dec, slot_dec, slot)
-        ok = jnp.where(is_dec, wf.sum(-1, keepdims=True) > 0, ok)
+        # bool logic, not a select on bools (Mosaic cannot lower that)
+        ok = (is_dec & (wf.sum(-1, keepdims=True) > 0)) | (~is_dec & ok)
 
     nxt = jnp.sum(jnp.where(colC == slot, nbr, 0), -1, keepdims=True)
     return (jnp.where(ok, nxt, -1), jnp.where(ok, slot, -1), ok)

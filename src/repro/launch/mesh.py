@@ -8,23 +8,19 @@ forces the 512-host-device XLA flag, and only in its own process).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh"]
-
-
-def _axis_kwargs(n: int) -> dict:
-    # jax >= 0.5 wants explicit axis types; 0.4.x has no AxisType at all.
-    t = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (t.Auto,) * n} if t is not None else {}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh():
     """Whatever devices exist right now (elastic launch path)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"), **_axis_kwargs(2))
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

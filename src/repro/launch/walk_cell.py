@@ -146,14 +146,12 @@ def build_walk_cell(shape_name: str, mesh, overrides: dict) -> CellSpec:
             arrived, _leftover, _overflow = exchange_walkers(
                 nxt, shard_size, num_shards, axis=dp)
             return arrived
-
-        from jax.experimental.shard_map import shard_map
-        walk_step = shard_map(
+        walk_step = jax.shard_map(
             walk_step_local, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(dp), sspecs,
                                    is_leaf=lambda s: isinstance(s, P)),
                       P(dp), P()),
-            out_specs=P(dp), check_rep=False)
+            out_specs=P(dp), check_vma=False)
 
         return CellSpec(
             arch="bingo-walk", shape_name=shape_name, kind="prefill",
@@ -206,14 +204,12 @@ def build_walk_cell(shape_name: str, mesh, overrides: dict) -> CellSpec:
             return sampler.sample_walk(
                 state, bcfg, jnp.clip(local, 0, shard_size - 1), key,
                 wparams)
-
-        from jax.experimental.shard_map import shard_map
-        walk_whole = shard_map(
+        walk_whole = jax.shard_map(
             walk_whole_local, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(dp), sspecs,
                                    is_leaf=lambda s: isinstance(s, P)),
                       P(dp), P()),
-            out_specs=P(dp), check_rep=False)
+            out_specs=P(dp), check_vma=False)
 
         return CellSpec(
             arch="bingo-walk", shape_name=shape_name, kind="prefill",
@@ -411,9 +407,7 @@ def build_walk_cell(shape_name: str, mesh, overrides: dict) -> CellSpec:
             paths = jnp.where(resident[:, None] & (paths >= 0),
                               paths + lo, -1)
             return st, paths, stats
-
-        from jax.experimental.shard_map import shard_map
-        update_walk = shard_map(
+        update_walk = jax.shard_map(
             update_walk_local, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(dp), sspecs,
                                    is_leaf=lambda s: isinstance(s, P)),
@@ -421,7 +415,7 @@ def build_walk_cell(shape_name: str, mesh, overrides: dict) -> CellSpec:
             out_specs=(jax.tree.map(lambda _: P(dp), sspecs,
                                     is_leaf=lambda s: isinstance(s, P)),
                        P(dp), P()),
-            check_rep=False)
+            check_vma=False)
 
         upd_sds = (jax.ShapeDtypeStruct((Bu,), jnp.bool_),
                    jax.ShapeDtypeStruct((Bu,), jnp.int32),

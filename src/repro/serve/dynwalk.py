@@ -50,13 +50,54 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.dyngraph import BingoConfig, BingoState, regrow_state
+from repro.core.dyngraph import (BingoConfig, BingoState, from_edges,
+                                 regrow_state)
 from repro.core.updates import NUM_REASONS, R_OK, UpdateStats, make_updater
 from repro.core.walks import WalkParams, make_walker
 from repro.graph.streams import UpdateStream, rounds_on_device
 from repro.serve.guard import GuardPolicy, IngestGuard
 
-__all__ = ["DynamicWalkEngine"]
+__all__ = ["DynamicWalkEngine", "sharded_from_edges"]
+
+
+def sharded_from_edges(cfg: BingoConfig, src, dst, bias, mesh,
+                       walker_axes=()) -> BingoState:
+    """``from_edges`` built in place across a mesh (the ``mesh=`` engine's
+    state), never whole on one device.
+
+    Every vertex shard takes the full edge list, keeps the edges whose
+    source it owns (re-based to its local ids; the rest go to the
+    out-of-range row ``shard_size``, which the scatters drop) and builds
+    its rows with ``from_edges`` at the shard-local size.  A stable sort
+    keeps each row in edge-list order, so shard ``s`` holds exactly rows
+    ``[s·shard_size, (s+1)·shard_size)`` of the single-device build.
+    """
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed.relay import shard_index
+    waxes = (walker_axes,) if isinstance(walker_axes, str) \
+        else tuple(walker_axes)
+    vaxes = tuple(a for a in mesh.axis_names if a not in waxes)
+    S = 1
+    for a in vaxes:
+        S *= mesh.shape[a]
+    if cfg.num_vertices % S:
+        raise ValueError(f"V={cfg.num_vertices} does not divide over "
+                         f"{S} vertex shards")
+    size = cfg.num_vertices // S
+    lcfg = dataclasses.replace(cfg, num_vertices=size)
+
+    def build(s, d, w):
+        lo = shard_index(mesh, vaxes) * size
+        own = (s >= lo) & (s < lo + size)
+        return from_edges(lcfg, jnp.where(own, s - lo, size), d, w)
+
+    spec = jax.tree.map(lambda leaf: P(vaxes, *([None] * (leaf.ndim - 1))),
+                        jax.eval_shape(lambda: from_edges(
+                            lcfg, src[:1], dst[:1], bias[:1])))
+    fn = jax.shard_map(build, mesh=mesh, in_specs=(P(), P(), P()),
+                       out_specs=spec, check_vma=False)
+    return jax.jit(fn)(jnp.asarray(src, jnp.int32),
+                       jnp.asarray(dst, jnp.int32), jnp.asarray(bias))
 
 
 class DynamicWalkEngine:
@@ -192,7 +233,6 @@ class DynamicWalkEngine:
         schedule — whose stitched (W, L+1) paths are bit-equal to the
         single-device whole walk for the same key.
         """
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.backend import get_backend
         from repro.distributed.relay import make_relay, shard_index
@@ -218,9 +258,9 @@ class DynamicWalkEngine:
             return st, jax.tree.map(
                 lambda t: jax.lax.psum(t, axis_name=vaxes), stats)
 
-        smap_upd = shard_map(update_local, mesh=mesh,
-                             in_specs=(sspec, P(), P(), P(), P(), P()),
-                             out_specs=(sspec, P()), check_rep=False)
+        smap_upd = jax.shard_map(update_local, mesh=mesh,
+                                 in_specs=(sspec, P(), P(), P(), P(), P()),
+                                 out_specs=(sspec, P()), check_vma=False)
 
         update = jax.jit(smap_upd, donate_argnums=0)
 
@@ -248,14 +288,13 @@ class DynamicWalkEngine:
                     lambda st: regrow_state(st, tcfg, ncfg),
                     donate_argnums=0)
             else:
-                from jax.experimental.shard_map import shard_map
                 shard_size = tcfg.num_vertices // self._num_vshards
                 lcfg = dataclasses.replace(tcfg, num_vertices=shard_size)
                 lncfg = dataclasses.replace(ncfg, num_vertices=shard_size)
                 sspec = self._sspec()
-                fn = shard_map(lambda st: regrow_state(st, lcfg, lncfg),
-                               mesh=self._mesh, in_specs=(sspec,),
-                               out_specs=sspec, check_rep=False)
+                fn = jax.shard_map(lambda st: regrow_state(st, lcfg, lncfg),
+                                   mesh=self._mesh, in_specs=(sspec,),
+                                   out_specs=sspec, check_vma=False)
                 self._regrow_progs[t] = jax.jit(fn, donate_argnums=0)
         return self._regrow_progs[t]
 

@@ -6,7 +6,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core.alias import alias_probs, build_alias, sample_alias
+from repro.core.alias import _div_rn, alias_probs, build_alias, sample_alias
 from tests.conftest import empirical_dist, tv_distance
 
 
@@ -42,3 +42,27 @@ def test_alias_sampling_empirical():
 def test_degenerate_single_entry():
     t = build_alias(jnp.array([[7.0]]))
     np.testing.assert_allclose(np.asarray(alias_probs(t))[0], [1.0])
+
+
+@pytest.mark.parametrize("kind", ["wide", "integer", "near_one"])
+def test_integer_division_is_ieee_division(kind):
+    """The alias build divides with integer ops so every backend and
+    every program shape gets the same bits; those bits must be IEEE
+    round-to-nearest-even quotients (subnormal results flush to 0)."""
+    rng = np.random.default_rng(len(kind))
+    N = 200_000
+    if kind == "wide":
+        a = rng.random(N) * 10.0 ** rng.integers(-18, 18, N)
+        b = rng.random(N) * 10.0 ** rng.integers(-18, 18, N) + 1e-30
+    elif kind == "integer":
+        a = rng.integers(0, 1 << 26, N) * 16.0
+        b = rng.integers(1, 1 << 30, N) * 1.0
+    else:
+        b = rng.integers(1, 1 << 24, N) * 1.0
+        a = b - rng.integers(0, 2, N)
+    a, b = a.astype(np.float32), b.astype(np.float32)
+    got = np.asarray(jax.jit(_div_rn)(a, b))
+    with np.errstate(under="ignore"):
+        want = a / b
+    want[np.abs(want) < np.finfo(np.float32).tiny] = 0.0
+    np.testing.assert_array_equal(got.view(np.int32), want.view(np.int32))

@@ -100,16 +100,15 @@ def test_partition_1d():
 def test_exchange_walkers_single_shard_semantics():
     """num_shards=1: routing reduces to sort-compact of live walkers."""
     mesh = jax.make_mesh((1,), ("data",))
-    from jax.experimental.shard_map import shard_map
 
     W = 16
     walkers = jnp.array([5, -1, 3, -1, 7, 2, -1, 9] + [-1] * 8, jnp.int32)
 
-    f = shard_map(
+    f = jax.shard_map(
         lambda w: exchange_walkers(w, shard_size=100, num_shards=1,
                                    axis="data"),
         mesh=mesh, in_specs=(P("data"),), out_specs=(P("data"),) * 2 + (P(),),
-        check_rep=False)
+        check_vma=False)
     out, leftover, overflow = f(walkers)
     out = np.asarray(out)
     live = sorted(x for x in out.tolist() if x >= 0)
@@ -124,7 +123,6 @@ def test_exchange_multifield_overflow_conservation():
     cap, sent multiset == arrived ∪ leftover (satellite: conservation),
     and traffic <= cap loses nothing."""
     mesh = jax.make_mesh((1,), ("data",))
-    from jax.experimental.shard_map import shard_map
 
     rng = np.random.default_rng(0)
     W = 16
@@ -138,11 +136,11 @@ def test_exchange_multifield_overflow_conservation():
     sent = {tuple(r) for r in rows.tolist() if r[0] >= 0}
 
     for cap in (None, 2, 1):
-        f = shard_map(
+        f = jax.shard_map(
             lambda p: exchange_walkers(p, shard_size=100, num_shards=1,
                                        axis="data", cap=cap),
             mesh=mesh, in_specs=(P("data"),),
-            out_specs=(P("data"),) * 2 + (P(),), check_rep=False)
+            out_specs=(P("data"),) * 2 + (P(),), check_vma=False)
         arrived, leftover, overflow = f(payload)
         got = {tuple(r) for r in np.asarray(arrived).tolist() if r[0] >= 0}
         kept = {tuple(r) for r in np.asarray(leftover).tolist() if r[0] >= 0}
@@ -163,7 +161,6 @@ def test_exchange_multifield_overflow_conservation():
 def test_exchange_multishard_routing_and_conservation():
     """4 shards: every routed record lands on its destination vertex's
     owner, and arrived ∪ leftover over ALL shards is the sent multiset."""
-    from jax.experimental.shard_map import shard_map
 
     S, shard_size, Wl = 4, 8, 12
     mesh = jax.make_mesh((S,), ("data",))
@@ -181,9 +178,9 @@ def test_exchange_multishard_routing_and_conservation():
                 p, shard_size=shard_size, num_shards=S, axis="data",
                 cap=cap)
             return arrived, leftover, overflow[None]   # (1,) per shard
-        f = shard_map(
+        f = jax.shard_map(
             route, mesh=mesh, in_specs=(P("data"),),
-            out_specs=(P("data"), P("data"), P("data")), check_rep=False)
+            out_specs=(P("data"), P("data"), P("data")), check_vma=False)
         arrived, leftover, overflow = f(payload)
         arrived = np.asarray(arrived).reshape(S, -1, 3)
         for s in range(S):
@@ -196,3 +193,27 @@ def test_exchange_multishard_routing_and_conservation():
         assert int(np.asarray(overflow).sum()) == len(kept)
         if cap == Wl:
             assert len(kept) == 0    # traffic <= cap: no walker lost
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_sharded_from_edges_matches_single_device_build(shards):
+    """The mesh engine's state built in place on every shard equals the
+    single-device ``from_edges`` build, row for row."""
+    from repro.core.dyngraph import BingoConfig, from_edges
+    from repro.graph.rmat import degree_bias, rmat_edges
+    from repro.serve.dynwalk import sharded_from_edges
+    if len(jax.devices()) < shards:
+        pytest.skip(f"needs {shards} devices "
+                    "(XLA_FLAGS=--xla_force_host_platform_device_count)")
+    src, dst = rmat_edges(8, 8, seed=1)
+    w = degree_bias(src, dst, 256, bias_bits=8)
+    cfg = BingoConfig(num_vertices=256, capacity=16, bias_bits=8)
+    mesh = jax.make_mesh((shards,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=jax.devices()[:shards])
+    built = sharded_from_edges(cfg, src, dst, w, mesh)
+    assert sorted(x.data.shape[0] for x in built.nbr.addressable_shards) \
+        == [256 // shards] * shards
+    for a, b in zip(jax.tree.leaves(from_edges(cfg, src, dst, w)),
+                    jax.tree.leaves(built)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

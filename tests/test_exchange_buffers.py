@@ -20,7 +20,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.walker_exchange import (exchange_walkers,
@@ -89,10 +88,10 @@ def _make_driver(mesh, num_shards, shard_size, rounds, cap=None,
             jnp.arange(rounds, dtype=jnp.int32))
         return resident, inflight, stats
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(AXIS), P(AXIS), P(AXIS)),
-                     out_specs=(P(AXIS), P(AXIS), P()),
-                     check_rep=False)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(AXIS), P(AXIS), P(AXIS)),
+                         out_specs=(P(AXIS), P(AXIS), P()),
+                         check_vma=False)
 
 
 def _rows(num_shards, per_shard, rows_per_shard, dest_fn):

@@ -16,7 +16,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import walks
@@ -221,10 +220,10 @@ def test_relay_round_is_one_pallas_call_per_shard(num_shards):
                            num_shards=num_shards, shard_size=shard_size,
                            axis="data")
 
-    f = shard_map(local, mesh=mesh,
-                  in_specs=(jax.tree.map(lambda _: P("data"), st), P(),
-                            P()),
-                  out_specs=(P("data"), P(), P()), check_rep=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(jax.tree.map(lambda _: P("data"), st), P(),
+                                P()),
+                      out_specs=(P("data"), P(), P()), check_vma=False)
     jaxpr = jax.make_jaxpr(f)(st, walkers, seed)
     # all pallas_calls live inside the relay while-loop, exactly one
     # (shard_map traces one per-shard SPMD program: 1 launch per shard)

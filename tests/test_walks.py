@@ -96,3 +96,21 @@ def test_walks_are_deterministic_given_key():
     a = walks.deepwalk(st, cfg, starts, jax.random.key(3), length=8)
     b = walks.deepwalk(st, cfg, starts, jax.random.key(3), length=8)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_row_neighbors_matches_per_candidate_membership():
+    """node2vec's exact fallback asks, for every slot of the current row,
+    whether that neighbor is adjacent to the previous vertex; the sorted
+    binary search must answer exactly as the per-candidate row compare,
+    including padding (-1) candidates and rows with duplicates."""
+    cfg = BingoConfig(num_vertices=16, capacity=8, bias_bits=4)
+    rng = np.random.default_rng(5)
+    src = rng.integers(0, 16, 90).astype(np.int32)
+    dst = rng.integers(0, 16, 90).astype(np.int32)
+    state = from_edges(cfg, src, dst, np.ones(90, np.int32))
+    prev = jnp.asarray(rng.integers(0, 16, 32), jnp.int32)
+    cands = state.nbr[jnp.asarray(rng.integers(0, 16, 32), jnp.int32)]
+    got = walks._row_neighbors(state, cfg, prev, cands)
+    want = jnp.stack([walks._is_neighbor(state, cfg, prev, cands[:, j])
+                      for j in range(cfg.capacity)], axis=1)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
