@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from repro.core import radix
 from repro.core.alias import AliasTable, build_alias
-from repro.core.lanes import lane_cumsum
+from repro.core.lanes import lane_total
 
 __all__ = [
     "EMPTY", "DENSE", "ONE", "SPARSE", "REGULAR",
@@ -206,11 +206,11 @@ def build_vertex_groups(cfg: BingoConfig, bias_row, frac_row, deg):
     else:
         ginv = jnp.where(member, pos, -1).T.astype(jnp.int32)  # (K, C)
 
-    # Decimal-group weight: the last lane of the log-step prefix sum, so
-    # the update kernel (which adds in the same order) matches it bit for
-    # bit.  Integer-bias states keep every frac at 0.
+    # Decimal-group weight: ``lanes.lane_total``, the form the update
+    # kernel takes, so the two match bit for bit.  Integer-bias states
+    # keep every frac at 0.
     if cfg.fp_bias:
-        wdec = lane_cumsum(jnp.where(valid, frac_row, 0.0), jnp.roll)[-1]
+        wdec = lane_total(jnp.where(valid, frac_row, 0.0), jnp.roll)[0]
     else:
         wdec = jnp.zeros((), jnp.float32)
     return gmem, ginv, gsize, digitsum, gtype, wdec
@@ -297,13 +297,12 @@ def _group_rows(cfg: BingoConfig, bias, frac, deg, chunk: int):
                         outs)
 
 
-def from_edges(cfg: BingoConfig, src, dst, bias, *,
-               chunk: int = 4096) -> BingoState:
+def from_edges(cfg: BingoConfig, src, dst, bias) -> BingoState:
     """Construct the full BINGO sampling space from an edge list.
 
     ``bias`` is int for integer mode; float for fp mode (λ-scaled per §4.3).
     Fully vectorized — no per-edge host loop; the group rows are built in
-    ``chunk``-row tiles (``_group_rows``) so a one-chip-sized graph builds
+    4,096-row tiles (``_group_rows``) so a one-chip-sized graph builds
     on the chip without materializing ``(V, C, K)`` intermediates.
     """
     src = jnp.asarray(src, jnp.int32)
@@ -315,7 +314,7 @@ def from_edges(cfg: BingoConfig, src, dst, bias, *,
         w_frac = jnp.zeros_like(src, dtype=jnp.float32)
     nbr, b, f, deg = _scatter_adjacency(cfg, src, dst, w_int, w_frac)
     gmem, ginv, gsize, digitsum, gtype, wdec = _group_rows(cfg, b, f, deg,
-                                                           chunk)
+                                                           4096)
     itable = build_itable_rows(cfg, digitsum, wdec)
     return BingoState(nbr, b, f, deg, gmem, ginv, gsize, digitsum, wdec,
                       gtype, itable)

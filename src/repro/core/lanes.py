@@ -15,7 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["lane_cumsum", "first_lane", "its_pick"]
+__all__ = ["lane_cumsum", "lane_total", "first_lane", "its_pick"]
 
 
 def lane_cumsum(x, roll):
@@ -36,6 +36,18 @@ def lane_cumsum(x, roll):
     return x
 
 
+def lane_total(x, roll):
+    """(…, 1) total of ``x`` along the last axis: the largest lane of its
+    log-step prefix sum.
+
+    The float prefix is not monotone — the last lane and an earlier one
+    add the same values in different orders and can differ by an ulp —
+    so every kernel and oracle that needs a lane total takes this one
+    form, and they agree bit for bit.
+    """
+    return jnp.max(lane_cumsum(x, roll), axis=-1, keepdims=True)
+
+
 def first_lane(mask):
     """(…, 1) index of the first set lane of ``mask``; n where none is."""
     n = mask.shape[-1]
@@ -47,8 +59,8 @@ def its_pick(w, x01, roll):
     """Exact ITS lane pass: first lane i with prefix(w)[i] > x01·Σw.
 
     ``w`` (B, C) float32 non-negative, ``x01`` (B, 1) in [0, 1).  The
-    total is the largest prefix (the prefix of non-negative weights is
-    monotone), so it is the last lane's value exactly.
+    total is ``lane_total``'s: the largest prefix, which can exceed the
+    last lane by an ulp; the pick is clamped to the last lane.
     """
     c = lane_cumsum(w, roll)
     x = x01 * jnp.max(c, axis=-1, keepdims=True)

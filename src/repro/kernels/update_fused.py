@@ -78,7 +78,7 @@ from repro.core import radix
 from repro.core.alias import AliasTable
 from repro.core.dyngraph import (DENSE, BingoConfig, BingoState,
                                  build_itable_rows, classify)
-from repro.core.lanes import lane_cumsum
+from repro.core.lanes import lane_cumsum, lane_total
 from repro.core.updates import (NUM_REASONS, R_ABSENT, R_CAPACITY, R_VERTEX,
                                 UpdateStats, _padded_unique)
 
@@ -268,7 +268,7 @@ def _rebuild_tile(cfg, Rt, Dp, slot, nd, deg0_ref, deg1_ref, insn_ref,
     if fp:
         frac3 = jnp.where(keep, moved[2], 0.0)
         row_out[2][...] = frac3.reshape(Rt, 1, C)
-        wdec = jnp.max(lane_cumsum(frac3, roll), -1, keepdims=True)
+        wdec = lane_total(frac3, roll)
     else:
         wdec = jnp.zeros((Rt, 1), jnp.float32)
 

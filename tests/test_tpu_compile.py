@@ -25,7 +25,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core.dyngraph import BingoConfig, empty_state, from_edges
+from repro.core import radix
+from repro.core.dyngraph import (BingoConfig, _group_rows, _scatter_adjacency,
+                                 empty_state)
 from repro.graph.rmat import degree_bias, rmat_edges
 from repro.kernels.update_fused import update_fused_pallas
 from repro.kernels.walk_fused import walk_fused_pallas
@@ -155,20 +157,25 @@ def test_update_fused_compiles(one_chip, capacity, adaptive, fp):
 @pytest.mark.parametrize("V_,adaptive,fp", [
     (256, True, False), (200, True, True), (256, False, False)])
 def test_from_edges_tiled_build_matches_untiled(V_, adaptive, fp):
-    """``from_edges`` builds the group rows in ``chunk``-row tiles (the
-    row axis padded to whole tiles); every leaf must match the one-shot
-    build bit for bit, including a V that is not a multiple of the
-    tile."""
+    """``from_edges`` builds the group rows in row tiles
+    (``_group_rows``, the row axis padded to whole tiles); every output
+    must match the one-shot build bit for bit, including a V that is not
+    a multiple of the tile."""
     scale = int(np.ceil(np.log2(V_)))
     src, dst = rmat_edges(scale, 8, seed=3)
     keep = (src < V_) & (dst < V_)
     src, dst = src[keep], dst[keep]
     w = degree_bias(src, dst, V_, bias_bits=8)
-    if fp:
-        w = w.astype(np.float32) / 3.0
     cfg = BingoConfig(num_vertices=V_, capacity=32, bias_bits=8,
                       adaptive=adaptive, fp_bias=fp)
-    whole = from_edges(cfg, src, dst, w, chunk=V_)
-    tiled = from_edges(cfg, src, dst, w, chunk=48)
+    if fp:
+        w_int, w_frac = radix.decompose_fp(w.astype(np.float32) / 3.0,
+                                           cfg.lam)
+    else:
+        w_int, w_frac = jnp.asarray(w), jnp.zeros(len(w), jnp.float32)
+    _, b, f, deg = _scatter_adjacency(cfg, jnp.asarray(src),
+                                      jnp.asarray(dst), w_int, w_frac)
+    whole = _group_rows(cfg, b, f, deg, chunk=V_)
+    tiled = _group_rows(cfg, b, f, deg, chunk=48)
     for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(tiled)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

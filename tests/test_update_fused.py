@@ -84,6 +84,29 @@ def test_bit_exact_vs_reference(mode, adaptive, fp, base_log2):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("mode", ["insert", "delete", "mixed"])
+def test_bit_exact_fp_full_mantissa(mode):
+    """fp biases drawn from (0, 1): the λ-scaled decimal parts use the
+    whole mantissa, so a 64-lane decimal-group sum rounds and depends on
+    the order of its additions — the kernel's W_D must still equal the
+    reference's bit for bit."""
+    V, C = 96, 64
+    rng = np.random.default_rng(11)
+    cfg = BingoConfig(num_vertices=V, capacity=C, bias_bits=6,
+                      adaptive=True, fp_bias=True)
+    src, dst, _ = random_graph(V, C, seed=5, density=0.9)
+    w = rng.random(len(src)).astype(np.float32)
+    st_ref = from_edges(cfg, src, dst, w)
+    st_pal = st_ref
+    edges = list(zip(src.tolist(), dst.tolist()))
+    for _ in range(3):
+        batch = _round(rng, V, edges, 24, mode)
+        batch = batch[:3] + (jnp.asarray(rng.random(24).astype(np.float32)),)
+        st_ref, _ = batched_update(st_ref, cfg, *batch)
+        st_pal, _ = update_fused(st_pal, cfg, *batch)
+        assert_states_equal(st_ref, st_pal)
+
+
 def test_bit_exact_all_group_types():
     """The hub row spans DENSE/ONE/SPARSE/REGULAR before the round, and
     the batch forces transitions — gmem compaction, ginv-free GA locate,
