@@ -14,13 +14,11 @@ Two measurement modes, selected by ``run.py``:
     Mosaic megakernel at each K; on CPU (where pallas is interpret-only)
     the fused rows route through the jnp megawalk oracle — which is
     cohort-invariant by construction, so the K rows bracket measurement
-    noise rather than a kernel difference (the CI guard compares them
-    with tolerance for exactly this reason) — the interpret-only paths
+    noise rather than a kernel difference — the interpret-only paths
     (pallas-step, pallas-fused legacy row) are pruned, while the relay
     rows switch to the XLA-compiled reference segment so the bulk vs
     overlapped comparison (``round_ms`` / ``overlap_efficiency``
-    extras, gated by ``guard.py --mode relay``) is always measured on
-    compiled programs.
+    extras) is always measured on compiled programs.
 
 The sweep threads ONE donated ``BingoState`` copy through every timed
 case (``common.walk_rate``'s ``donated=`` contract) so the tables are
@@ -117,8 +115,7 @@ def _relay_backend():
     """Backend for the relay rows: the pallas megakernel on TPU (or in
     interpret mode), the XLA-compiled jnp segment on compiled CPU —
     bit-identical outputs either way, so compiled CPU snapshots get
-    real relay rows instead of a pruned hole (the ``--mode relay``
-    guard gates on them)."""
+    real relay rows instead of a pruned hole."""
     from repro.kernels.ops import on_tpu
     return "reference" if common.COMPILED and not on_tpu() else "pallas"
 
@@ -161,79 +158,6 @@ def relay_rate(state, cfg, params, starts, *, seed: int = 0,
     return rate, int(rounds), int(peak), round_ms
 
 
-def relay_phase_times(state, cfg, params, starts, *, seed: int = 0,
-                      reps: int = 5):
-    """Host-driver capture of per-phase relay device time (ms).
-
-    Compiles the two round phases as standalone programs at the relay's
-    exact shapes — one resumable segment launch over the Wl compacted
-    slots per shard, and one round's walker + path-record all_to_alls —
-    and times each under the jitted-call protocol.  segment_ms vs
-    exchange_ms is the number that says how much a round COULD gain
-    from overlapping them (perfect overlap hides min(seg, exch)); the
-    measured ``round_ms`` ratio says how much it DID."""
-    from jax.sharding import PartitionSpec as P
-    from repro.core.backend import get_backend
-    from repro.distributed.relay import relay_view, slot_count
-    from repro.distributed.walker_exchange import exchange_walkers
-    from repro.kernels.ops import seed_from_key
-
-    S = len(jax.devices())
-    W = starts.shape[0]
-    if cfg.num_vertices % S or W % S:
-        S = 1
-    mesh = jax.make_mesh((S,), ("data",))
-    shard_size = cfg.num_vertices // S
-    Wl = slot_count(W, S)
-    L = params.length
-    bk = get_backend(_relay_backend())
-    import dataclasses as _dc
-    lcfg = _dc.replace(cfg, num_vertices=shard_size)
-
-    def seg_local(st, sd):
-        sidx = jax.lax.axis_index("data")
-        view = relay_view(st, sidx * shard_size, shard_size)
-        slot_cur = jnp.arange(Wl, dtype=jnp.int32) % shard_size
-        slot_wid = jnp.arange(Wl, dtype=jnp.int32) + sidx * Wl
-        paths, frontier = bk.sample_walk_segment(
-            view, lcfg, slot_cur, jnp.zeros((Wl,), jnp.int32), sd,
-            params, wid=slot_wid)
-        return paths, frontier
-
-    def exch_local(wpay, ppay):
-        a_w, l_w, o_w = exchange_walkers(wpay, shard_size, S, "data")
-        a_p, l_p, o_p = exchange_walkers(ppay, shard_size, S, "data")
-        return a_w, a_p, o_w + o_p
-
-    sspec = jax.tree.map(lambda _: P("data"), state,
-                         is_leaf=lambda x: hasattr(x, "ndim"))
-    seg = jax.jit(jax.shard_map(seg_local, mesh=mesh,
-                                in_specs=(sspec, P()), out_specs=P("data"),
-                                check_vma=False))
-    exch = jax.jit(jax.shard_map(exch_local, mesh=mesh,
-                                 in_specs=(P("data"), P("data")),
-                                 out_specs=(P("data"), P("data"), P()),
-                                 check_vma=False))
-
-    sd = seed_from_key(jax.random.key(seed))
-    wpay = jnp.stack([starts % cfg.num_vertices,
-                      jnp.zeros((W,), jnp.int32),
-                      jnp.arange(W, dtype=jnp.int32)], axis=-1)
-    ppay = jnp.full((S * Wl, L + 4), 1, jnp.int32).at[:, 0].set(
-        jnp.arange(S * Wl, dtype=jnp.int32) % cfg.num_vertices)
-
-    def _time(fn, *args):
-        jax.block_until_ready(fn(*args))          # warmup/compile
-        ts = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            ts.append(time.perf_counter() - t0)
-        return float(np.median(ts)) * 1e3
-
-    return _time(seg, state, sd), _time(exch, wpay, ppay)
-
-
 def main():
     from repro.kernels.ops import on_tpu
     scale = MICRO_SCALE if common.MICRO else SCALE
@@ -266,13 +190,11 @@ def main():
                    rate)
         # relay rows run in EVERY mode: on compiled CPU they route
         # through the XLA-compiled reference segment (_relay_backend)
-        # instead of being pruned, so the --mode relay guard always has
-        # a snapshot to gate.  Per-kind bulk + overlapped rows, plus the
-        # per-phase host-driver capture and the overlap_efficiency
-        # extra = bulk_round_ms / overlap_round_ms (the tentpole's win,
-        # measured per ROUND — overlap trades extra crossing-latency
-        # rounds for collectives off the critical path, so steps/s at
-        # micro scale would mis-score it).
+        # instead of being pruned.  Per-kind bulk + overlapped rows and
+        # the overlap_efficiency extra = bulk_round_ms /
+        # overlap_round_ms, measured per ROUND: overlap trades extra
+        # crossing-latency rounds for collectives off the critical
+        # path, so steps/s at micro scale would mis-score it.
         S_here = len(jax.devices())
         rate, rounds, peak, round_ms = relay_rate(st, cfg, params, starts)
         record("walks", f"{kind}-relay", "steps_per_sec", rate)
@@ -291,9 +213,6 @@ def main():
                round_ms / max(o_round_ms, 1e-9))
         record("walks", f"{kind}-relay-overlap", "mesh_sv", S_here)
         record("walks", f"{kind}-relay-overlap", "mesh_sw", 1)
-        seg_ms, exch_ms = relay_phase_times(st, cfg, params, starts)
-        record("walks", f"{kind}-relay", "segment_ms", seg_ms)
-        record("walks", f"{kind}-relay", "exchange_ms", exch_ms)
 
 
 if __name__ == "__main__":

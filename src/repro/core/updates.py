@@ -457,13 +457,15 @@ def make_updater(cfg: BingoConfig, backend: Optional[str] = None,
     from repro.core.backend import get_backend
     bk = get_backend(cfg.backend if backend is None else backend)
 
+    # The program is named ``engine_update`` in traces, on one device
+    # and on a mesh (``serve/dynwalk.py``) alike.
     if with_active:
         @functools.partial(jax.jit, donate_argnums=0)
-        def run(st, is_insert, u, v, w, active):
+        def engine_update(st, is_insert, u, v, w, active):
             return bk.apply_updates(st, cfg, is_insert, u, v, w,
                                     active=active)
     else:
         @functools.partial(jax.jit, donate_argnums=0)
-        def run(st, is_insert, u, v, w):
+        def engine_update(st, is_insert, u, v, w):
             return bk.apply_updates(st, cfg, is_insert, u, v, w)
-    return run
+    return engine_update

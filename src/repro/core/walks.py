@@ -261,8 +261,9 @@ def make_walker(state: BingoState, cfg: BingoConfig, params: WalkParams,
     never copy them — callers rebind ``st, path = run(st, starts, key)``
     (``benchmarks/common.py:walk_rate``).
     """
+    # named ``engine_walk`` in traces, like the relay walk on a mesh
     @functools.partial(jax.jit, donate_argnums=0)
-    def run(st, starts, key):
+    def engine_walk(st, starts, key):
         return st, random_walk(st, cfg, starts, key, params,
                                backend=backend, whole_walk=whole_walk)
-    return run
+    return engine_walk

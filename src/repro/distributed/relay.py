@@ -404,27 +404,28 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
         # path row).  Placement order never affects the result: every
         # per-walker quantity downstream is keyed by the wid the slot
         # carries, not by the slot index.
-        free = pend_wid < 0
-        forder = jnp.argsort(~free)             # free slot indices first
-        nfree = free.sum(dtype=jnp.int32)
-        ws = _compact_rows(waiting, W)
-        k = jnp.arange(W, dtype=jnp.int32)
-        place = (k < nfree) & (ws[:, 0] >= 0)
-        tgt = jnp.where(place, forder[jnp.minimum(k, Wl - 1)], Wl)
-        slot_wid = jnp.full((Wl,), -1, jnp.int32).at[tgt].set(
-            ws[:, 2], mode="drop")
-        slot_cur = jnp.full((Wl,), -1, jnp.int32).at[tgt].set(
-            ws[:, 0] - lo, mode="drop")
-        slot_t0 = jnp.zeros((Wl,), jnp.int32).at[tgt].set(
-            ws[:, 1], mode="drop")
-        waiting = jnp.where(place[:, None], -1, ws)
-        occupied = slot_wid >= 0
-        # local max only — max over rounds and shards commute, so the
-        # cross-shard pmax happens ONCE after the loop (diagnostics
-        # path), not as a per-round collective in the hot loop.
-        peak = jnp.maximum(
-            peak,
-            occupied.sum(dtype=jnp.int32) + (~free).sum(dtype=jnp.int32))
+        with jax.named_scope("relay.merge"):
+            free = pend_wid < 0
+            forder = jnp.argsort(~free)             # free slot indices first
+            nfree = free.sum(dtype=jnp.int32)
+            ws = _compact_rows(waiting, W)
+            k = jnp.arange(W, dtype=jnp.int32)
+            place = (k < nfree) & (ws[:, 0] >= 0)
+            tgt = jnp.where(place, forder[jnp.minimum(k, Wl - 1)], Wl)
+            slot_wid = jnp.full((Wl,), -1, jnp.int32).at[tgt].set(
+                ws[:, 2], mode="drop")
+            slot_cur = jnp.full((Wl,), -1, jnp.int32).at[tgt].set(
+                ws[:, 0] - lo, mode="drop")
+            slot_t0 = jnp.zeros((Wl,), jnp.int32).at[tgt].set(
+                ws[:, 1], mode="drop")
+            waiting = jnp.where(place[:, None], -1, ws)
+            occupied = slot_wid >= 0
+            # local max only — max over rounds and shards commute, so the
+            # cross-shard pmax happens ONCE after the loop (diagnostics
+            # path), not as a per-round collective in the hot loop.
+            peak = jnp.maximum(
+                peak,
+                occupied.sum(dtype=jnp.int32) + (~free).sum(dtype=jnp.int32))
 
         if overlap:
             # -- in-flight exchanges: drain the buffers the PREVIOUS
@@ -433,143 +434,148 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
             # so XLA's latency-hiding scheduler is free to run the
             # all_to_alls concurrently with the megakernel launch:
             # launch(g+1, locals) ∥ exchange(g, movers).
-            arrived, spill_w, n_spill_w, f_w = exchange_fn(
-                outbox, cap=mailbox_cap, r=r, channel=0)
-            pinned = pend_wid >= 0
-            in_home = jnp.where(pinned, (pend_wid - wid_base) // Wb, -1)
-            pay_p = jnp.concatenate(
-                [jnp.where(pinned, route_tag(in_home, shard_size),
-                           -1)[:, None],
-                 jnp.where(pinned, pend_wid, -1)[:, None],
-                 jnp.where(pinned, slot_ids, -1)[:, None],
-                 jnp.where(pinned[:, None], pend_path, -1)], axis=1)
-            got, spill_p, n_spill_p, f_p = exchange_fn(
-                pay_p, cap=path_cap, r=r, channel=1)
+            with jax.named_scope("relay.exchange"):
+                arrived, spill_w, n_spill_w, f_w = exchange_fn(
+                    outbox, cap=mailbox_cap, r=r, channel=0)
+                pinned = pend_wid >= 0
+                in_home = jnp.where(pinned, (pend_wid - wid_base) // Wb, -1)
+                pay_p = jnp.concatenate(
+                    [jnp.where(pinned, route_tag(in_home, shard_size),
+                               -1)[:, None],
+                     jnp.where(pinned, pend_wid, -1)[:, None],
+                     jnp.where(pinned, slot_ids, -1)[:, None],
+                     jnp.where(pinned[:, None], pend_path, -1)], axis=1)
+                got, spill_p, n_spill_p, f_p = exchange_fn(
+                    pay_p, cap=path_cap, r=r, channel=1)
 
         # -- segment: one resumable megakernel launch over the compacted
         # slots; the slot→wid map keys the hash PRNG (and gathers the
         # fed stream) so each walker draws its own columns.
-        u_slots = None if u is None else jnp.take(
-            u, jnp.maximum(slot_wid, 0), axis=1)
-        starts = jnp.where(occupied, slot_cur, -1)
-        paths, frontier = bk.sample_walk_segment(
-            view, lcfg, starts, slot_t0, seed, params, u=u_slots,
-            wid=slot_wid)
+        with jax.named_scope("relay.segment"):
+            u_slots = None if u is None else jnp.take(
+                u, jnp.maximum(slot_wid, 0), axis=1)
+            starts = jnp.where(occupied, slot_cur, -1)
+            paths, frontier = bk.sample_walk_segment(
+                view, lcfg, starts, slot_t0, seed, params, u=u_slots,
+                wid=slot_wid)
 
-        fr_ok = occupied & (frontier[:, 0] >= 0)
-        # census: an occupied slot whose frontier is exhausted finished
-        # its walk HERE — mark its wid.  De-duping by wid (a bitmap, not
-        # a counter) is what makes chaos duplicates unable to mask a
-        # dropped walker: the same wid finishing twice sets one bit.
-        term = occupied & (frontier[:, 0] < 0)
-        fin = fin.at[jnp.where(term, slot_wid - wid_base, W)].set(
-            True, mode="drop")
-        new_fr = jnp.where(
-            fr_ok[:, None],
-            jnp.stack([frontier[:, 0], frontier[:, 1], slot_wid], -1), -1)
+        with jax.named_scope("relay.merge"):
+            fr_ok = occupied & (frontier[:, 0] >= 0)
+            # census: an occupied slot whose frontier is exhausted finished
+            # its walk HERE — mark its wid.  De-duping by wid (a bitmap, not
+            # a counter) is what makes chaos duplicates unable to mask a
+            # dropped walker: the same wid finishing twice sets one bit.
+            term = occupied & (frontier[:, 0] < 0)
+            fin = fin.at[jnp.where(term, slot_wid - wid_base, W)].set(
+                True, mode="drop")
+            new_fr = jnp.where(
+                fr_ok[:, None],
+                jnp.stack([frontier[:, 0], frontier[:, 1], slot_wid], -1), -1)
 
-        if overlap:
-            # -- buffer swap: fresh frontier exits + walker-channel
-            # spills become the NEXT round's in-flight outbox; walker
-            # arrivals join the waiting queue only now, after the
-            # segment's inputs were fixed (the landing buffer).
-            outbox = _compact_rows(
-                _dedup_wid(jnp.concatenate([spill_w, new_fr], axis=0)), W)
-            waiting = _compact_rows(_dedup_wid(
-                jnp.concatenate([waiting, arrived], axis=0)), W)
+            if overlap:
+                # -- buffer swap: fresh frontier exits + walker-channel
+                # spills become the NEXT round's in-flight outbox; walker
+                # arrivals join the waiting queue only now, after the
+                # segment's inputs were fixed (the landing buffer).
+                outbox = _compact_rows(
+                    _dedup_wid(jnp.concatenate([spill_w, new_fr], axis=0)), W)
+                waiting = _compact_rows(_dedup_wid(
+                    jnp.concatenate([waiting, arrived], axis=0)), W)
 
-            # -- fresh path rows: home-local columns scatter straight
-            # into the home block; remote ones pin to the slot that
-            # walked them and ride NEXT round's exchange.
-            frow_path = jnp.where(occupied[:, None],
-                                  jnp.where(paths >= 0, paths + lo, -1),
-                                  -1)
-            frow_wid = jnp.where(occupied, slot_wid, -1)
-            has_frow = frow_wid >= 0
-            fhome = jnp.where(has_frow, (frow_wid - wid_base) // Wb, -1)
-            flocal = has_frow & (fhome == sidx)
-            lrow = jnp.where(flocal, (frow_wid - wid_base) - sidx * Wb,
-                             Wb)
-            acc = acc.at[lrow].max(
-                jnp.where(flocal[:, None], frow_path, -1), mode="drop")
-            g_ok = got[:, 0] >= 0
-            grow = jnp.where(g_ok, (got[:, 1] - wid_base) - sidx * Wb,
-                             Wb)
-            acc = acc.at[grow].max(
-                jnp.where(g_ok[:, None], got[:, 3:], -1), mode="drop")
-            # spilled in-flight rows re-pin to their slot; fresh remote
-            # rows pin to theirs.  The two slot sets are disjoint by
-            # construction: segment targets were free at round start,
-            # spilled rows' slots were pinned.
-            s_ok = spill_p[:, 0] >= 0
-            s_slot = jnp.where(s_ok, spill_p[:, 2], Wl)
-            pend_path = jnp.full((Wl, L + 1), -1, jnp.int32) \
-                .at[s_slot].set(spill_p[:, 3:], mode="drop")
-            pend_wid = jnp.full((Wl,), -1, jnp.int32) \
-                .at[s_slot].set(spill_p[:, 1], mode="drop")
-            fremote = has_frow & (fhome != sidx)
-            rm_slot = jnp.where(fremote, slot_ids, Wl)
-            pend_path = pend_path.at[rm_slot].set(
-                jnp.where(fremote[:, None], frow_path, -1), mode="drop")
-            pend_wid = pend_wid.at[rm_slot].set(
-                jnp.where(fremote, frow_wid, -1), mode="drop")
-            faults = faults + f_w + f_p
-        else:
-            # -- route walkers (bulk): fresh frontier exits + outbox
-            # leftovers ride one all_to_all as (vertex, step, wid)
-            # records; arrivals queue at the receiver (placement happens
-            # next round), spills return to the sender's outbox.
-            pay_w = jnp.concatenate([outbox, new_fr], axis=0)
-            arrived, spill_w, n_spill_w, f_w = exchange_fn(
-                pay_w, cap=mailbox_cap, r=r, channel=0)
-            outbox = _compact_rows(_dedup_wid(spill_w), W)
-            waiting = _compact_rows(_dedup_wid(
-                jnp.concatenate([waiting, arrived], axis=0)), W)
+                # -- fresh path rows: home-local columns scatter straight
+                # into the home block; remote ones pin to the slot that
+                # walked them and ride NEXT round's exchange.
+                frow_path = jnp.where(occupied[:, None],
+                                      jnp.where(paths >= 0, paths + lo, -1),
+                                      -1)
+                frow_wid = jnp.where(occupied, slot_wid, -1)
+                has_frow = frow_wid >= 0
+                fhome = jnp.where(has_frow, (frow_wid - wid_base) // Wb, -1)
+                flocal = has_frow & (fhome == sidx)
+                lrow = jnp.where(flocal, (frow_wid - wid_base) - sidx * Wb,
+                                 Wb)
+                acc = acc.at[lrow].max(
+                    jnp.where(flocal[:, None], frow_path, -1), mode="drop")
+                g_ok = got[:, 0] >= 0
+                grow = jnp.where(g_ok, (got[:, 1] - wid_base) - sidx * Wb,
+                                 Wb)
+                acc = acc.at[grow].max(
+                    jnp.where(g_ok[:, None], got[:, 3:], -1), mode="drop")
+                # spilled in-flight rows re-pin to their slot; fresh remote
+                # rows pin to theirs.  The two slot sets are disjoint by
+                # construction: segment targets were free at round start,
+                # spilled rows' slots were pinned.
+                s_ok = spill_p[:, 0] >= 0
+                s_slot = jnp.where(s_ok, spill_p[:, 2], Wl)
+                pend_path = jnp.full((Wl, L + 1), -1, jnp.int32) \
+                    .at[s_slot].set(spill_p[:, 3:], mode="drop")
+                pend_wid = jnp.full((Wl,), -1, jnp.int32) \
+                    .at[s_slot].set(spill_p[:, 1], mode="drop")
+                fremote = has_frow & (fhome != sidx)
+                rm_slot = jnp.where(fremote, slot_ids, Wl)
+                pend_path = pend_path.at[rm_slot].set(
+                    jnp.where(fremote[:, None], frow_path, -1), mode="drop")
+                pend_wid = pend_wid.at[rm_slot].set(
+                    jnp.where(fremote, frow_wid, -1), mode="drop")
+                faults = faults + f_w + f_p
+            else:
+                # -- route walkers (bulk): fresh frontier exits + outbox
+                # leftovers ride one all_to_all as (vertex, step, wid)
+                # records; arrivals queue at the receiver (placement happens
+                # next round), spills return to the sender's outbox.
+                pay_w = jnp.concatenate([outbox, new_fr], axis=0)
+                with jax.named_scope("relay.exchange"):
+                    arrived, spill_w, n_spill_w, f_w = exchange_fn(
+                        pay_w, cap=mailbox_cap, r=r, channel=0)
+                outbox = _compact_rows(_dedup_wid(spill_w), W)
+                waiting = _compact_rows(_dedup_wid(
+                    jnp.concatenate([waiting, arrived], axis=0)), W)
 
-            # -- route paths (bulk): every slot that walked this round
-            # emits its path columns (translated to global ids) toward
-            # the walker's home shard; pinned rows from earlier rounds
-            # retry alongside.
-            row_path = jnp.where(occupied[:, None],
-                                 jnp.where(paths >= 0, paths + lo, -1),
-                                 pend_path)
-            row_wid = jnp.where(occupied, slot_wid, pend_wid)
-            has_row = row_wid >= 0
-            home = jnp.where(has_row, (row_wid - wid_base) // Wb, -1)
-            local = has_row & (home == sidx)
-            lrow = jnp.where(local, (row_wid - wid_base) - sidx * Wb, Wb)
-            acc = acc.at[lrow].max(
-                jnp.where(local[:, None], row_path, -1), mode="drop")
-            remote = has_row & (home != sidx)
-            pay_p = jnp.concatenate(
-                [jnp.where(remote, route_tag(home, shard_size),
-                           -1)[:, None],
-                 jnp.where(remote, row_wid, -1)[:, None],
-                 jnp.where(remote, slot_ids, -1)[:, None],
-                 jnp.where(remote[:, None], row_path, -1)], axis=1)
-            got, spill_p, n_spill_p, f_p = exchange_fn(
-                pay_p, cap=path_cap, r=r, channel=1)
-            faults = faults + f_w + f_p
-            g_ok = got[:, 0] >= 0
-            grow = jnp.where(g_ok, (got[:, 1] - wid_base) - sidx * Wb,
-                             Wb)
-            acc = acc.at[grow].max(
-                jnp.where(g_ok[:, None], got[:, 3:], -1), mode="drop")
-            # spilled rows stay pinned to their slot (re-keyed by the
-            # slot field — exchange returns them in sort order);
-            # delivered and home-local rows free theirs.
-            s_ok = spill_p[:, 0] >= 0
-            s_slot = jnp.where(s_ok, spill_p[:, 2], Wl)
-            pend_path = jnp.full((Wl, L + 1), -1, jnp.int32) \
-                .at[s_slot].set(spill_p[:, 3:], mode="drop")
-            pend_wid = jnp.full((Wl,), -1, jnp.int32) \
-                .at[s_slot].set(spill_p[:, 1], mode="drop")
+                # -- route paths (bulk): every slot that walked this round
+                # emits its path columns (translated to global ids) toward
+                # the walker's home shard; pinned rows from earlier rounds
+                # retry alongside.
+                row_path = jnp.where(occupied[:, None],
+                                     jnp.where(paths >= 0, paths + lo, -1),
+                                     pend_path)
+                row_wid = jnp.where(occupied, slot_wid, pend_wid)
+                has_row = row_wid >= 0
+                home = jnp.where(has_row, (row_wid - wid_base) // Wb, -1)
+                local = has_row & (home == sidx)
+                lrow = jnp.where(local, (row_wid - wid_base) - sidx * Wb, Wb)
+                acc = acc.at[lrow].max(
+                    jnp.where(local[:, None], row_path, -1), mode="drop")
+                remote = has_row & (home != sidx)
+                pay_p = jnp.concatenate(
+                    [jnp.where(remote, route_tag(home, shard_size),
+                               -1)[:, None],
+                     jnp.where(remote, row_wid, -1)[:, None],
+                     jnp.where(remote, slot_ids, -1)[:, None],
+                     jnp.where(remote[:, None], row_path, -1)], axis=1)
+                with jax.named_scope("relay.exchange"):
+                    got, spill_p, n_spill_p, f_p = exchange_fn(
+                        pay_p, cap=path_cap, r=r, channel=1)
+                faults = faults + f_w + f_p
+                g_ok = got[:, 0] >= 0
+                grow = jnp.where(g_ok, (got[:, 1] - wid_base) - sidx * Wb,
+                                 Wb)
+                acc = acc.at[grow].max(
+                    jnp.where(g_ok[:, None], got[:, 3:], -1), mode="drop")
+                # spilled rows stay pinned to their slot (re-keyed by the
+                # slot field — exchange returns them in sort order);
+                # delivered and home-local rows free theirs.
+                s_ok = spill_p[:, 0] >= 0
+                s_slot = jnp.where(s_ok, spill_p[:, 2], Wl)
+                pend_path = jnp.full((Wl, L + 1), -1, jnp.int32) \
+                    .at[s_slot].set(spill_p[:, 3:], mode="drop")
+                pend_wid = jnp.full((Wl,), -1, jnp.int32) \
+                    .at[s_slot].set(spill_p[:, 1], mode="drop")
 
-        pending = jax.lax.psum(
-            (waiting[:, 0] >= 0).sum(dtype=jnp.int32)
-            + (outbox[:, 0] >= 0).sum(dtype=jnp.int32)
-            + (pend_wid >= 0).sum(dtype=jnp.int32), axis_name=sync_axes)
-        ovf = ovf + jax.lax.psum(n_spill_w + n_spill_p,
+            pending = jax.lax.psum(
+                (waiting[:, 0] >= 0).sum(dtype=jnp.int32)
+                + (outbox[:, 0] >= 0).sum(dtype=jnp.int32)
+                + (pend_wid >= 0).sum(dtype=jnp.int32), axis_name=sync_axes)
+            ovf = ovf + jax.lax.psum(n_spill_w + n_spill_p,
                                  axis_name=sync_axes)
         return (r + 1, pend_path, pend_wid, waiting, outbox, acc, ovf,
                 peak, fin, faults, pending)

@@ -340,103 +340,107 @@ def update_fused_pallas(state: BingoState, cfg: BingoConfig, is_insert,
     Cg = cfg.group_capacity
     fp = cfg.fp_bias
     B = u.shape[0]
-    u = jnp.asarray(u, jnp.int32)
-    v = jnp.asarray(v, jnp.int32)
-    if active is None:
-        active = jnp.ones((B,), bool)
-    # Same lane-validity contract as the reference (reject-and-count —
-    # a negative u would wrap in the prepass scatters): see
-    # ``batched_update``'s robustness note.
-    lane_ok = (u >= 0) & (u < V) & (v >= 0)
-    ins = is_insert & active & lane_ok
-    dele = (~is_insert) & active & lane_ok
-    if fp:
-        w_int, w_frac = radix.decompose_fp(w, cfg.lam)
-    else:
-        w_int = jnp.asarray(w, jnp.int32)
-        w_frac = jnp.zeros((B,), jnp.float32)
+    with jax.named_scope("update.prepass"):
+        u = jnp.asarray(u, jnp.int32)
+        v = jnp.asarray(v, jnp.int32)
+        if active is None:
+            active = jnp.ones((B,), bool)
+        # Same lane-validity contract as the reference (reject-and-count:
+        # a negative u would wrap in the prepass scatters): see
+        # ``batched_update``'s robustness note.
+        lane_ok = (u >= 0) & (u < V) & (v >= 0)
+        ins = is_insert & active & lane_ok
+        dele = (~is_insert) & active & lane_ok
+        if fp:
+            w_int, w_frac = radix.decompose_fp(w, cfg.lam)
+        else:
+            w_int = jnp.asarray(w, jnp.int32)
+            w_frac = jnp.zeros((B,), jnp.float32)
 
-    # ---- ordering prepass (the reference's stage-1/2 sorts, verbatim) ----
-    U = _padded_unique(jnp.where(ins | dele, u, V), V)           # (B,)
-    Uc = jnp.minimum(U, V - 1)
-    idx = jnp.arange(B, dtype=jnp.int32)
+        # -- ordering prepass (the reference's stage-1/2 sorts, verbatim) --
+        U = _padded_unique(jnp.where(ins | dele, u, V), V)           # (B,)
+        Uc = jnp.minimum(U, V - 1)
+        idx = jnp.arange(B, dtype=jnp.int32)
 
-    su = jnp.where(ins, u, V)
-    order = jnp.argsort(su)
-    su_s, v_s = su[order], v[order]
-    wi_s, wf_s = w_int[order], w_frac[order]
-    first = jnp.concatenate([jnp.ones((1,), bool), su_s[1:] != su_s[:-1]])
-    rank = idx - jax.lax.cummax(jnp.where(first, idx, -1), axis=0)
-    off = state.deg[jnp.minimum(su_s, V - 1)] + rank
-    okA = (su_s < V) & (off < C)
-    n_ins = jnp.sum(okA, dtype=jnp.int32)
-    rowA = jnp.where(okA, jnp.searchsorted(U, su_s).astype(jnp.int32), B)
-    offA = jnp.where(okA, off, 0)
-    ins_nbr = jnp.zeros((B, C), jnp.int32).at[rowA, offA].set(
-        v_s, mode="drop")
-    ins_bias = jnp.zeros((B, C), jnp.int32).at[rowA, offA].set(
-        wi_s, mode="drop")
-    ins_cnt = jnp.zeros((B,), jnp.int32).at[rowA].add(1, mode="drop")
-    deg0 = state.deg[Uc]
-    deg_ins = deg0 + ins_cnt
+        su = jnp.where(ins, u, V)
+        order = jnp.argsort(su)
+        su_s, v_s = su[order], v[order]
+        wi_s, wf_s = w_int[order], w_frac[order]
+        first = jnp.concatenate([jnp.ones((1,), bool), su_s[1:] != su_s[:-1]])
+        rank = idx - jax.lax.cummax(jnp.where(first, idx, -1), axis=0)
+        off = state.deg[jnp.minimum(su_s, V - 1)] + rank
+        okA = (su_s < V) & (off < C)
+        n_ins = jnp.sum(okA, dtype=jnp.int32)
+        rowA = jnp.where(okA, jnp.searchsorted(U, su_s).astype(jnp.int32), B)
+        offA = jnp.where(okA, off, 0)
+        ins_nbr = jnp.zeros((B, C), jnp.int32).at[rowA, offA].set(
+            v_s, mode="drop")
+        ins_bias = jnp.zeros((B, C), jnp.int32).at[rowA, offA].set(
+            wi_s, mode="drop")
+        ins_cnt = jnp.zeros((B,), jnp.int32).at[rowA].add(1, mode="drop")
+        deg0 = state.deg[Uc]
+        deg_ins = deg0 + ins_cnt
 
-    du = jnp.where(dele, u, V)
-    dv = jnp.where(dele, v, -1)
-    ordD = jnp.lexsort((dv, du))
-    du_s, dv_s = du[ordD], dv[ordD]
-    firstD = jnp.concatenate(
-        [jnp.ones((1,), bool),
-         (du_s[1:] != du_s[:-1]) | (dv_s[1:] != dv_s[:-1])])
-    rankD = idx - jax.lax.cummax(jnp.where(firstD, idx, -1), axis=0)
-    Dp = block_dels if block_dels > 0 else min(B, 2 * C)
-    firstR = jnp.concatenate([jnp.ones((1,), bool), du_s[1:] != du_s[:-1]])
-    lane = idx - jax.lax.cummax(jnp.where(firstR, idx, -1), axis=0)
-    rowD = jnp.where((du_s < V) & (lane < Dp),
-                     jnp.searchsorted(U, du_s).astype(jnp.int32), B)
-    laneD = jnp.minimum(lane, Dp - 1)
-    # v >= 0 on every valid delete lane, so -1 marks an unused lane
-    del_v = jnp.full((B, Dp), -1, jnp.int32).at[rowD, laneD].set(
-        dv_s, mode="drop")
-    del_rank = jnp.zeros((B, Dp), jnp.int32).at[rowD, laneD].set(
-        rankD, mode="drop")
-    del_cnt = jnp.zeros((B,), jnp.int32).at[rowD].add(1, mode="drop")
+        du = jnp.where(dele, u, V)
+        dv = jnp.where(dele, v, -1)
+        ordD = jnp.lexsort((dv, du))
+        du_s, dv_s = du[ordD], dv[ordD]
+        firstD = jnp.concatenate(
+            [jnp.ones((1,), bool),
+             (du_s[1:] != du_s[:-1]) | (dv_s[1:] != dv_s[:-1])])
+        rankD = idx - jax.lax.cummax(jnp.where(firstD, idx, -1), axis=0)
+        Dp = block_dels if block_dels > 0 else min(B, 2 * C)
+        firstR = jnp.concatenate([jnp.ones((1,), bool), du_s[1:] != du_s[:-1]])
+        lane = idx - jax.lax.cummax(jnp.where(firstR, idx, -1), axis=0)
+        rowD = jnp.where((du_s < V) & (lane < Dp),
+                         jnp.searchsorted(U, du_s).astype(jnp.int32), B)
+        laneD = jnp.minimum(lane, Dp - 1)
+        # v >= 0 on every valid delete lane, so -1 marks an unused lane
+        del_v = jnp.full((B, Dp), -1, jnp.int32).at[rowD, laneD].set(
+            dv_s, mode="drop")
+        del_rank = jnp.zeros((B, Dp), jnp.int32).at[rowD, laneD].set(
+            rankD, mode="drop")
+        del_cnt = jnp.zeros((B,), jnp.int32).at[rowD].add(1, mode="drop")
 
-    # ---- pad the affected-row axis to the tile size ----
-    Rt = max(1, min(block_rows, B))
-    if Rt >= _NDEL_LANE:
-        raise ValueError(f"block_rows {Rt} does not fit a {_IDS_W}-lane "
-                         "id row")
-    nt = -(-B // Rt)
-    pad = nt * Rt - B
-    Bp = nt * Rt
+        # ---- pad the affected-row axis to the tile size ----
+        Rt = max(1, min(block_rows, B))
+        if Rt >= _NDEL_LANE:
+            raise ValueError(f"block_rows {Rt} does not fit a {_IDS_W}-lane "
+                             "id row")
+        nt = -(-B // Rt)
+        pad = nt * Rt - B
+        Bp = nt * Rt
 
-    def padr(x, fill):
-        return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1),
-                       constant_values=fill)
+        def padr(x, fill):
+            return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1),
+                           constant_values=fill)
 
-    Up = padr(U, V)
-    tile_ndel = jnp.max(padr(del_cnt, 0).reshape(nt, Rt), axis=1)
-    ids = jnp.concatenate(
-        [Up.reshape(nt, Rt), jnp.zeros((nt, _NDEL_LANE - Rt), jnp.int32),
-         tile_ndel[:, None]], axis=1).reshape(nt, 1, _IDS_W)
+        Up = padr(U, V)
+        tile_ndel = jnp.max(padr(del_cnt, 0).reshape(nt, Rt), axis=1)
+        ids = jnp.concatenate(
+            [Up.reshape(nt, Rt), jnp.zeros((nt, _NDEL_LANE - Rt), jnp.int32),
+             tile_ndel[:, None]], axis=1).reshape(nt, 1, _IDS_W)
+        patches = ([ids, padr(deg0[:, None], 0), padr(deg_ins[:, None], 0),
+                    padr(ins_nbr, 0), padr(ins_bias, 0)]
+                   + ([padr(jnp.zeros((B, C), jnp.float32).at[rowA, offA]
+                             .set(wf_s, mode="drop"), 0)] if fp else [])
+                   + [padr(del_v, -1), padr(del_rank, 0)])
+
     has_ginv = state.ginv is not None
     # Row tables go in as (V, 1, C) so each vertex row is one tile-aligned
     # DMA (see walk_fused.META_W); frac only in fp mode — an integer-bias
     # state keeps it all zero, and so does every round.
     tabs = [state.nbr, state.bias] + ([state.frac] if fp else [])
-    tabs = [t.reshape(V, 1, C) for t in tabs]
+    with jax.named_scope("update.relayout"):
+        tabs = [t.reshape(V, 1, C) for t in tabs]
     ntab = len(tabs) + 1 + has_ginv
 
     def row_spec(lane):
         return pl.BlockSpec((Rt, lane), lambda i: (i, 0))
 
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    args = ([ids, padr(deg0[:, None], 0), padr(deg_ins[:, None], 0),
-             padr(ins_nbr, 0), padr(ins_bias, 0)]
-            + ([padr(jnp.zeros((B, C), jnp.float32).at[rowA, offA].set(
-                wf_s, mode="drop"), 0)] if fp else [])
-            + [padr(del_v, -1), padr(del_rank, 0)]
-            + tabs + [state.gmem] + ([state.ginv] if has_ginv else []))
+    args = (patches + tabs + [state.gmem]
+            + ([state.ginv] if has_ginv else []))
     first_tab = len(args) - ntab
     in_specs = ([any_spec] + [row_spec(1)] * 2 + [row_spec(C)] * (3 if fp
                                                                   else 2)
@@ -465,50 +469,58 @@ def update_fused_pallas(state: BingoState, cfg: BingoConfig, is_insert,
         pltpu.SemaphoreType.DMA(()),            # id row
     ]
 
-    outs = list(pl.pallas_call(
-        functools.partial(_kernel, cfg, Rt, Dp),
-        grid=(nt,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(*args))
-    rows_n = [t.reshape(V, C) for t in outs[:len(tabs)]]
+    with jax.named_scope("update.kernel"):
+        outs = list(pl.pallas_call(
+            functools.partial(_kernel, cfg, Rt, Dp),
+            grid=(nt,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            input_output_aliases=aliases,
+            interpret=interpret,
+            name="update_round",
+        )(*args))
+    with jax.named_scope("update.relayout"):
+        rows_n = [t.reshape(V, C) for t in outs[:len(tabs)]]
     gmem_n = outs[len(tabs)]
     ginv_n = outs[len(tabs) + 1] if has_ginv else None
     dego, gsz, dsum, wdec, gt, ndel = outs[ntab:]
-    # The inter-group alias rows are built outside the kernel by the
-    # very function the reference uses, so both paths divide and sum
-    # with the same XLA ops on any backend.
-    itab = build_itable_rows(cfg, dsum, wdec[:, 0])
+    with jax.named_scope("update.epilogue"):
+        # The inter-group alias rows are built outside the kernel by the
+        # very function the reference uses, so both paths divide and sum
+        # with the same XLA ops on any backend.
+        with jax.named_scope("alias_rows"):
+            itab = build_itable_rows(cfg, dsum, wdec[:, 0])
+        with jax.named_scope("writeback"):
+            def at(table, values):        # pad rows (>= V) dropped
+                return table.at[Up].set(values, mode="drop")
 
-    st = state._replace(
-        nbr=rows_n[0], bias=rows_n[1], frac=rows_n[2] if fp else state.frac,
-        gmem=gmem_n, ginv=ginv_n,
-        deg=state.deg.at[Up].set(dego[:, 0], mode="drop"),
-        gsize=state.gsize.at[Up].set(gsz, mode="drop"),
-        digitsum=state.digitsum.at[Up].set(dsum, mode="drop"),
-        wdec=state.wdec.at[Up].set(wdec[:, 0], mode="drop"),
-        gtype=state.gtype.at[Up].set(gt.astype(jnp.int8), mode="drop"),
-        itable=AliasTable(
-            prob=state.itable.prob.at[Up].set(itab.prob, mode="drop"),
-            alias=state.itable.alias.at[Up].set(itab.alias, mode="drop"),
-        ),
-    )
+            st = state._replace(
+                nbr=rows_n[0], bias=rows_n[1],
+                frac=rows_n[2] if fp else state.frac,
+                gmem=gmem_n, ginv=ginv_n,
+                deg=at(state.deg, dego[:, 0]),
+                gsize=at(state.gsize, gsz),
+                digitsum=at(state.digitsum, dsum),
+                wdec=at(state.wdec, wdec[:, 0]),
+                gtype=at(state.gtype, gt.astype(jnp.int8)),
+                itable=AliasTable(prob=at(state.itable.prob, itab.prob),
+                                  alias=at(state.itable.alias, itab.alias)))
 
-    n_del = jnp.sum(jnp.where(Up < V, ndel[:, 0], 0), dtype=jnp.int32)
-    old_gtype = state.gtype[Uc]
-    new_gtype = gt[:B].astype(jnp.int8)
-    valid_row = (U < V)[:, None]
-    pair = old_gtype.astype(jnp.int32) * 5 + new_gtype.astype(jnp.int32)
-    changed = (old_gtype != new_gtype) & valid_row
-    trans = jnp.zeros((25,), jnp.int32).at[
-        jnp.where(changed, pair, 25)].add(1, mode="drop").reshape(5, 5)
-    rejected = (
-        jnp.zeros((NUM_REASONS,), jnp.int32)
-        .at[R_VERTEX].set(jnp.sum(active & ~lane_ok, dtype=jnp.int32))
-        .at[R_CAPACITY].set(jnp.sum(ins, dtype=jnp.int32) - n_ins)
-        .at[R_ABSENT].set(jnp.sum(dele, dtype=jnp.int32) - n_del))
+            n_del = jnp.sum(jnp.where(Up < V, ndel[:, 0], 0),
+                            dtype=jnp.int32)
+            old_gtype = state.gtype[Uc]
+            new_gtype = gt[:B].astype(jnp.int8)
+            valid_row = (U < V)[:, None]
+            pair = (old_gtype.astype(jnp.int32) * 5
+                    + new_gtype.astype(jnp.int32))
+            changed = (old_gtype != new_gtype) & valid_row
+            trans = jnp.zeros((25,), jnp.int32).at[
+                jnp.where(changed, pair, 25)].add(1, mode="drop").reshape(5, 5)
+            rejected = (
+                jnp.zeros((NUM_REASONS,), jnp.int32)
+                .at[R_VERTEX].set(jnp.sum(active & ~lane_ok, dtype=jnp.int32))
+                .at[R_CAPACITY].set(jnp.sum(ins, dtype=jnp.int32) - n_ins)
+                .at[R_ABSENT].set(jnp.sum(dele, dtype=jnp.int32) - n_del))
     return st, UpdateStats(n_ins, n_del, trans, rejected)

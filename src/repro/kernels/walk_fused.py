@@ -418,16 +418,17 @@ def walk_fused_pallas(prob, alias, bias, nbr, deg, frac, starts, seed,
     if Kin >= DEG_LANE:
         raise ValueError(f"{Kin} inter-group entries do not fit a "
                          f"{META_W}-lane meta row")
-    pad = jnp.zeros((V, DEG_LANE - Kin), jnp.int32)
-    meta = jnp.concatenate(
-        ([pad] if uniform else [alias.astype(jnp.int32), pad])
-        + [deg[:, None]], axis=-1)
-    tab_args = [meta] if uniform else [
-        meta, jnp.pad(prob, ((0, 0), (0, META_W - Kin)))]
-    tab_args += [nbr] if uniform else [bias, nbr]
-    if has_frac:
-        tab_args.append(frac)
-    tab_args = [t.reshape(V, 1, t.shape[-1]) for t in tab_args]
+    with jax.named_scope("walk.relayout"):
+        pad = jnp.zeros((V, DEG_LANE - Kin), jnp.int32)
+        meta = jnp.concatenate(
+            ([pad] if uniform else [alias.astype(jnp.int32), pad])
+            + [deg[:, None]], axis=-1)
+        tab_args = [meta] if uniform else [
+            meta, jnp.pad(prob, ((0, 0), (0, META_W - Kin)))]
+        tab_args += [nbr] if uniform else [bias, nbr]
+        if has_frac:
+            tab_args.append(frac)
+        tab_args = [t.reshape(V, 1, t.shape[-1]) for t in tab_args]
     # Per-slot scratch rows: the K=1 ping-pong needs 2 full-tile slots;
     # K >= 2 needs K cohort-sized slots — K·(Bt/K) = Bt rows total, a
     # 2x shrink of gather scratch vs. the ping-pong (DESIGN.md §8).
@@ -451,13 +452,15 @@ def walk_fused_pallas(prob, alias, bias, nbr, deg, frac, starts, seed,
     kern = functools.partial(_kernel, length, base_log2, float(stop_prob),
                              uniform, has_frac, has_u, segment, block_b, V,
                              cohorts, Kin)
-    out = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("walk.kernel"):
+        out = pl.pallas_call(
+            kern,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            interpret=interpret,
+            name="walk_segment" if segment else "walk_whole",
+        )(*args)
     return (out[0], out[1]) if segment else out[0]

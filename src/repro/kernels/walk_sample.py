@@ -223,6 +223,7 @@ def walk_sample_pallas(prob, alias, bias, nbr, deg, u, frac=None, *,
             jax.ShapeDtypeStruct((B, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="walk_step",
     )(*args)
     return nxt[:, 0], slot[:, 0]
 
@@ -258,5 +259,6 @@ def walk_sample_uniform_pallas(nbr, deg, u, *, block_b: int = 256,
             jax.ShapeDtypeStruct((B, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="walk_uniform",
     )(nbr, deg[:, None], u[:, :1])
     return nxt[:, 0], slot[:, 0]

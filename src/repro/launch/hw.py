@@ -5,12 +5,5 @@ HBM_BW = 819e9                  # bytes/s per chip
 ICI_BW = 50e9                   # bytes/s per link (~the spec's figure)
 HBM_BYTES = 16 * 2**30          # 16 GiB per v5e chip
 
-# Issue-to-completion latency of one small HBM->VMEM row DMA (the walk
-# megakernel's per-walker gathers are a few KB each — latency-bound,
-# not bandwidth-bound).  This is the term cohort interleaving hides
-# (DESIGN.md §8): exposed once per step per walker batch at K=1,
-# amortized ~1/K with K cohorts in flight.
-DMA_LATENCY = 2e-6              # seconds, order-of-magnitude estimate
-
 SINGLE_POD_CHIPS = 256          # 16 x 16
 MULTI_POD_CHIPS = 512           # 2 pods

@@ -63,86 +63,6 @@ def mem_deltas():
     return deltas
 
 
-BENCH_FILES = ("BENCH_walks.json", "BENCH_updates.json",
-               "BENCH_serving.json")
-
-
-def _snapshots(doc: dict) -> list:
-    """Snapshot list of one BENCH_*.json in either format (the merged
-    ``snapshots`` list, or the PR-5 single-snapshot layout)."""
-    if not doc:
-        return []
-    if "snapshots" in doc:
-        return list(doc["snapshots"])
-    return [doc] if "cases" in doc else []
-
-
-def _stamp(snap: dict):
-    """The comparability stamp: platform + interpret mode + device
-    count + sizing.  Two snapshots may be diffed as a perf trajectory
-    ONLY when these all match — a CPU-interpret number against a
-    compiled one (or a micro sizing against full scale) is
-    apples-to-oranges by construction and must be refused, not
-    averaged into a delta."""
-    return (json.dumps({k: snap.get("env", {}).get(k)
-                        for k in ("platform", "interpret",
-                                  "device_count")}, sort_keys=True),
-            json.dumps(snap.get("sizing", {}), sort_keys=True))
-
-
-def _mesh_fact(snap: dict, case: str):
-    """The (S_v, S_w) mesh factorization ``case`` was measured under,
-    read from its ``mesh_sv``/``mesh_sw`` extras (None = unstamped,
-    i.e. a case that predates factorized meshes)."""
-    ex = snap.get("extras", {})
-    sv = ex.get(f"{case}.mesh_sv")
-    sw = ex.get(f"{case}.mesh_sw")
-    if sv is None and sw is None:
-        return None
-    return (sv, sw)
-
-
-def perf_deltas(rel_thresh: float = 0.05):
-    """(file, case, metric, old, new) throughput deltas vs the committed
-    BENCH_*.json — the walk/update analogue of ``mem_deltas``.
-
-    Snapshots are matched by ``_stamp``; a working-tree snapshot with no
-    same-stamp committed counterpart contributes no rows (new platform
-    or sizing — nothing to diff against), and cross-stamp pairs are
-    never compared.  A case whose (S_v, S_w) mesh factorization changed
-    (its ``mesh_sv``/``mesh_sw`` extras differ, or only one side is
-    stamped) is refused the same way: a 64×4 relay against a 16×16 one
-    times different collectives and table replication, not a perf
-    trajectory.  Only deltas beyond ``rel_thresh`` relative change are
-    reported (timing noise suppression).
-    """
-    deltas = []
-    for fname in BENCH_FILES:
-        path = os.path.join(REPO_ROOT, fname)
-        if not os.path.exists(path):
-            continue
-        new_doc = json.load(open(path))
-        old_doc = _committed(path)
-        if old_doc is None:
-            continue
-        metric = new_doc.get("metric", "")
-        old_by_stamp = {_stamp(s): s for s in _snapshots(old_doc)}
-        for snap in _snapshots(new_doc):
-            old = old_by_stamp.get(_stamp(snap))
-            if old is None:
-                continue                  # no comparable committed stamp
-            for case, val in sorted(snap.get("cases", {}).items()):
-                ov = old.get("cases", {}).get(case)
-                if ov is None or not ov:
-                    continue
-                if _mesh_fact(snap, case) != _mesh_fact(old, case):
-                    continue              # cross-factorization — refuse
-                if abs(val - ov) / abs(ov) < rel_thresh:
-                    continue
-                deltas.append((fname, case, metric, float(ov), float(val)))
-    return deltas
-
-
 def fmt_row(d) -> str:
     tc, tm, tx = d["t_compute"], d["t_memory"], d["t_collective"]
     dom = max(tc, tm, tx)
@@ -198,15 +118,6 @@ def main():
                 print(f"| {mesh} | {arch} | {shape} | {g0:.2f} | {g1:.2f} "
                       f"| {g1 - g0:+.2f} "
                       f"| {'Y' if f0 else 'N'}→{'Y' if f1 else 'N'} |")
-    pdeltas = perf_deltas()
-    if pdeltas:
-        print("\n### Throughput deltas vs committed BENCH_*.json (HEAD, "
-              "same-stamp snapshots only)\n")
-        print("| file | case | metric | HEAD | now | delta |")
-        print("|" + "---|" * 6)
-        for fname, case, metric, ov, nv in pdeltas:
-            print(f"| {fname} | {case} | {metric} | {ov:.4g} | {nv:.4g} "
-                  f"| {(nv - ov) / ov:+.1%} |")
 
 
 if __name__ == "__main__":

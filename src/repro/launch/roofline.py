@@ -26,8 +26,7 @@ from typing import Dict, Optional
 
 from repro.launch import hw
 
-__all__ = ["collective_bytes", "RooflineReport", "analyze",
-           "walk_step_roofline", "grade_walk_snapshot"]
+__all__ = ["collective_bytes", "RooflineReport", "analyze"]
 
 _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute")
@@ -162,117 +161,3 @@ def analyze(*, arch: str, shape: str, mesh_name: str, chips: int,
         useful_ratio=mf / max(flops * chips, 1.0),
         memory_analysis=mem, tokens=tokens, meta=meta or {},
     )
-
-
-# ---------------------------------------------------------------------------
-# Walk-megakernel step-throughput model (DESIGN.md §8 cohort interleave)
-# ---------------------------------------------------------------------------
-
-def walk_row_bytes(capacity: int, kin: int, fp_bias: bool = False) -> int:
-    """HBM bytes gathered per walker per step by the fused walk kernel:
-    prob (f32) + alias (i32) rows of ``kin`` entries, bias + nbr (i32)
-    rows of ``capacity`` entries, the 1-entry deg row, and the fp-mode
-    frac (f32) row."""
-    return 4 * (2 * kin + 2 * capacity + 1) + (4 * capacity if fp_bias
-                                               else 0)
-
-
-def walk_step_roofline(*, walkers: int, capacity: int, kin: int,
-                       length: int, cohorts: int = 1,
-                       fp_bias: bool = False) -> dict:
-    """Predicted fused-walk steps/second at one cohort count.
-
-    Two terms per step, per the kernel's actual structure
-    (``kernels/walk_fused.py``):
-
-      t_bw   = walkers * row_bytes / HBM_BW     — the bandwidth floor,
-               K-independent (every K gathers the same bytes)
-      t_lat  = DMA_LATENCY / cohorts            — the exposed per-step
-               DMA latency.  The next gather is data-dependent on the
-               sample, so K=1's ping-pong eats the full latency every
-               step; with K cohorts in flight each cohort's DMA rides
-               under the other K-1 cohorts' samples, amortizing it ~1/K.
-
-    steps/s = walkers / (t_bw + t_lat).  The model is deliberately
-    latency-vs-bandwidth only — sample compute is a few VPU passes over
-    rows already in VMEM and never dominates at production shapes.
-    """
-    row = walk_row_bytes(capacity, kin, fp_bias)
-    t_bw = walkers * row / hw.HBM_BW
-    t_lat = hw.DMA_LATENCY / max(cohorts, 1)
-    t_step = t_bw + t_lat
-    return {
-        "cohorts": cohorts,
-        "row_bytes": row,
-        "t_bandwidth": t_bw,
-        "t_latency": t_lat,
-        "predicted_steps_per_s": walkers / t_step,
-        "length": length,
-    }
-
-
-def grade_walk_snapshot(snap: dict) -> list:
-    """Achieved-vs-predicted rows for every fused ``-K<k>`` case of one
-    BENCH_walks snapshot (``{env, sizing, cases}``).
-
-    Only ``interpret: false`` snapshots are graded against the TPU
-    model — interpret-mode emulation timings share no axis with a
-    hardware roofline, and on non-TPU compiled platforms the ratio is
-    reported but only the *relative* K trend is meaningful (stamped in
-    each row's ``platform``).  Returns dicts with kind, cohorts,
-    achieved/predicted steps/s, and their ratio.
-    """
-    env = snap.get("env", {})
-    sz = snap.get("sizing", {})
-    if env.get("interpret", True):
-        return []
-    rows = []
-    for case, achieved in sorted(snap.get("cases", {}).items()):
-        m = re.match(r"(.+)-pallas-fused-K(\d+)$", case)
-        if not m:
-            continue
-        kind, k = m.group(1), int(m.group(2))
-        pred = walk_step_roofline(
-            walkers=sz.get("walkers", 256),
-            capacity=sz.get("capacity", 128),
-            kin=sz.get("kin", 12),
-            length=sz.get("walk_length", 16),
-            cohorts=k)
-        rows.append({
-            "kind": kind, "cohorts": k,
-            "platform": env.get("platform", "?"),
-            "achieved_steps_per_s": float(achieved),
-            "predicted_steps_per_s": pred["predicted_steps_per_s"],
-            "ratio": float(achieved) / pred["predicted_steps_per_s"],
-        })
-    return rows
-
-
-def _main_walks(path: str) -> None:
-    import json
-    with open(path) as f:
-        doc = json.load(f)
-    snaps = doc.get("snapshots") or [doc]
-    print("| kind | K | platform | achieved steps/s | predicted steps/s "
-          "| achieved/predicted |")
-    print("|" + "---|" * 6)
-    graded = 0
-    for snap in snaps:
-        for r in grade_walk_snapshot(snap):
-            graded += 1
-            print(f"| {r['kind']} | {r['cohorts']} | {r['platform']} "
-                  f"| {r['achieved_steps_per_s']:.3e} "
-                  f"| {r['predicted_steps_per_s']:.3e} "
-                  f"| {r['ratio']:.3f} |")
-    if not graded:
-        print("(no interpret=false snapshots to grade — run "
-              "`python -m benchmarks.run --compiled` first)")
-
-
-if __name__ == "__main__":
-    import argparse
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--walks", default="BENCH_walks.json",
-                    help="BENCH_walks.json to grade (achieved vs the "
-                         "per-cohort step-throughput model)")
-    _main_walks(ap.parse_args().walks)

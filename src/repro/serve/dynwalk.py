@@ -55,6 +55,7 @@ from repro.core.dyngraph import (BingoConfig, BingoState, from_edges,
 from repro.core.updates import NUM_REASONS, R_OK, UpdateStats, make_updater
 from repro.core.walks import WalkParams, make_walker
 from repro.graph.streams import UpdateStream, rounds_on_device
+from repro.serve import spans
 from repro.serve.guard import GuardPolicy, IngestGuard
 
 __all__ = ["DynamicWalkEngine", "sharded_from_edges"]
@@ -178,6 +179,10 @@ class DynamicWalkEngine:
         self.rounds_ingested = 0
         self.updates_applied = 0
         self.walks_served = 0
+        # update lanes at or past ``n_valid``, and walk lanes of bucket
+        # padding (device-to-host reads are ``engine.read`` spans)
+        self.pad_lanes = 0
+        self.walk_pad_lanes = 0
 
     @staticmethod
     def _shard_state(state, mesh, vaxes):
@@ -262,14 +267,18 @@ class DynamicWalkEngine:
                                  in_specs=(sspec, P(), P(), P(), P(), P()),
                                  out_specs=(sspec, P()), check_vma=False)
 
-        update = jax.jit(smap_upd, donate_argnums=0)
+        # the same program names as on one device (``make_updater``,
+        # ``make_walker``), so a trace reads alike on any mesh
+        @functools.partial(jax.jit, donate_argnums=0)
+        def engine_update(st, is_insert, u, v, w, active):
+            return smap_upd(st, is_insert, u, v, w, active)
 
         @functools.partial(jax.jit, donate_argnums=0)
-        def walk(st, starts, key):
+        def engine_walk(st, starts, key):
             paths, _rounds, _ovf = relay(st, starts, seed_from_key(key))
             return st, paths
 
-        return update, walk
+        return engine_update, engine_walk
 
     def _regrow_program(self, t: int):
         """Jitted donated-state migration tier ``t`` -> ``t + 1``.
@@ -330,6 +339,10 @@ class DynamicWalkEngine:
         reason tally — no host sync) and ``drain_guard()`` settles
         quarantine/retry accounting for the whole window at once.
         """
+        with spans.span("engine.ingest"):
+            return self._ingest(is_insert, u, v, w, n_valid)
+
+    def _ingest(self, is_insert, u, v, w, n_valid) -> UpdateStats:
         B = int(u.shape[0])
         nv = B if n_valid is None else int(n_valid)
         if not 0 <= nv <= B:
@@ -337,35 +350,40 @@ class DynamicWalkEngine:
         lanes = jnp.ones((B,), bool) if nv == B else \
             jnp.arange(B, dtype=jnp.int32) < nv
         if self.guard is None:
-            self._state, stats = self._update(
-                self._state, is_insert, u, v, w, lanes)
-            self.rounds_ingested += 1
-            self.updates_applied += nv
+            with spans.span("engine.update"):
+                self._state, stats = self._update(
+                    self._state, is_insert, u, v, w, lanes)
+            self._count_round(B, nv)
             return stats._replace(max_fill=self._fill())
 
         g = self.guard
         rnd = self.rounds_ingested
-        reasons = g.classify(self._state, is_insert, u, v, w)
-        self._state, stats = self._update(
-            self._state, is_insert, u, v, w, lanes & (reasons == R_OK))
+        with spans.span("engine.classify"):
+            reasons = g.classify(self._state, is_insert, u, v, w)
+        with spans.span("engine.update"):
+            self._state, stats = self._update(
+                self._state, is_insert, u, v, w, lanes & (reasons == R_OK))
         if self.defer_guard:
             # Device-side reason tally (pad lanes masked to R_OK so
             # they never count): dispatches async, the host never
             # blocks — quarantine records wait in the backlog.
-            tally = jnp.bincount(
-                jnp.where(lanes, reasons, R_OK), length=NUM_REASONS
-            ).at[R_OK].set(0)
-            stats = stats._replace(
-                rejected=stats.rejected + tally.astype(jnp.int32))
+            with spans.span("engine.tally"):
+                tally = jnp.bincount(
+                    jnp.where(lanes, reasons, R_OK), length=NUM_REASONS
+                ).at[R_OK].set(0)
+                stats = stats._replace(
+                    rejected=stats.rejected + tally.astype(jnp.int32),
+                    max_fill=self._fill())
             self._guard_backlog.append(
                 (rnd, is_insert, u, v, w, reasons, stats.del_applied, nv))
-            self.rounds_ingested += 1
-            self.updates_applied += nv
-            return stats._replace(max_fill=self._fill())
-        counts = g.account(rnd, np.asarray(is_insert)[:nv],
-                           np.asarray(u)[:nv], np.asarray(v)[:nv],
-                           np.asarray(w)[:nv], np.asarray(reasons)[:nv])
-        g.deletes_since_retry += int(stats.del_applied)
+            self._count_round(B, nv)
+            return stats
+        with spans.span("guard.account"):
+            counts = g.account(rnd, self._host(is_insert)[:nv],
+                               self._host(u)[:nv], self._host(v)[:nv],
+                               self._host(w)[:nv],
+                               self._host(reasons)[:nv])
+            g.deletes_since_retry += int(self._host(stats.del_applied))
         stats = stats._replace(
             rejected=stats.rejected + jnp.asarray(counts, jnp.int32))
         rstats = self._run_guard_retry(rnd)
@@ -373,9 +391,23 @@ class DynamicWalkEngine:
             stats = stats._replace(
                 ins_applied=stats.ins_applied + rstats.ins_applied,
                 transitions=stats.transitions + rstats.transitions)
-        self.rounds_ingested += 1
-        self.updates_applied += nv
+        self._count_round(B, nv)
         return stats._replace(max_fill=self._fill())
+
+    def _count_round(self, lanes: int, n_valid: int) -> None:
+        self.rounds_ingested += 1
+        self.updates_applied += n_valid
+        self.pad_lanes += lanes - n_valid
+
+    @staticmethod
+    def _host(x) -> np.ndarray:
+        """``x`` on the host; a device array's read is one
+        ``engine.read`` span, so the span count is the engine's host
+        syncs and its time their wait."""
+        if not isinstance(x, jax.Array):
+            return np.asarray(x)
+        with spans.span("engine.read"):
+            return np.asarray(x)
 
     def _fill(self):
         """Device-scalar fill watermark ``max(deg) / capacity`` — never
@@ -402,7 +434,7 @@ class DynamicWalkEngine:
         r_reasons = g.classify(self._state, r_ins, ru, rv, rw)
         self._state, rstats = self._update(
             self._state, r_ins, ru, rv, rw, r_reasons == R_OK)
-        applied = g.settle_retry(rnd, entries, np.asarray(r_reasons))
+        applied = g.settle_retry(rnd, entries, self._host(r_reasons))
         return rstats if applied else None
 
     @property
@@ -424,13 +456,15 @@ class DynamicWalkEngine:
         g = self.guard
         if g is None or not self._guard_backlog:
             return 0
-        backlog, self._guard_backlog = self._guard_backlog, []
-        for rnd, ins, u, v, w, reasons, dels, nv in backlog:
-            g.account(rnd, np.asarray(ins)[:nv], np.asarray(u)[:nv],
-                      np.asarray(v)[:nv], np.asarray(w)[:nv],
-                      np.asarray(reasons)[:nv])
-            g.deletes_since_retry += int(dels)
-        self._run_guard_retry(self.rounds_ingested)
+        with spans.span("engine.drain_guard"):
+            backlog, self._guard_backlog = self._guard_backlog, []
+            with spans.span("guard.account"):
+                for rnd, ins, u, v, w, reasons, dels, nv in backlog:
+                    g.account(rnd, self._host(ins)[:nv],
+                              self._host(u)[:nv], self._host(v)[:nv],
+                              self._host(w)[:nv], self._host(reasons)[:nv])
+                    g.deletes_since_retry += int(self._host(dels))
+            self._run_guard_retry(self.rounds_ingested)
         return len(backlog)
 
     def audit(self, *, pressure: bool = False) -> dict:
@@ -450,7 +484,7 @@ class DynamicWalkEngine:
         from repro.core.invariants import DEVICE_RULES, check_state_device
         pend = len(self.guard.pending) \
             if (pressure and self.guard is not None) else 0
-        counts = np.asarray(check_state_device(self._state, self.cfg,
+        counts = self._host(check_state_device(self._state, self.cfg,
                                                pend))
         out = dict(zip(DEVICE_RULES, counts.tolist()))
         if pressure:
@@ -465,7 +499,7 @@ class DynamicWalkEngine:
 
     def max_fill(self) -> float:
         """Host-synced fill watermark ``max(deg) / capacity``."""
-        return float(jax.device_get(self._fill()))
+        return float(self._host(self._fill()))
 
     def pressure(self) -> dict:
         """Capacity-pressure gauges: fill watermark, ladder position,
@@ -519,6 +553,10 @@ class DynamicWalkEngine:
             raise ValueError(
                 f"already at the top tier of capacity ladder "
                 f"{self.cfg.ladder}")
+        with spans.span("engine.regrow"):
+            return self._regrow(t)
+
+    def _regrow(self, t: int) -> BingoConfig:
         if self.defer_guard:
             self.drain_guard()
         mig = self._regrow_program(t)
@@ -563,11 +601,16 @@ class DynamicWalkEngine:
         which is all the §12 replay contract needs.  ``walks_served``
         counts real (unpadded) requests only.
         """
-        starts = jnp.asarray(starts, jnp.int32)
-        n = int(starts.shape[0])
-        if key is None:
-            self._key, key = jax.random.split(self._key)
-        if self.walk_buckets is not None:
+        with spans.span("engine.walk"):
+            starts = jnp.asarray(starts, jnp.int32)
+            n = int(starts.shape[0])
+            if key is None:
+                with spans.span("engine.key"):
+                    self._key, key = jax.random.split(self._key)
+            if self.walk_buckets is None:
+                self._state, paths = self._walk(self._state, starts, key)
+                self.walks_served += n
+                return paths
             B = self._bucket_for(n)
             if B != n:
                 # pad lanes: dead (-1) slots in relay mode (free slots,
@@ -579,10 +622,8 @@ class DynamicWalkEngine:
                     [starts, jnp.full((B - n,), fill, jnp.int32)])
             self._state, paths = self._walk(self._state, starts, key)
             self.walks_served += n
+            self.walk_pad_lanes += B - n
             return paths[:n] if B != n else paths
-        self._state, paths = self._walk(self._state, starts, key)
-        self.walks_served += n
-        return paths
 
     def walk_cache_size(self) -> int:
         """Compiled-program count across every tier's walk closure (the

@@ -105,8 +105,7 @@ def make_classifier(cfg: BingoConfig, policy: GuardPolicy = GuardPolicy()):
     """
     V, C = cfg.num_vertices, cfg.capacity
 
-    @jax.jit
-    def classify(state, is_insert, u, v, w):
+    def reason_codes(state, is_insert, u, v, w):
         B = u.shape[0]
         u = jnp.asarray(u, jnp.int32)
         v = jnp.asarray(v, jnp.int32)
@@ -180,6 +179,11 @@ def make_classifier(cfg: BingoConfig, policy: GuardPolicy = GuardPolicy()):
         reasons = jnp.where(ins1 & overflow, R_CAPACITY, reasons)
         reasons = jnp.where(absent, R_ABSENT, reasons)
         return reasons
+
+    @jax.jit
+    def classify(state, is_insert, u, v, w):
+        with jax.named_scope("guard.classify"):
+            return reason_codes(state, is_insert, u, v, w)
 
     return classify
 

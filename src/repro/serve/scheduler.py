@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serve import spans
 from repro.serve.dynwalk import DynamicWalkEngine
 
 __all__ = ["SchedulerConfig", "WalkResult", "UpdateOp", "WalkOp",
@@ -134,12 +135,14 @@ class _QueuedWalk(NamedTuple):
     rid: int
     starts: np.ndarray
     t_submit: float
+    t_queued: float                # perf_counter, for the sched.queue span
 
 
 class _Inflight(NamedTuple):
     paths: jax.Array               # device handle, harvested lazily
     entries: tuple                 # ((rid, offset, size, t_submit), ...)
     generation: int
+    t_dispatch: float              # perf_counter, for sched.inflight
 
 
 class ServingScheduler:
@@ -202,6 +205,11 @@ class ServingScheduler:
         self.updates_offered = 0
         self.updates_rejected = 0
         self.updates_admitted = 0    # lanes flushed into the engine
+        # batching counters: walk cohorts dispatched, and update
+        # windows flushed by the deadline (padded); the engine counts
+        # the pad lanes of both
+        self.cohorts = 0
+        self.deadline_flushes = 0
 
     # -- admission ---------------------------------------------------------
     def submit_walk(self, starts) -> Optional[int]:
@@ -219,7 +227,8 @@ class ServingScheduler:
             return None
         rid = self._next_rid
         self._next_rid += 1
-        self._walk_queue.append(_QueuedWalk(rid, starts, self.clock()))
+        self._walk_queue.append(_QueuedWalk(rid, starts, self.clock(),
+                                            time.perf_counter()))
         self._walk_queue_lanes += n
         return rid
 
@@ -257,12 +266,17 @@ class ServingScheduler:
         """One scheduling quantum: flush due update windows, dispatch
         walk cohorts against the published generation, harvest whatever
         finished — never blocking on device work."""
+        with spans.span("sched.tick"):
+            self._tick()
+
+    def _tick(self) -> None:
         self.tick_count += 1
         while self._update_queue_lanes >= self.cfg.update_lanes:
             self._flush_update_window()
         if self._update_queue and (
                 self.tick_count - self._update_queue[0][5]
                 >= self.cfg.max_update_delay):
+            self.deadline_flushes += 1
             self._flush_update_window()          # deadline flush (padded)
         self._dispatch_walks()
         self._harvest(block=False)
@@ -318,6 +332,8 @@ class ServingScheduler:
                         "admitted": self.updates_admitted,
                         "rejected": self.updates_rejected,
                         "queued_lanes": self._update_queue_lanes},
+            "cohorts": self.cohorts,
+            "deadline_flushes": self.deadline_flushes,
         }
 
     def check_conservation(self) -> None:
@@ -340,6 +356,10 @@ class ServingScheduler:
     def _flush_update_window(self) -> None:
         """Pack up to ``update_lanes`` queued edges into ONE padded
         §5.2 round, ingest it (async dispatch), bump the generation."""
+        with spans.span("sched.flush"):
+            self._flush()
+
+    def _flush(self) -> None:
         lanes = self.cfg.update_lanes
         w_dtype = np.float32 if self.engine.cfg.fp_bias else np.int32
         ins = np.ones(lanes, bool)
@@ -373,6 +393,11 @@ class ServingScheduler:
     def _dispatch_walks(self) -> None:
         """Pack queued walk queries into cohorts (continuous batching)
         and dispatch them against the published generation."""
+        if self._walk_queue and len(self._inflight) < self.cfg.max_inflight:
+            with spans.span("sched.dispatch"):
+                self._dispatch()
+
+    def _dispatch(self) -> None:
         max_b = self.engine.walk_buckets[-1]
         while self._walk_queue and len(self._inflight) < self.cfg.max_inflight:
             batch: List[_QueuedWalk] = []
@@ -385,30 +410,41 @@ class ServingScheduler:
             starts = np.concatenate([q.starts for q in batch])
             self._walk_queue_lanes -= total
             self.walks_admitted += len(batch)
+            self.cohorts += 1
             op = WalkOp(starts, tuple(q.rid for q in batch),
                         tuple(len(q.starts) for q in batch))
             self.trace.append(op)
+            t = time.perf_counter()
+            for q in batch:
+                spans.add("sched.queue", q.t_queued, t, rid=q.rid)
             paths = self.engine.walk(jnp.asarray(starts))
             offs = np.cumsum([0] + list(op.sizes))
             self._inflight.append(_Inflight(
                 paths,
                 tuple((q.rid, int(offs[i]), len(q.starts), q.t_submit)
                       for i, q in enumerate(batch)),
-                self.generation))
+                self.generation, t))
 
     def _harvest(self, *, block: bool) -> None:
         """Collect finished cohorts in dispatch order.  Non-blocking
         mode stops at the first cohort whose device buffer is not
         ready (stream order: later cohorts cannot be ready before it).
         """
+        if self._inflight:
+            with spans.span("sched.harvest"):
+                self._collect(block)
+
+    def _collect(self, block: bool) -> None:
         while self._inflight:
             head = self._inflight[0]
             if not block and not head.paths.is_ready():
                 return
             rows = np.asarray(head.paths)       # blocks only when ready
             t = self.clock()
+            t_done = time.perf_counter()
             self._inflight.popleft()
             for rid, off, size, t_submit in head.entries:
+                spans.add("sched.inflight", head.t_dispatch, t_done, rid=rid)
                 self._completed.append(WalkResult(
                     rid, rows[off:off + size], head.generation,
                     t - t_submit))
@@ -416,7 +452,8 @@ class ServingScheduler:
     def _drain_guard(self) -> None:
         if self.engine.guard is None or not self.engine.guard_backlog:
             return
-        settled = self.engine.drain_guard()
+        with spans.span("sched.drain"):
+            settled = self.engine.drain_guard()
         self.trace.append(DrainOp(settled))
 
     def _maybe_regrow(self) -> None:
@@ -429,10 +466,11 @@ class ServingScheduler:
         eng = self.engine
         if len(eng.cfg.ladder) <= 1:
             return
-        while eng.want_regrow(self.cfg.regrow_watermark):
-            eng.regrow()
-            self.trace.append(RegrowOp(eng.tier))
-            self.generation += 1     # the state buffer was re-laid
+        with spans.span("sched.regrow"):
+            while eng.want_regrow(self.cfg.regrow_watermark):
+                eng.regrow()
+                self.trace.append(RegrowOp(eng.tier))
+                self.generation += 1     # the state buffer was re-laid
 
 
 def replay_admission_trace(engine: DynamicWalkEngine, trace) -> List[np.ndarray]:

@@ -18,6 +18,7 @@ bit-identical to the untiled one.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -59,9 +60,14 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernel):
+    """Compile ``fn`` for the chip; its Mosaic kernel is the custom call
+    named ``kernel`` (the ``pallas_call``'s ``name``, which traces
+    show)."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"%{kernel}(\.\d+)? = [^\n]*custom-call", text), kernel
     m = compiled.memory_analysis()
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
@@ -90,7 +96,7 @@ def test_walk_fused_whole_walk_compiles(one_chip, cohorts, fp, base_log2):
 
     _compile(walk, prob, alias, bias, nbr, deg, frac,
              _spec(one_chip, (2 * BT,), jnp.int32),
-             _spec(one_chip, (1,), jnp.int32))
+             _spec(one_chip, (1,), jnp.int32), kernel="walk_whole")
 
 
 @pytest.mark.parametrize("cohorts", [1, 2, 4])
@@ -104,7 +110,8 @@ def test_walk_fused_segment_compiles(one_chip, cohorts):
                                  segment=True, block_b=BT, cohorts=cohorts)
 
     _compile(seg, prob, alias, bias, nbr, deg, vec,
-             _spec(one_chip, (1,), jnp.int32), vec, vec)
+             _spec(one_chip, (1,), jnp.int32), vec, vec,
+             kernel="walk_segment")
 
 
 @pytest.mark.parametrize("cohorts", [1, 2, 4])
@@ -117,7 +124,7 @@ def test_walk_fused_uniform_compiles(one_chip, cohorts):
                                  cohorts=cohorts)
 
     _compile(walk, nbr, deg, _spec(one_chip, (2 * BT,), jnp.int32),
-             _spec(one_chip, (1,), jnp.int32))
+             _spec(one_chip, (1,), jnp.int32), kernel="walk_whole")
 
 
 @pytest.mark.parametrize("fp,base_log2", [(False, 1), (True, 1), (False, 2)])
@@ -129,13 +136,15 @@ def test_walk_sample_compiles(one_chip, fp, base_log2):
             _spec(s, (BT,), jnp.int32), _spec(s, (BT, 5), jnp.float32)]
     if fp:
         args.append(_spec(s, (BT, C), jnp.float32))
-    _compile(lambda *a: walk_sample_pallas(*a, base_log2=base_log2), *args)
+    _compile(lambda *a: walk_sample_pallas(*a, base_log2=base_log2), *args,
+             kernel="walk_step")
 
 
 def test_walk_sample_uniform_compiles(one_chip):
     s = one_chip
     _compile(walk_sample_uniform_pallas, _spec(s, (BT, C), jnp.int32),
-             _spec(s, (BT,), jnp.int32), _spec(s, (BT, 1), jnp.float32))
+             _spec(s, (BT,), jnp.int32), _spec(s, (BT, 1), jnp.float32),
+             kernel="walk_uniform")
 
 
 @pytest.mark.parametrize("capacity,adaptive,fp", [
@@ -151,7 +160,7 @@ def test_update_fused_compiles(one_chip, capacity, adaptive, fp):
         jnp.bool_, jnp.int32, jnp.int32,
         jnp.float32 if fp else jnp.int32)]
     _compile(lambda st, i, u, v, w: update_fused_pallas(st, cfg, i, u, v, w),
-             state, *lanes)
+             state, *lanes, kernel="update_round")
 
 
 @pytest.mark.parametrize("V_,adaptive,fp", [
