@@ -13,11 +13,14 @@ untouched vertices are never copied), and per grid step only the
 Staging per affected-vertex tile of Rt rows (paper Fig. 10(a)):
 
   * **host-order prepass (jnp, outside the kernel)** — the paper's
-    "CPU-side ordering becomes an on-device sort": inserts sorted by
-    vertex with segmented ranks, deletes lexsorted by (vertex, value)
-    with duplicate ranks, both scattered into dense per-affected-row
-    *patches* (values at their target slots).  Ordering only — no
-    ``BingoState`` tensor is touched outside the kernel;
+    "CPU-side ordering becomes an on-device sort": one stable sort of
+    the lanes by (vertex, deleted value) puts each vertex's inserts in
+    lane order ahead of its deletes; the runs of that sort give every
+    lane its affected row, its insert rank or delete lane and its
+    duplicate rank, and the lanes are scattered into dense
+    per-affected-row *patches* (values at their target slots).
+    Ordering only — no ``BingoState`` tensor is touched outside the
+    kernel;
   * **inserts** — conflict-free append: one lane select places the patch
     values on the lanes ``[deg, deg + inserted)`` (the scatter the
     reference does in HBM happens on the VMEM-resident row);
@@ -80,7 +83,7 @@ from repro.core.dyngraph import (DENSE, BingoConfig, BingoState,
                                  build_itable_rows, classify)
 from repro.core.lanes import lane_cumsum, lane_total
 from repro.core.updates import (NUM_REASONS, R_ABSENT, R_CAPACITY, R_VERTEX,
-                                UpdateStats, _padded_unique)
+                                UpdateStats)
 
 __all__ = ["update_fused_pallas"]
 
@@ -357,21 +360,40 @@ def update_fused_pallas(state: BingoState, cfg: BingoConfig, is_insert,
             w_int = jnp.asarray(w, jnp.int32)
             w_frac = jnp.zeros((B,), jnp.float32)
 
-        # -- ordering prepass (the reference's stage-1/2 sorts, verbatim) --
-        U = _padded_unique(jnp.where(ins | dele, u, V), V)           # (B,)
-        Uc = jnp.minimum(U, V - 1)
+        # -- ordering prepass: ONE stable sort of the lanes by (vertex,
+        # delete value).  Inserts key -1, so they lead their vertex's run
+        # in lane order; its deletes follow, ordered by v; unused lanes
+        # key V and sort last.  A lane's row in U is the number of vertex
+        # runs before it, and each rank is the lane's offset from the
+        # first lane of its run: no search of U --
+        uk = jnp.where(ins | dele, u, V)
+        vk = jnp.where(dele, v, -1)
+        srt = jax.lax.sort((uk, vk, v, w_int) + ((w_frac,) if fp else ()),
+                           num_keys=2)
+        u_s, vk_s, v_s, wi_s = srt[:4]
+        wf_s = srt[4] if fp else None
         idx = jnp.arange(B, dtype=jnp.int32)
 
-        su = jnp.where(ins, u, V)
-        order = jnp.argsort(su)
-        su_s, v_s = su[order], v[order]
-        wi_s, wf_s = w_int[order], w_frac[order]
-        first = jnp.concatenate([jnp.ones((1,), bool), su_s[1:] != su_s[:-1]])
-        rank = idx - jax.lax.cummax(jnp.where(first, idx, -1), axis=0)
-        off = state.deg[jnp.minimum(su_s, V - 1)] + rank
-        okA = (su_s < V) & (off < C)
+        def starts_run(x):
+            return jnp.concatenate([jnp.ones((1,), bool), x[1:] != x[:-1]])
+
+        def run_rank(first):
+            return idx - jax.lax.cummax(jnp.where(first, idx, -1), axis=0)
+
+        first_u = starts_run(u_s)
+        is_del = vk_s >= 0
+        first_k = first_u | starts_run(is_del)
+        rank = run_rank(first_k)     # insert rank, or delete lane, in a row
+        rankD = run_rank(first_k | starts_run(vk_s))  # duplicate (u, v) rank
+        row = jnp.cumsum(first_u, dtype=jnp.int32) - 1
+        # the affected vertices, sorted and padded with V
+        U = jnp.sort(jnp.where(first_u, u_s, V))                     # (B,)
+        Uc = jnp.minimum(U, V - 1)
+
+        off = state.deg[jnp.minimum(u_s, V - 1)] + rank
+        okA = (u_s < V) & ~is_del & (off < C)
         n_ins = jnp.sum(okA, dtype=jnp.int32)
-        rowA = jnp.where(okA, jnp.searchsorted(U, su_s).astype(jnp.int32), B)
+        rowA = jnp.where(okA, row, B)
         offA = jnp.where(okA, off, 0)
         ins_nbr = jnp.zeros((B, C), jnp.int32).at[rowA, offA].set(
             v_s, mode="drop")
@@ -381,23 +403,12 @@ def update_fused_pallas(state: BingoState, cfg: BingoConfig, is_insert,
         deg0 = state.deg[Uc]
         deg_ins = deg0 + ins_cnt
 
-        du = jnp.where(dele, u, V)
-        dv = jnp.where(dele, v, -1)
-        ordD = jnp.lexsort((dv, du))
-        du_s, dv_s = du[ordD], dv[ordD]
-        firstD = jnp.concatenate(
-            [jnp.ones((1,), bool),
-             (du_s[1:] != du_s[:-1]) | (dv_s[1:] != dv_s[:-1])])
-        rankD = idx - jax.lax.cummax(jnp.where(firstD, idx, -1), axis=0)
         Dp = block_dels if block_dels > 0 else min(B, 2 * C)
-        firstR = jnp.concatenate([jnp.ones((1,), bool), du_s[1:] != du_s[:-1]])
-        lane = idx - jax.lax.cummax(jnp.where(firstR, idx, -1), axis=0)
-        rowD = jnp.where((du_s < V) & (lane < Dp),
-                         jnp.searchsorted(U, du_s).astype(jnp.int32), B)
-        laneD = jnp.minimum(lane, Dp - 1)
+        rowD = jnp.where(is_del & (rank < Dp), row, B)
+        laneD = jnp.minimum(rank, Dp - 1)
         # v >= 0 on every valid delete lane, so -1 marks an unused lane
         del_v = jnp.full((B, Dp), -1, jnp.int32).at[rowD, laneD].set(
-            dv_s, mode="drop")
+            vk_s, mode="drop")
         del_rank = jnp.zeros((B, Dp), jnp.int32).at[rowD, laneD].set(
             rankD, mode="drop")
         del_cnt = jnp.zeros((B,), jnp.int32).at[rowD].add(1, mode="drop")

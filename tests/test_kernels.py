@@ -366,25 +366,34 @@ def _subjaxprs(v):
             yield x.jaxpr if hasattr(x, "jaxpr") else x
 
 
-def _count_prims(closed_jaxpr, name, *, inside_loops_only=False):
+def _count_prims(closed_jaxpr, name, *, inside_loops_only=False, skip=(),
+                 scope=None):
     """Recursively count ``name`` eqns across nested (closed) jaxprs.
 
+    ``name`` is a primitive's, or a jitted function's (``searchsorted``).
     ``inside_loops_only`` counts only occurrences under a scan/while —
-    i.e. launches that repeat at run time."""
+    i.e. launches that repeat at run time.  The bodies of the primitives
+    named in ``skip`` (e.g. ``pallas_call``) are not searched, and with
+    ``scope`` only eqns under that ``jax.named_scope`` path count."""
 
-    def walk(j, in_loop):
+    def walk(j, in_loop, stack):
         n = 0
         for eqn in j.eqns:
-            if eqn.primitive.name == name and (in_loop or
-                                               not inside_loops_only):
+            prim = eqn.primitive.name
+            where = f"{stack}/{eqn.source_info.name_stack}"
+            if (name in (prim, eqn.params.get("name"))
+                    and (in_loop or not inside_loops_only)
+                    and (scope is None or scope in where)):
                 n += 1
-            loop = in_loop or eqn.primitive.name in ("scan", "while")
+            if prim in skip:
+                continue
+            loop = in_loop or prim in ("scan", "while")
             for v in eqn.params.values():
                 for s in _subjaxprs(v):
-                    n += walk(s, loop)
+                    n += walk(s, loop, where)
         return n
 
-    return walk(closed_jaxpr.jaxpr, False)
+    return walk(closed_jaxpr.jaxpr, False, "")
 
 
 def test_whole_walk_is_one_pallas_call():

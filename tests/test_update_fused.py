@@ -10,6 +10,8 @@ serving can interleave backends freely and a pallas-ingested state is
 indistinguishable from a reference-ingested one.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -39,20 +41,58 @@ def assert_states_equal(ref, got):
 
 
 def _round(rng, V, edges, Bn, mode):
-    """One update batch: deletes target live edges, inserts are random."""
+    """One update batch: deletes target live edges, inserts are random.
+
+    The last four modes stress the prepass's map from lanes to affected
+    rows: "split" gives one vertex inserts only, one deletes only and
+    one both; "dups" repeats live (u, v) deletes beyond their count,
+    beside inserts of the same pairs; "holes" puts inactive and
+    out-of-range lanes between valid ones (the batch then carries the
+    ``active`` mask as a fifth element); "one_vertex" puts every lane on
+    one vertex."""
     ins = {"insert": np.ones(Bn, bool), "delete": np.zeros(Bn, bool),
-           "mixed": rng.random(Bn) < 0.5}[mode]
+           "mixed": rng.random(Bn) < 0.5}.get(mode)
+    if ins is None:
+        ins = rng.random(Bn) < (0.25 if mode == "dups" else 0.5)
     uu = rng.integers(0, V, Bn).astype(np.int32)
     vv = rng.integers(0, V, Bn).astype(np.int32)
     ww = rng.integers(1, 32, Bn).astype(np.int32)
+    srcs = sorted({e[0] for e in edges})
+    if mode == "split":          # a: inserts, b: deletes, c: both
+        a, b, c = rng.choice(srcs, 3, replace=False)
+        role = rng.integers(0, 3, Bn)
+        uu[:] = np.array([a, b, c])[role]
+        ins[:] = (role == 0) | ((role == 2) & (rng.random(Bn) < 0.5))
+    elif mode == "dups":         # three live pairs, deleted 4-7 times each
+        pairs = [edges[int(i)] for i in rng.choice(len(edges), 3)]
+        pick = rng.integers(0, 3, Bn)
+        uu[:] = [pairs[k][0] for k in pick]
+        vv[:] = [pairs[k][1] for k in pick]
+    elif mode == "one_vertex":
+        uu[:] = rng.choice(srcs)
     for i in range(Bn):
-        if not ins[i] and rng.random() < 0.8 and edges:
-            uu[i], vv[i] = edges[int(rng.integers(len(edges)))]
-    return (jnp.asarray(ins), jnp.asarray(uu), jnp.asarray(vv),
-            jnp.asarray(ww))
+        if not ins[i] and mode != "dups" and rng.random() < 0.8:
+            pool = (edges if mode in ("delete", "mixed", "holes")
+                    else [e for e in edges if e[0] == uu[i]])
+            if pool:
+                uu[i], vv[i] = pool[int(rng.integers(len(pool)))]
+    batch = (jnp.asarray(ins), jnp.asarray(uu), jnp.asarray(vv),
+             jnp.asarray(ww))
+    if mode != "holes":
+        return batch
+    bad = rng.random(Bn) < 0.2   # u = -1, u >= V or v < 0, between valid
+    which = rng.integers(0, 3, Bn)
+    uu = np.where(bad & (which == 0), -1, uu)
+    uu = np.where(bad & (which == 1), V + rng.integers(0, 3, Bn), uu)
+    vv = np.where(bad & (which == 2), -2, vv)
+    act = rng.random(Bn) >= 0.25
+    return batch[:1] + (jnp.asarray(uu, jnp.int32),
+                        jnp.asarray(vv, jnp.int32), batch[3],
+                        jnp.asarray(act))
 
 
-@pytest.mark.parametrize("mode", ["insert", "delete", "mixed"])
+@pytest.mark.parametrize("mode", ["insert", "delete", "mixed", "split",
+                                  "dups", "holes", "one_vertex"])
 @pytest.mark.parametrize("adaptive,fp,base_log2",
                          [(True, False, 1), (False, False, 1),
                           (True, True, 1), (True, False, 2),
@@ -60,8 +100,10 @@ def _round(rng, V, edges, Bn, mode):
 def test_bit_exact_vs_reference(mode, adaptive, fp, base_log2):
     """Full-state bit-exactness across group-representation modes
     (adaptive GA incl. ginv-carrying BS), fp-bias, bases 2/4, and
-    insert-only / delete-only / mixed rounds — chained over 3 rounds so
-    the fused path also consumes its own output."""
+    insert-only / delete-only / mixed rounds and the lane-map stress
+    rounds of ``_round`` — chained over 3 rounds so the fused path also
+    consumes its own output.  B=20 is no multiple of the default
+    ``block_rows`` 8, so the last row tile is always part padding."""
     V, C = 12, 16
     rng = np.random.default_rng(base_log2 * 7 + fp * 3 + adaptive)
     cfg = BingoConfig(num_vertices=V, capacity=C, bias_bits=6,
@@ -76,7 +118,8 @@ def test_bit_exact_vs_reference(mode, adaptive, fp, base_log2):
         batch = _round(rng, V, edges, 20, mode)
         if fp:
             batch = batch[:3] + (batch[3].astype(jnp.float32)
-                                 + rng.random(20).astype(np.float32),)
+                                 + rng.random(20).astype(np.float32),
+                                 ) + batch[4:]
         st_ref, stats_ref = batched_update(st_ref, cfg, *batch)
         st_pal, stats_pal = update_fused(st_pal, cfg, *batch)
         assert_states_equal(st_ref, st_pal)
@@ -229,6 +272,38 @@ def test_one_pallas_call_per_round():
         lambda s, i, u, v, w: get_backend("reference").apply_updates(
             s, cfg, i, u, v, w))(st, *args)
     assert _count_prims(ref, "pallas_call") == 0
+
+
+def test_prepass_has_no_loop_or_search():
+    """The prepass takes each lane's affected row from the runs of its own
+    sort: outside the one pallas_call, a round traces to no searchsorted
+    (on the chip a binary search is a log2(B)-step loop of B-wide
+    gathers) and to one loop only, the alias rows' Vose steps in the
+    epilogue (K steps over lane-dense rows; unrolled they ran slower on
+    a v5e).  The reference keeps its searchsorted, which shows the count
+    can see one."""
+    from tests.test_kernels import _count_prims
+    V, C = 12, 16
+    cfg = BingoConfig(num_vertices=V, capacity=C, bias_bits=5)
+    src, dst, w = random_graph(V, C, max_bias=31, seed=1, density=0.4)
+    st = from_edges(cfg, src, dst, w)
+    Bn = 20
+    args = (jnp.arange(Bn) % 2 == 0, jnp.arange(Bn, dtype=jnp.int32) % V,
+            jnp.ones((Bn,), jnp.int32), jnp.ones((Bn,), jnp.int32))
+
+    fused = jax.make_jaxpr(
+        lambda s, i, u, v, w: get_backend("pallas").apply_updates(
+            s, cfg, i, u, v, w))(st, *args)
+    outside = functools.partial(_count_prims, fused, skip=("pallas_call",))
+    assert outside("searchsorted") == 0
+    for loop in ("scan", "while"):
+        assert outside(loop, scope="update.prepass") == 0, loop
+    loops = outside("scan") + outside("while")
+    assert loops == 1 == outside("scan", scope="update.epilogue/alias_rows")
+
+    ref = jax.make_jaxpr(
+        lambda s, i, u, v, w: batched_update(s, cfg, i, u, v, w))(st, *args)
+    assert _count_prims(ref, "searchsorted") > 0
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
