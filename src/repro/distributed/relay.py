@@ -271,7 +271,7 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
                 diagnostics: bool = False,
                 exchange_fn=None, census: bool = False,
                 overlap: bool = False, wid_base=0, sync_axes=None,
-                with_pending: bool = False):
+                with_pending: bool = False, delivered: bool = False):
     """Per-shard body of the super-step relay (call inside shard_map).
 
     ``bk``/``lcfg``/``params`` — an ``EngineBackend`` with
@@ -337,7 +337,12 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
     once at exit — duplicates from chaos cannot mask a dropped walker),
     the pending count at loop exit (> 0 means the relay gave up with
     work outstanding — only possible against ``max_rounds``), and the
-    psum'd fault counts.  ``with_pending=True`` appends the pending
+    psum'd fault counts.  ``delivered=True`` appends each shard's count
+    of walker records delivered to it across shards (arrivals, not
+    sends: a mailbox spill counts once, when it lands), gathered once
+    at exit into a replicated (shards,) int32 in the order of the
+    mesh's devices — the relay's cross-shard traffic, one record per
+    hop that leaves a shard.  ``with_pending=True`` appends the pending
     count once more as the very last output (the strict-mode hook).
     All default off; the production path is unchanged.
     """
@@ -390,6 +395,9 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
     fin0 = jnp.zeros((W,), bool)
     faults0 = jnp.zeros((3,), jnp.int32)
 
+    def arrivals(rows):
+        return (rows[:, 0] >= 0).sum(dtype=jnp.int32)
+
     def cond(c):
         r = c[0]
         pending = c[-1]
@@ -397,7 +405,7 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
 
     def body(c):
         (r, pend_path, pend_wid, waiting, outbox, acc, ovf, peak,
-         fin, faults, _p) = c
+         fin, faults, dlv, _p) = c
 
         # -- place: free-list allocator drains the waiting queue into
         # open slots (a slot stays pinned while it holds an undelivered
@@ -437,6 +445,7 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
             with jax.named_scope("relay.exchange"):
                 arrived, spill_w, n_spill_w, f_w = exchange_fn(
                     outbox, cap=mailbox_cap, r=r, channel=0)
+                dlv = dlv + arrivals(arrived)
                 pinned = pend_wid >= 0
                 in_home = jnp.where(pinned, (pend_wid - wid_base) // Wb, -1)
                 pay_p = jnp.concatenate(
@@ -527,6 +536,7 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
                 with jax.named_scope("relay.exchange"):
                     arrived, spill_w, n_spill_w, f_w = exchange_fn(
                         pay_w, cap=mailbox_cap, r=r, channel=0)
+                dlv = dlv + arrivals(arrived)
                 outbox = _compact_rows(_dedup_wid(spill_w), W)
                 waiting = _compact_rows(_dedup_wid(
                     jnp.concatenate([waiting, arrived], axis=0)), W)
@@ -578,13 +588,14 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
             ovf = ovf + jax.lax.psum(n_spill_w + n_spill_p,
                                  axis_name=sync_axes)
         return (r + 1, pend_path, pend_wid, waiting, outbox, acc, ovf,
-                peak, fin, faults, pending)
+                peak, fin, faults, dlv, pending)
 
-    (rounds, _, _, _, _, acc, ovf, peak, fin, faults,
+    (rounds, _, _, _, _, acc, ovf, peak, fin, faults, dlv,
      pending_final) = jax.lax.while_loop(
         cond, body,
         (jnp.int32(0), pend_path0, pend_wid0, waiting0, outbox0, acc0,
-         jnp.int32(0), jnp.int32(0), fin0, faults0, pending0))
+         jnp.int32(0), jnp.int32(0), fin0, faults0, jnp.int32(0),
+         pending0))
 
     # acc IS this shard's home block: walker wid's row landed here iff
     # (wid - wid_base) // Wb == sidx, so the P(walker+vertex axes)-
@@ -606,6 +617,8 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
         outs.append(n_fin)
         outs.append(pending_final)
         outs.append(jax.lax.psum(faults, axis_name=sync_axes))
+    if delivered:
+        outs.append(jax.lax.all_gather(dlv, axis_name=sync_axes))
     if with_pending:
         outs.append(pending_final)
     return tuple(outs)
@@ -618,7 +631,7 @@ def make_relay(bk, cfg, params, mesh, *, mailbox_cap: int | None = None,
                diagnostics: bool = False,
                exchange_fn=None, census: bool = False,
                overlap: bool = False, walker_axes=(),
-               strict: bool = False):
+               strict: bool = False, delivered: bool = False):
     """Build the shard_mapped relay: the one wrapper every layer shares.
 
     Vertex-shards ``cfg.num_vertices`` over ``mesh``'s axes MINUS
@@ -646,7 +659,10 @@ def make_relay(bk, cfg, params, mesh, *, mailbox_cap: int | None = None,
     exits against ``max_rounds`` with work outstanding — the check
     needs concrete outputs, so it fires on eager calls and is skipped
     under an enclosing jit (jitted callers read the census outputs
-    instead).  ``exchange_fn``/``census`` thread to ``relay_local`` —
+    instead).  ``delivered=True`` appends the walker records delivered
+    across shards, one count per shard (``(num_groups * num_shards,)``
+    int32, in the order of the mesh's devices).
+    ``exchange_fn``/``census`` thread to ``relay_local`` —
     the chaos harness (``distributed/chaos.py``) swaps the mailbox
     all_to_all and reads the (distinct-finished, pending-at-exit,
     faults) census outputs it appends.  Used by the ``walk_relay`` /
@@ -690,7 +706,7 @@ def make_relay(bk, cfg, params, mesh, *, mailbox_cap: int | None = None,
             diagnostics=diagnostics, exchange_fn=exchange_fn,
             census=census, overlap=overlap,
             wid_base=shard_index(mesh, waxes) * Wg, sync_axes=axes,
-            with_pending=with_pending)
+            with_pending=with_pending, delivered=delivered)
 
     def run(state, walkers, seed, u=None):
         W = walkers.shape[0]
@@ -704,6 +720,7 @@ def make_relay(bk, cfg, params, mesh, *, mailbox_cap: int | None = None,
         out_specs = (P(waxes + vaxes), P(), P()) \
             + ((P(),) if diagnostics else ()) \
             + ((P(), P(), P()) if census else ()) \
+            + ((P(),) if delivered else ()) \
             + ((P(),) if with_pending else ())
         f = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                           out_specs=out_specs, check_vma=False)

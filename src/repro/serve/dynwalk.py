@@ -37,7 +37,9 @@ single-device engine for the same key, at any shard count, with
 per-shard walk state sized to active residents rather than the global
 walker count.  The serving API is unchanged by the compaction.  The
 donated-state discipline is unchanged too: one sharded ``BingoState``
-threads through every ingest and walk.
+threads through every ingest and walk.  Each relay walk also adds its
+rounds, mailbox-overflow re-enqueues and cross-shard walker deliveries
+to device-side totals that ``relay_stats()`` reads on request.
 """
 
 from __future__ import annotations
@@ -110,6 +112,13 @@ class DynamicWalkEngine:
     ``walk`` may be interleaved freely; each is one jitted call (one
     megakernel launch each on the pallas backend — per shard, in
     ``mesh=`` mode, where walks run the exact cross-shard relay).
+
+    In ``mesh=`` mode the relay's own counts ride along on the device:
+    each walk adds its rounds, mailbox-overflow re-enqueues and the
+    walker records delivered across shards (per shard) to totals held
+    in the walk program's carry, and ``relay_stats()`` reads them in
+    one device-to-host read — operator gauges, like ``walk_pad_lanes``;
+    the walk path itself never reads them.
     """
 
     def __init__(self, state: BingoState, cfg: BingoConfig,
@@ -183,6 +192,16 @@ class DynamicWalkEngine:
         # padding (device-to-host reads are ``engine.read`` spans)
         self.pad_lanes = 0
         self.walk_pad_lanes = 0
+        # relay totals (mesh mode), kept on the device: columns rounds,
+        # overflow, then delivered per shard; rows their high and low
+        # 30-bit limbs, so that no count wraps in a long-running engine
+        self._relay_batches = 0
+        self._relay_tally = None
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            self._relay_tally = jax.device_put(
+                jnp.zeros((2, 2 + self.num_shards), jnp.int32),
+                NamedSharding(mesh, P()))
 
     @staticmethod
     def _shard_state(state, mesh, vaxes):
@@ -236,7 +255,9 @@ class DynamicWalkEngine:
         lockstep, so stats sum over vertex axes only); walk = the
         super-step relay — overlapped rounds by default, the production
         schedule — whose stitched (W, L+1) paths are bit-equal to the
-        single-device whole walk for the same key.
+        single-device whole walk for the same key, and whose rounds,
+        overflow and per-shard deliveries add into the carried relay
+        totals (``relay_stats``).
         """
         from jax.sharding import PartitionSpec as P
         from repro.core.backend import get_backend
@@ -249,7 +270,8 @@ class DynamicWalkEngine:
         relay = make_relay(bk, cfg, self.params, mesh,
                            mailbox_cap=self._mailbox_cap,
                            overlap=self._relay_overlap,
-                           walker_axes=waxes)         # validates V % S_v
+                           walker_axes=waxes,         # validates V % S_v
+                           delivered=True)
         shard_size = cfg.num_vertices // self._num_vshards
         lcfg = dataclasses.replace(cfg, num_vertices=shard_size)
         sspec = self._sspec()
@@ -273,10 +295,13 @@ class DynamicWalkEngine:
         def engine_update(st, is_insert, u, v, w, active):
             return smap_upd(st, is_insert, u, v, w, active)
 
-        @functools.partial(jax.jit, donate_argnums=0)
-        def engine_walk(st, starts, key):
-            paths, _rounds, _ovf = relay(st, starts, seed_from_key(key))
-            return st, paths
+        @functools.partial(jax.jit, donate_argnums=(0, 1))
+        def engine_walk(st, tally, starts, key):
+            paths, rounds, ovf, dlv = relay(st, starts, seed_from_key(key))
+            counts = jnp.concatenate([jnp.stack([rounds, ovf]), dlv])
+            lo = tally[1] + counts
+            tally = jnp.stack([tally[0] + (lo >> 30), lo & ((1 << 30) - 1)])
+            return st, tally, paths
 
         return engine_update, engine_walk
 
@@ -608,7 +633,7 @@ class DynamicWalkEngine:
                 with spans.span("engine.key"):
                     self._key, key = jax.random.split(self._key)
             if self.walk_buckets is None:
-                self._state, paths = self._walk(self._state, starts, key)
+                paths = self._dispatch_walk(starts, key)
                 self.walks_served += n
                 return paths
             B = self._bucket_for(n)
@@ -620,10 +645,36 @@ class DynamicWalkEngine:
                 fill = -1 if self.num_shards > 1 else 0
                 starts = jnp.concatenate(
                     [starts, jnp.full((B - n,), fill, jnp.int32)])
-            self._state, paths = self._walk(self._state, starts, key)
+            paths = self._dispatch_walk(starts, key)
             self.walks_served += n
             self.walk_pad_lanes += B - n
             return paths[:n] if B != n else paths
+
+    def _dispatch_walk(self, starts, key):
+        if self._mesh is None:
+            self._state, paths = self._walk(self._state, starts, key)
+            return paths
+        self._state, self._relay_tally, paths = self._walk(
+            self._state, self._relay_tally, starts, key)
+        self._relay_batches += 1
+        return paths
+
+    def relay_stats(self) -> dict:
+        """The relay's totals over every walk batch this engine ran on
+        its mesh: ``batches``, ``rounds`` (relay super-steps, one
+        segment-kernel launch per shard each), ``overflow`` (mailbox
+        re-enqueues), ``delivered`` (walker records that arrived at a
+        shard from another, i.e. hops that left a shard) and
+        ``delivered_per_shard``.  One device-to-host read (an
+        ``engine.read`` span); raises without a mesh."""
+        if self._mesh is None:
+            raise ValueError("relay_stats() needs an engine built with "
+                             "mesh=")
+        hi, lo = self._host(self._relay_tally).astype(np.int64)
+        tot = (hi << 30) + lo
+        return {"batches": self._relay_batches, "rounds": int(tot[0]),
+                "overflow": int(tot[1]), "delivered": int(tot[2:].sum()),
+                "delivered_per_shard": [int(x) for x in tot[2:]]}
 
     def walk_cache_size(self) -> int:
         """Compiled-program count across every tier's walk closure (the

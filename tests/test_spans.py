@@ -289,7 +289,8 @@ cfg = BingoConfig(num_vertices=64, capacity=8, bias_bits=4,
 mesh = jax.make_mesh((4,), ("v",), axis_types=(jax.sharding.AxisType.Auto,))
 eng = DynamicWalkEngine(from_edges(cfg, src, dst, w), cfg,
                         WalkParams(kind="deepwalk", length=4), mesh=mesh)
-walk = eng._walk.lower(eng.state, jnp.arange(8, dtype=jnp.int32),
+walk = eng._walk.lower(eng.state, eng._relay_tally,
+                       jnp.arange(8, dtype=jnp.int32),
                        jax.random.key(0)).as_text(debug_info=True)
 b = jnp.ones(8, bool)
 upd = eng._update.lower(eng.state, b, jnp.zeros(8, jnp.int32),
