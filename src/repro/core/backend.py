@@ -113,10 +113,11 @@ class EngineBackend(Protocol):
     ``u`` (L, B, 6) optionally pins the exact uniform stream), and the
     resumable-segment capability ``sample_walk_segment(state, cfg,
     starts, t0, seed (1,) int32, params, u=None, wid=None) ->
-    (path (B, L+1), frontier (B, 2))`` — one relay round over
+    (path (B, L+1), frontier (B, 2), steps)`` — one relay round over
     per-walker windows [t0, exit) with the counter-based PRNG contract,
     keyed by the slot→wid map ``wid`` so compacted slot layouts draw
-    the walker's own stream (DESIGN.md §10).
+    the walker's own stream (DESIGN.md §10); ``steps`` (tiles,) int32
+    counts the step-loop iterations each tile of the launch ran.
     ``random_walk`` prefers ``sample_walk`` over the per-step scan for
     deepwalk/ppr/simple when present; the distributed relay requires
     ``sample_walk_segment``.

@@ -271,7 +271,7 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
                 diagnostics: bool = False,
                 exchange_fn=None, census: bool = False,
                 overlap: bool = False, wid_base=0, sync_axes=None,
-                with_pending: bool = False, delivered: bool = False):
+                with_pending: bool = False, counters: bool = False):
     """Per-shard body of the super-step relay (call inside shard_map).
 
     ``bk``/``lcfg``/``params`` — an ``EngineBackend`` with
@@ -337,13 +337,16 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
     once at exit — duplicates from chaos cannot mask a dropped walker),
     the pending count at loop exit (> 0 means the relay gave up with
     work outstanding — only possible against ``max_rounds``), and the
-    psum'd fault counts.  ``delivered=True`` appends each shard's count
-    of walker records delivered to it across shards (arrivals, not
-    sends: a mailbox spill counts once, when it lands), gathered once
-    at exit into a replicated (shards,) int32 in the order of the
+    psum'd fault counts.  ``counters=True`` appends two: each shard's
+    count of walker records delivered to it across shards (arrivals,
+    not sends: a mailbox spill counts once, when it lands), gathered
+    once at exit into a replicated (shards,) int32 in the order of the
     mesh's devices — the relay's cross-shard traffic, one record per
-    hop that leaves a shard.  ``with_pending=True`` appends the pending
-    count once more as the very last output (the strict-mode hook).
+    hop that leaves a shard; and the segment kernel's step-loop
+    iterations, summed over tiles, rounds and shards (a replicated
+    scalar; at most ``rounds × shards × tiles × L``).
+    ``with_pending=True`` appends the pending count once more as the
+    very last output (the strict-mode hook).
     All default off; the production path is unchanged.
     """
     W = walkers.shape[0]
@@ -405,7 +408,7 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
 
     def body(c):
         (r, pend_path, pend_wid, waiting, outbox, acc, ovf, peak,
-         fin, faults, dlv, _p) = c
+         fin, faults, dlv, seg, _p) = c
 
         # -- place: free-list allocator drains the waiting queue into
         # open slots (a slot stays pinned while it holds an undelivered
@@ -464,9 +467,10 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
             u_slots = None if u is None else jnp.take(
                 u, jnp.maximum(slot_wid, 0), axis=1)
             starts = jnp.where(occupied, slot_cur, -1)
-            paths, frontier = bk.sample_walk_segment(
+            paths, frontier, steps = bk.sample_walk_segment(
                 view, lcfg, starts, slot_t0, seed, params, u=u_slots,
                 wid=slot_wid)
+            seg = seg + steps.sum()
 
         with jax.named_scope("relay.merge"):
             fr_ok = occupied & (frontier[:, 0] >= 0)
@@ -588,14 +592,14 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
             ovf = ovf + jax.lax.psum(n_spill_w + n_spill_p,
                                  axis_name=sync_axes)
         return (r + 1, pend_path, pend_wid, waiting, outbox, acc, ovf,
-                peak, fin, faults, dlv, pending)
+                peak, fin, faults, dlv, seg, pending)
 
-    (rounds, _, _, _, _, acc, ovf, peak, fin, faults, dlv,
+    (rounds, _, _, _, _, acc, ovf, peak, fin, faults, dlv, seg,
      pending_final) = jax.lax.while_loop(
         cond, body,
         (jnp.int32(0), pend_path0, pend_wid0, waiting0, outbox0, acc0,
          jnp.int32(0), jnp.int32(0), fin0, faults0, jnp.int32(0),
-         pending0))
+         jnp.int32(0), pending0))
 
     # acc IS this shard's home block: walker wid's row landed here iff
     # (wid - wid_base) // Wb == sidx, so the P(walker+vertex axes)-
@@ -617,8 +621,9 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
         outs.append(n_fin)
         outs.append(pending_final)
         outs.append(jax.lax.psum(faults, axis_name=sync_axes))
-    if delivered:
+    if counters:
         outs.append(jax.lax.all_gather(dlv, axis_name=sync_axes))
+        outs.append(jax.lax.psum(seg, axis_name=sync_axes))
     if with_pending:
         outs.append(pending_final)
     return tuple(outs)
@@ -631,7 +636,7 @@ def make_relay(bk, cfg, params, mesh, *, mailbox_cap: int | None = None,
                diagnostics: bool = False,
                exchange_fn=None, census: bool = False,
                overlap: bool = False, walker_axes=(),
-               strict: bool = False, delivered: bool = False):
+               strict: bool = False, counters: bool = False):
     """Build the shard_mapped relay: the one wrapper every layer shares.
 
     Vertex-shards ``cfg.num_vertices`` over ``mesh``'s axes MINUS
@@ -659,9 +664,10 @@ def make_relay(bk, cfg, params, mesh, *, mailbox_cap: int | None = None,
     exits against ``max_rounds`` with work outstanding — the check
     needs concrete outputs, so it fires on eager calls and is skipped
     under an enclosing jit (jitted callers read the census outputs
-    instead).  ``delivered=True`` appends the walker records delivered
+    instead).  ``counters=True`` appends the walker records delivered
     across shards, one count per shard (``(num_groups * num_shards,)``
-    int32, in the order of the mesh's devices).
+    int32, in the order of the mesh's devices), and the segment
+    kernel's step-loop iterations over every tile, round and shard.
     ``exchange_fn``/``census`` thread to ``relay_local`` —
     the chaos harness (``distributed/chaos.py``) swaps the mailbox
     all_to_all and reads the (distinct-finished, pending-at-exit,
@@ -706,7 +712,7 @@ def make_relay(bk, cfg, params, mesh, *, mailbox_cap: int | None = None,
             diagnostics=diagnostics, exchange_fn=exchange_fn,
             census=census, overlap=overlap,
             wid_base=shard_index(mesh, waxes) * Wg, sync_axes=axes,
-            with_pending=with_pending, delivered=delivered)
+            with_pending=with_pending, counters=counters)
 
     def run(state, walkers, seed, u=None):
         W = walkers.shape[0]
@@ -720,7 +726,7 @@ def make_relay(bk, cfg, params, mesh, *, mailbox_cap: int | None = None,
         out_specs = (P(waxes + vaxes), P(), P()) \
             + ((P(),) if diagnostics else ()) \
             + ((P(), P(), P()) if census else ()) \
-            + ((P(),) if delivered else ()) \
+            + ((P(), P()) if counters else ()) \
             + ((P(),) if with_pending else ())
         f = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                           out_specs=out_specs, check_vma=False)

@@ -122,7 +122,8 @@ def walk_segment(prob, alias, bias, nbr, deg, frac, starts, t0, seed,
     walkers keep their stream.  ``wid`` (B,) int32 is the compacted
     relay's slot→wid map — the hash PRNG keys by global walker id, not
     by lane (default identity, ``arange(B)``).  Returns
-    ``(path (B, length+1), frontier (B, 2))``.
+    ``(path (B, length+1), frontier (B, 2), steps (tiles,))``, the
+    last the steps each kernel tile ran (``walk_fused_pallas``).
     """
     if force_ref:
         return _ref.walk_segment_ref(prob, alias, bias, nbr, deg, frac,

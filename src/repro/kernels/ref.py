@@ -207,7 +207,9 @@ def walk_segment_ref(prob, alias, bias, nbr, deg, frac, starts, t0,
     the counter-based ``(seed, wid[b], t)`` hash, where ``wid`` is the
     compacted relay's slot→wid map (default: the batch row) — identical
     columns and semantics to the kernel, bit-exact in both modes.
-    Returns ``(path (B, L+1), frontier (B, 2))``.
+    Returns ``(path (B, L+1), frontier (B, 2), steps (1,))``: the scan
+    is one tile that runs all L steps, where the kernel's tiles report
+    the steps of their own windows.
     """
     B = starts.shape[0]
     L = length
@@ -256,7 +258,8 @@ def walk_segment_ref(prob, alias, bias, nbr, deg, frac, starts, t0,
     colL = jnp.arange(L + 1, dtype=jnp.int32)[None, :]
     path = jnp.where((colL == t0[:, None]) & occupied[:, None],
                      starts[:, None], path)
-    return path, jnp.stack([fv, ft], axis=-1)
+    return (path, jnp.stack([fv, ft], axis=-1),
+            jnp.full((1,), L, jnp.int32))
 
 
 def attention_ref(q, k, v, *, causal=True, window=0, scale=None,

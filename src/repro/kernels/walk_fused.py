@@ -71,6 +71,15 @@ PRNG draws with the *mapped* global walker id, so a walker keeps its
 stream no matter which lane of which shard it currently occupies
 (default ``wid = arange(B)``, the whole-walk identity layout).
 
+A segment tile walks only its own window of steps, not all L: it
+starts at the earliest ``t0`` of its walking lanes and stops once no
+lane is alive and none is still to enter (an empty tile runs no step).
+A relay round's walkers mostly enter late and leave the shard after a
+hop or two, so most of a tile's L steps would find no live lane.  The
+steps skipped are ones in which no lane would draw, gather or write,
+so the result is the full loop's; the kernel reports the steps each
+tile ran.  Whole walks keep the fixed L-step loop.
+
 Uniform column layout (hashed or fed, 6 lanes per walker per step):
 ``u0`` alias bucket, ``u1`` alias coin, ``u2`` member pick, ``u3``
 acceptance coin, ``u4`` ITS position, ``u5`` PPR stop coin.
@@ -151,6 +160,7 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
     # --- unpack refs: inputs, outputs, scratch (order fixed by pallas_call)
     refs = list(refs)
     seed_ref = refs.pop(0)                     # (1,) SMEM
+    win_ref = refs.pop(0) if segment else None  # (2*grid,) SMEM windows
     starts_ref = refs.pop(0)                   # (Bt, 1) VMEM
     t0_ref = refs.pop(0) if segment else None  # (Bt, 1) VMEM
     wid_ref = refs.pop(0) if segment else None  # (Bt, 1) VMEM slot→wid
@@ -161,8 +171,10 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
     tabs = tuple(refs.pop(0) for _ in range(ntab))
     out_ref = refs.pop(0)                      # (Bt, L+1) VMEM
     fr_ref = refs.pop(0) if segment else None  # (Bt, 2) VMEM
+    steps_ref = refs.pop(0) if segment else None  # (grid,) SMEM
     bufs = tuple(refs.pop(0) for _ in tabs)    # (nslots, rows, 1, W) VMEM
-    state_v, state_s, gsem, ssem = refs        # VMEM/SMEM (Bt,2), DMA sems
+    state_v, state_s, gsem, ssem = refs[:4]    # VMEM/SMEM (Bt,2), DMA sems
+    live_s = refs[4] if segment else None      # (1,) SMEM: lanes started
 
     # Walker identity for the counter-based PRNG, hoisted out of the
     # step loop (``pl.program_id`` must sit at kernel top level).
@@ -173,6 +185,11 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
     # change any walker's stream.
     if segment:
         wid_all = None                  # read from wid_ref per phase
+        # The tile's step window (see ``walk_fused_pallas``): its
+        # earliest entry step, and its latest one.
+        tile = pl.program_id(0)
+        t_lo = win_ref[2 * tile]
+        t_last = win_ref[2 * tile + 1]
     else:
         wid_all = (pl.program_id(0) * Bt
                    + jax.lax.broadcasted_iota(jnp.int32, (Bt, 1), 0))
@@ -185,7 +202,9 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
         win: dead walkers stop gathering (and must skip the wait too —
         the predicate is stable between the paired loops because a
         cohort's ``state_s`` lanes are only rewritten by its own phase,
-        after the previous ``wait`` and before the next ``start``)."""
+        after the previous ``wait`` and before the next ``start``).
+        In a segment, ``start`` also counts the lanes it starts into
+        ``live_s``: the walkers alive at the next step."""
         def body(b, _):
             @pl.when(state_s[lane0 + b, 1] != 0)
             def _():
@@ -194,6 +213,8 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
                     dma = pltpu.make_async_copy(tab.at[v], buf.at[slot, b],
                                                 gsem.at[slot])
                     getattr(dma, action)()
+                if segment and action == "start":
+                    live_s[0] += 1
             return 0
         jax.lax.fori_loop(0, Bc, body, 0)
 
@@ -211,7 +232,8 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
         cp.wait()
 
     # --- prologue: start vertex at col t0 (col 0 when not a segment),
-    # everything else -1, stage the step-0 rows of the t0 == 0 walkers.
+    # everything else -1, stage the first step's rows: step 0 of every
+    # walker, or in a segment step t_lo of the walkers entering there.
     starts = starts_ref[...]
     colL = jax.lax.broadcasted_iota(jnp.int32, (Bc, length + 1), 1)
     if segment:
@@ -220,7 +242,8 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
         colT = jax.lax.broadcasted_iota(jnp.int32, (Bt, length + 1), 1)
         out_ref[...] = jnp.where((colT == t0) & occupied, starts, -1)
         fr_ref[...] = jnp.full((Bt, 2), -1, jnp.int32)
-        alive0 = occupied & (t0 == 0)
+        alive0 = occupied & (t0 == t_lo) & (t0 < length)
+        live_s[0] = 0
     else:
         t0 = jnp.zeros((Bt, 1), jnp.int32)
         colT = jax.lax.broadcasted_iota(jnp.int32, (Bt, length + 1), 1)
@@ -230,7 +253,7 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
     state_v[:, 1:2] = alive0.astype(jnp.int32)
     sync_state()
     if K == 1:
-        gather(0, 0, "start")
+        gather(jax.lax.rem(t_lo, 2) if segment else 0, 0, "start")
     else:
         for c in range(K):
             gather(c, c * Bc, "start")
@@ -325,7 +348,26 @@ def _kernel(length, base_log2, stop_prob, uniform, has_frac, has_u,
                 phase(t, c, c, c)
         return 0
 
-    jax.lax.fori_loop(0, length, step, 0)
+    if not segment:
+        jax.lax.fori_loop(0, length, step, 0)
+        return
+
+    # A segment walks only its tile's window: from t_lo while a lane is
+    # alive or still to enter (a lane writes only columns of its own
+    # [t0, L] window, so no step outside the window writes anything).
+    def live_step(c):
+        t, live = c
+        return (t < length) & ((live > 0) | (t < t_last))
+
+    def windowed_step(c):
+        t, _ = c
+        live_s[0] = 0
+        step(t, 0)
+        return t + 1, live_s[0]
+
+    t_end, _ = jax.lax.while_loop(live_step, windowed_step,
+                                  (t_lo, live_s[0]))
+    steps_ref[tile] = t_end - t_lo
 
 
 @functools.partial(
@@ -353,12 +395,15 @@ def walk_fused_pallas(prob, alias, bias, nbr, deg, frac, starts, seed,
     ``segment=True`` is the resumable entry (DESIGN.md §10): ``t0``
     (B,) int32 gives each walker's start step, ``starts < 0`` marks free
     slots, adjacency values ``<= -2`` are remote neighbors encoded as
-    ``-(global_id + 2)``, and the return becomes ``(path, frontier)``
-    with ``frontier`` (B, 2) int32 ``[vertex, step]`` exit records
-    (-1 where the walker finished locally).  ``wid`` (B,) int32 is the
-    slot→wid map of the compacted relay: the hash PRNG is keyed by
-    ``wid[b]``, not by the lane index ``b`` (default ``arange(B)`` —
-    identity, i.e. the uncompacted layout).
+    ``-(global_id + 2)``, and the return becomes ``(path, frontier,
+    steps)`` with ``frontier`` (B, 2) int32 ``[vertex, step]`` exit
+    records (-1 where the walker finished locally) and ``steps``
+    (tiles,) int32 the steps each tile's loop ran: from the tile's
+    earliest ``t0`` to the last step at which one of its lanes was
+    alive, or 0 for a tile with no lane to walk.  ``wid`` (B,) int32
+    is the slot→wid map of the compacted relay: the hash PRNG is keyed
+    by ``wid[b]``, not by the lane index ``b`` (default ``arange(B)``
+    — identity, i.e. the uncompacted layout).
 
     Returns the (B, length+1) int32 path; column ``t0`` (0 for whole
     walks) is the start vertex, columns outside a walker's segment
@@ -398,11 +443,24 @@ def walk_fused_pallas(prob, alias, bias, nbr, deg, frac, starts, seed,
     if segment and wid is None:
         wid = jnp.arange(B, dtype=jnp.int32)
 
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),              # seed
-        pl.BlockSpec((block_b, 1), lambda i: (i, 0)),       # starts
-    ]
-    args = [seed, starts[:, None]]
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]    # seed
+    args = [seed]
+    if segment:
+        # each tile's step window: [min t0, max t0] over the lanes that
+        # walk (an empty tile gets [L, -1] and runs no step)
+        walking = (starts >= 0) & (t0 >= 0) & (t0 < length)
+        extra = grid[0] * block_b - B
+
+        def per_tile(x, fill):
+            return jnp.pad(x, (0, extra), constant_values=fill).reshape(
+                grid[0], block_b)
+
+        t_lo = per_tile(jnp.where(walking, t0, length), length).min(1)
+        t_last = per_tile(jnp.where(walking, t0, -1), -1).max(1)
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.append(jnp.stack([t_lo, t_last], -1).reshape(-1))
+    in_specs.append(pl.BlockSpec((block_b, 1), lambda i: (i, 0)))  # starts
+    args.append(starts[:, None])
     if segment:
         in_specs.append(pl.BlockSpec((block_b, 1), lambda i: (i, 0)))
         args.append(t0[:, None])
@@ -440,6 +498,8 @@ def walk_fused_pallas(prob, alias, bias, nbr, deg, frac, starts, seed,
     if segment:
         out_specs.append(pl.BlockSpec((block_b, 2), lambda i: (i, 0)))
         out_shape.append(jax.ShapeDtypeStruct((B, 2), jnp.int32))
+        out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        out_shape.append(jax.ShapeDtypeStruct(grid, jnp.int32))
 
     scratch = [pltpu.VMEM((nslots, rows, 1, t.shape[-1]), t.dtype)
                for t in tab_args]
@@ -449,6 +509,8 @@ def walk_fused_pallas(prob, alias, bias, nbr, deg, frac, starts, seed,
         pltpu.SemaphoreType.DMA((nslots,)),         # row gathers, per slot
         pltpu.SemaphoreType.DMA(()),                # state mirror copy
     ]
+    if segment:
+        scratch.append(pltpu.SMEM((1,), jnp.int32))  # live_s
     kern = functools.partial(_kernel, length, base_log2, float(stop_prob),
                              uniform, has_frac, has_u, segment, block_b, V,
                              cohorts, Kin)
@@ -463,4 +525,4 @@ def walk_fused_pallas(prob, alias, bias, nbr, deg, frac, starts, seed,
             interpret=interpret,
             name="walk_segment" if segment else "walk_whole",
         )(*args)
-    return (out[0], out[1]) if segment else out[0]
+    return tuple(out) if segment else out[0]

@@ -38,8 +38,9 @@ per-shard walk state sized to active residents rather than the global
 walker count.  The serving API is unchanged by the compaction.  The
 donated-state discipline is unchanged too: one sharded ``BingoState``
 threads through every ingest and walk.  Each relay walk also adds its
-rounds, mailbox-overflow re-enqueues and cross-shard walker deliveries
-to device-side totals that ``relay_stats()`` reads on request.
+rounds, mailbox-overflow re-enqueues, segment-kernel loop steps and
+cross-shard walker deliveries to device-side totals that
+``relay_stats()`` reads on request.
 """
 
 from __future__ import annotations
@@ -114,11 +115,12 @@ class DynamicWalkEngine:
     ``mesh=`` mode, where walks run the exact cross-shard relay).
 
     In ``mesh=`` mode the relay's own counts ride along on the device:
-    each walk adds its rounds, mailbox-overflow re-enqueues and the
-    walker records delivered across shards (per shard) to totals held
-    in the walk program's carry, and ``relay_stats()`` reads them in
-    one device-to-host read — operator gauges, like ``walk_pad_lanes``;
-    the walk path itself never reads them.
+    each walk adds its rounds, mailbox-overflow re-enqueues, segment-
+    kernel loop steps and the walker records delivered across shards
+    (per shard) to totals held in the walk program's carry, and
+    ``relay_stats()`` reads them in one device-to-host read — operator
+    gauges, like ``walk_pad_lanes``; the walk path itself never reads
+    them.
     """
 
     def __init__(self, state: BingoState, cfg: BingoConfig,
@@ -193,14 +195,15 @@ class DynamicWalkEngine:
         self.pad_lanes = 0
         self.walk_pad_lanes = 0
         # relay totals (mesh mode), kept on the device: columns rounds,
-        # overflow, then delivered per shard; rows their high and low
-        # 30-bit limbs, so that no count wraps in a long-running engine
+        # overflow, segment steps, then delivered per shard; rows their
+        # high and low 30-bit limbs, so that no count wraps in a
+        # long-running engine
         self._relay_batches = 0
         self._relay_tally = None
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
             self._relay_tally = jax.device_put(
-                jnp.zeros((2, 2 + self.num_shards), jnp.int32),
+                jnp.zeros((2, 3 + self.num_shards), jnp.int32),
                 NamedSharding(mesh, P()))
 
     @staticmethod
@@ -256,8 +259,8 @@ class DynamicWalkEngine:
         super-step relay — overlapped rounds by default, the production
         schedule — whose stitched (W, L+1) paths are bit-equal to the
         single-device whole walk for the same key, and whose rounds,
-        overflow and per-shard deliveries add into the carried relay
-        totals (``relay_stats``).
+        overflow, segment steps and per-shard deliveries add into the
+        carried relay totals (``relay_stats``).
         """
         from jax.sharding import PartitionSpec as P
         from repro.core.backend import get_backend
@@ -271,7 +274,7 @@ class DynamicWalkEngine:
                            mailbox_cap=self._mailbox_cap,
                            overlap=self._relay_overlap,
                            walker_axes=waxes,         # validates V % S_v
-                           delivered=True)
+                           counters=True)
         shard_size = cfg.num_vertices // self._num_vshards
         lcfg = dataclasses.replace(cfg, num_vertices=shard_size)
         sspec = self._sspec()
@@ -297,8 +300,9 @@ class DynamicWalkEngine:
 
         @functools.partial(jax.jit, donate_argnums=(0, 1))
         def engine_walk(st, tally, starts, key):
-            paths, rounds, ovf, dlv = relay(st, starts, seed_from_key(key))
-            counts = jnp.concatenate([jnp.stack([rounds, ovf]), dlv])
+            paths, rounds, ovf, dlv, seg = relay(st, starts,
+                                                 seed_from_key(key))
+            counts = jnp.concatenate([jnp.stack([rounds, ovf, seg]), dlv])
             lo = tally[1] + counts
             tally = jnp.stack([tally[0] + (lo >> 30), lo & ((1 << 30) - 1)])
             return st, tally, paths
@@ -663,9 +667,12 @@ class DynamicWalkEngine:
         """The relay's totals over every walk batch this engine ran on
         its mesh: ``batches``, ``rounds`` (relay super-steps, one
         segment-kernel launch per shard each), ``overflow`` (mailbox
-        re-enqueues), ``delivered`` (walker records that arrived at a
-        shard from another, i.e. hops that left a shard) and
-        ``delivered_per_shard``.  One device-to-host read (an
+        re-enqueues), ``segment_steps`` (the segment kernel's step-loop
+        iterations over every tile, round and shard: its share of
+        ``rounds × shards × tiles × L`` is the part of a fixed L-step
+        loop that still runs), ``delivered`` (walker records that
+        arrived at a shard from another, i.e. hops that left a shard)
+        and ``delivered_per_shard``.  One device-to-host read (an
         ``engine.read`` span); raises without a mesh."""
         if self._mesh is None:
             raise ValueError("relay_stats() needs an engine built with "
@@ -673,8 +680,9 @@ class DynamicWalkEngine:
         hi, lo = self._host(self._relay_tally).astype(np.int64)
         tot = (hi << 30) + lo
         return {"batches": self._relay_batches, "rounds": int(tot[0]),
-                "overflow": int(tot[1]), "delivered": int(tot[2:].sum()),
-                "delivered_per_shard": [int(x) for x in tot[2:]]}
+                "overflow": int(tot[1]), "segment_steps": int(tot[2]),
+                "delivered": int(tot[3:].sum()),
+                "delivered_per_shard": [int(x) for x in tot[3:]]}
 
     def walk_cache_size(self) -> int:
         """Compiled-program count across every tier's walk closure (the

@@ -268,39 +268,107 @@ def _remoteify(nbr, frac_remote=0.3, seed=0):
     return jnp.where(mask, -(nbr + 2), nbr)
 
 
-@pytest.mark.parametrize("base_log2,fp,stop,uniform,fed", [
-    (1, False, 0.0, False, True),    # base-2 integer, fed uniforms
-    (2, True, 0.15, False, True),    # base-4 + fp + PPR stop
-    (1, False, 0.0, True, True),     # simple-kind degree pick
-    (1, False, 0.0, False, False),   # hash-PRNG mode
-])
-def test_walk_segment_matches_ref(base_log2, fp, stop, uniform, fed):
-    """Resumable segment entry vs the windowed scan oracle: random
-    per-walker start steps t0 (incl. t0 == L final-hop-only and free
-    starts < 0 slots), remote-encoded adjacency entries -> (vertex,
-    step) frontier records, bit-exact path AND frontier in both the
-    fed-uniform and counter-hash PRNG modes (DESIGN.md §10)."""
-    st, cfg = _fused_case(base_log2=base_log2, fp=fp)
-    B, L = 29, 8
+def _segment_layout(layout, num_verts, L):
+    """Per-lane ``(starts, t0)`` and the tile size for a segment case.
+
+    ``random``: 29 lanes in tiles of 16 (the last one ragged), about a
+    fifth of them free, ``t0`` anywhere in [0, L].  ``tiles``: four
+    tiles of 16, each a window case of its own — empty; late entries
+    only (``t0`` of L-2, L-1 and L, the last writing its prologue
+    column alone); entries at 0 and at L-2 with a gap between them;
+    one live lane.
+    """
     rng = np.random.default_rng(3)
-    starts = jnp.asarray(rng.integers(0, cfg.num_vertices, B), jnp.int32)
-    starts = jnp.where(jnp.asarray(rng.random(B) < 0.2), -1, starts)
-    t0 = jnp.asarray(rng.integers(0, L + 1, B), jnp.int32)
+    if layout == "random":
+        B = 29
+        starts = rng.integers(0, num_verts, B)
+        starts = np.where(rng.random(B) < 0.2, -1, starts)
+        return starts, rng.integers(0, L + 1, B), 16
+    bt = 16
+    starts = np.full(4 * bt, -1)
+    t0 = np.zeros(4 * bt, np.int64)
+    late = slice(bt, 2 * bt)
+    starts[late] = rng.integers(0, num_verts, bt)
+    t0[late] = rng.choice([L - 2, L - 1, L], bt)
+    gap = slice(2 * bt, 3 * bt)
+    starts[gap] = rng.integers(0, num_verts, bt)
+    t0[gap] = np.where(np.arange(bt) % 2, 0, L - 2)
+    starts[3 * bt + 5], t0[3 * bt + 5] = 1, 3
+    return starts, t0, bt
+
+
+def _tile_steps(path, starts, t0, L, bt):
+    """Each tile's window from the oracle's paths: from the earliest
+    ``t0`` of its walking lanes to the last step at which one of them
+    is alive (a lane is alive at step t >= t0 iff its path holds a
+    vertex in column t), 0 where no lane walks."""
+    path, starts, t0 = (np.asarray(a) for a in (path, starts, t0))
+    cols = np.arange(L)
+    out = []
+    for lo in range(0, len(starts), bt):
+        sl = slice(lo, lo + bt)
+        walk = (starts[sl] >= 0) & (t0[sl] >= 0) & (t0[sl] < L)
+        alive = ((path[sl, :L] >= 0) & (cols >= t0[sl, None])
+                 & walk[:, None])
+        out.append(cols[alive.any(0)].max() - t0[sl][walk].min() + 1
+                   if walk.any() else 0)
+    return out
+
+
+@pytest.mark.parametrize("base_log2,fp,stop,uniform,fed,cohorts,layout", [
+    (1, False, 0.0, False, True, 1, "random"),   # base-2, fed uniforms
+    (2, True, 0.15, False, True, 1, "random"),   # base-4 + fp + PPR stop
+    (1, False, 0.0, True, True, 1, "random"),    # simple-kind degree pick
+    (1, False, 0.0, False, False, 1, "random"),  # hash-PRNG mode
+    (2, True, 0.15, False, True, 2, "random"),   # cohort interleaving
+    (2, True, 0.15, False, False, 2, "random"),
+    (2, True, 0.15, False, True, 4, "random"),
+    (2, True, 0.15, False, False, 4, "random"),
+    (2, True, 0.15, False, True, 1, "tiles"),    # per-tile windows
+    (2, True, 0.15, False, False, 1, "tiles"),
+    (2, True, 0.15, False, True, 2, "tiles"),
+    (2, True, 0.15, False, False, 2, "tiles"),
+    (2, True, 0.15, False, True, 4, "tiles"),
+    (2, True, 0.15, False, False, 4, "tiles"),
+    (1, False, 0.0, True, False, 1, "tiles"),
+])
+def test_walk_segment_matches_ref(base_log2, fp, stop, uniform, fed,
+                                  cohorts, layout):
+    """Resumable segment entry vs the windowed scan oracle: per-walker
+    start steps t0 (incl. t0 == L final-hop-only and free starts < 0
+    slots), remote-encoded adjacency entries -> (vertex, step) frontier
+    records, bit-exact path AND frontier in both the fed-uniform and
+    counter-hash PRNG modes and at every cohort count K (DESIGN.md
+    §10; the relay's bit-equality depends on this).  Each tile walks
+    only its own window of steps, which its step count reports; the
+    ``tiles`` layout puts an empty tile, a late tile, a gap and a lone
+    lane side by side."""
+    st, cfg = _fused_case(base_log2=base_log2, fp=fp)
+    L = 8
+    starts, t0, bt = _segment_layout(layout, cfg.num_vertices, L)
+    starts = jnp.asarray(starts, jnp.int32)
+    t0 = jnp.asarray(t0, jnp.int32)
+    B = starts.shape[0]
     nbr = _remoteify(st.nbr)
     u = jax.random.uniform(jax.random.key(4), (L, B, 6)) if fed else None
     seed = jnp.array([99], jnp.int32)
     frac = st.frac if fp else None
     args = ((None, None, None, nbr, st.deg, None) if uniform else
             (st.itable.prob, st.itable.alias, st.bias, nbr, st.deg, frac))
-    path_k, fr_k = walk_fused_pallas(
+    path_k, fr_k, steps_k = walk_fused_pallas(
         *args, starts, seed, u, t0, length=L, base_log2=base_log2,
-        stop_prob=stop, uniform=uniform, segment=True, block_b=16,
-        interpret=True)
-    path_r, fr_r = ref.walk_segment_ref(
+        stop_prob=stop, uniform=uniform, segment=True, block_b=bt,
+        cohorts=cohorts, interpret=True)
+    path_r, fr_r, _ = ref.walk_segment_ref(
         *args, starts, t0, u, length=L, base_log2=base_log2,
-        stop_prob=stop, uniform=uniform, seed=seed)
+        stop_prob=stop, uniform=uniform, seed=seed, cohorts=cohorts)
     np.testing.assert_array_equal(np.asarray(path_k), np.asarray(path_r))
     np.testing.assert_array_equal(np.asarray(fr_k), np.asarray(fr_r))
+    steps = np.asarray(steps_k).tolist()
+    assert steps == _tile_steps(path_r, starts, t0, L, bt)
+    if layout == "tiles":
+        assert steps[0] == 0                       # the empty tile
+        assert 1 <= steps[1] <= 2                  # entries at L-2, L-1
     # structural checks: free slots emit nothing; a frontier record's
     # step column is inside (0, L]; columns before t0 stay -1
     pk, fk = np.asarray(path_k), np.asarray(fr_k)
@@ -310,6 +378,40 @@ def test_walk_segment_matches_ref(base_log2, fp, stop, uniform, fed):
     assert ((fk[has_fr, 1] > 0) & (fk[has_fr, 1] <= L)).all()
     cols = np.arange(L + 1)[None, :]
     assert (pk[cols < np.asarray(t0)[:, None]] == -1).all()
+
+
+@pytest.mark.parametrize("cohorts", [1, 2, 4])
+def test_walk_segment_tile_steps(cohorts):
+    """The steps a tile runs, by hand on a ring (1 -> 2 -> ... -> 7 ->
+    1) beside a dead end (vertex 0), L = 6, tiles of 8: a walker on
+    the ring never stops, one entering at the dead end dies at once."""
+    from repro.core.dyngraph import BingoConfig, from_edges
+    src = np.array([1, 2, 3, 4, 5, 6, 7], np.int32)
+    dst = np.array([2, 3, 4, 5, 6, 7, 1], np.int32)
+    cfg = BingoConfig(num_vertices=8, capacity=2, bias_bits=2)
+    st = from_edges(cfg, src, dst, np.ones(7, np.int32))
+    L, bt = 6, 8
+    lanes = {  # tile: {lane: (start, t0)}, every other lane free
+        0: {},                          # empty: no step
+        1: {2: (3, 2)},                 # ring from step 2: steps 2..5
+        2: {0: (0, 1), 7: (0, 4)},      # dead at 1 and at 4: steps 1..4
+        3: {4: (5, L)},                 # prologue column only: no step
+        4: {1: (0, 0), 6: (2, 5)},      # dead at 0, ring at 5: steps 0..5
+    }
+    starts = np.full(len(lanes) * bt, -1)
+    t0 = np.zeros(len(lanes) * bt, np.int64)
+    for tile, ls in lanes.items():
+        for lane, (s, t) in ls.items():
+            starts[tile * bt + lane], t0[tile * bt + lane] = s, t
+    path, _, steps = walk_fused_pallas(
+        st.itable.prob, st.itable.alias, st.bias, st.nbr, st.deg, None,
+        jnp.asarray(starts, jnp.int32), jnp.array([3], jnp.int32), None,
+        jnp.asarray(t0, jnp.int32), length=L, segment=True, block_b=bt,
+        cohorts=cohorts, interpret=True)
+    assert np.asarray(steps).tolist() == [0, 4, 4, 0, 6]
+    path = np.asarray(path)
+    assert path[3 * bt + 4].tolist() == [-1] * L + [5]
+    assert (path[bt + 2, 2:] >= 1).all() and (path[bt + 2, :2] == -1).all()
 
 
 def test_walk_segments_stitch_to_whole_walk():
@@ -334,7 +436,7 @@ def test_walk_segments_stitch_to_whole_walk():
         return walk_fused_pallas(
             st.itable.prob, st.itable.alias, st.bias, nbr, st.deg, None,
             s, seed, None, t, length=L, segment=True, block_b=16,
-            interpret=True)
+            interpret=True)[:2]
 
     acc = jnp.full((B, L + 1), -1, jnp.int32)
     s_lo = jnp.where(starts < half, starts, -1)
@@ -498,42 +600,6 @@ def test_walk_fused_cohorts_dead_cohort(cohorts):
     # dead cohort terminated at once; live walkers never did (ring)
     assert (got[:B // cohorts, 1:] == -1).all()
     assert (got[B // cohorts:] >= 0).all()
-
-
-@pytest.mark.parametrize("cohorts", [2, 4])
-@pytest.mark.parametrize("fed", [True, False])
-def test_walk_segment_cohorts_bitexact(cohorts, fed):
-    """Segment entry under cohort interleaving: remote-encoded
-    adjacency, random t0 windows, free slots — path AND frontier must
-    match K=1 and the windowed oracle in fed and hash-PRNG modes (the
-    relay's bit-equality depends on this)."""
-    st, cfg = _fused_case(base_log2=2, fp=True)
-    B, L = 29, 8
-    rng = np.random.default_rng(3)
-    starts = jnp.asarray(rng.integers(0, cfg.num_vertices, B), jnp.int32)
-    starts = jnp.where(jnp.asarray(rng.random(B) < 0.2), -1, starts)
-    t0 = jnp.asarray(rng.integers(0, L + 1, B), jnp.int32)
-    nbr = _remoteify(st.nbr)
-    u = jax.random.uniform(jax.random.key(4), (L, B, 6)) if fed else None
-    seed = jnp.array([99], jnp.int32)
-
-    def run(K):
-        return walk_fused_pallas(
-            st.itable.prob, st.itable.alias, st.bias, nbr, st.deg,
-            st.frac, starts, seed, u, t0, length=L, base_log2=2,
-            stop_prob=0.15, segment=True, block_b=16, cohorts=K,
-            interpret=True)
-
-    p1, f1 = (np.asarray(a) for a in run(1))
-    pk, fk = (np.asarray(a) for a in run(cohorts))
-    np.testing.assert_array_equal(pk, p1)
-    np.testing.assert_array_equal(fk, f1)
-    p_r, f_r = ref.walk_segment_ref(
-        st.itable.prob, st.itable.alias, st.bias, nbr, st.deg, st.frac,
-        starts, t0, u, length=L, base_log2=2, stop_prob=0.15, seed=seed,
-        cohorts=cohorts)
-    np.testing.assert_array_equal(pk, np.asarray(p_r))
-    np.testing.assert_array_equal(fk, np.asarray(f_r))
 
 
 @pytest.mark.parametrize("cohorts", [1, 2, 4])

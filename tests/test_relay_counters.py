@@ -1,7 +1,7 @@
 """The relay's counters on four virtual CPU devices: the walker records
-it delivers across shards, its rounds against ``round_bound``, and the
-sharded engine's ``relay_stats()`` totals over several walks, for the
-bulk and the overlapped schedule.
+it delivers across shards, its rounds against ``round_bound``, the
+segment kernel's loop steps, and the sharded engine's ``relay_stats()``
+totals over several walks, for the bulk and the overlapped schedule.
 
 A walker record crosses shards once per hop whose two vertices lie on
 different shards, so the delivered count of each shard must equal the
@@ -29,7 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import walks
 from repro.core.backend import get_backend
 from repro.core.dyngraph import BingoConfig, from_edges
-from repro.distributed.relay import make_relay, round_bound
+from repro.distributed.relay import make_relay, round_bound, slot_count
 from repro.kernels.ops import seed_from_key
 from repro.serve.dynwalk import DynamicWalkEngine
 from tests.conftest import random_graph
@@ -65,9 +65,9 @@ for sched in ("bulk", "overlap"):
     rep = {}
     for cap in (None, 1):
         relay = jax.jit(make_relay(bk, cfg, params, mesh, overlap=overlap,
-                                   mailbox_cap=cap, delivered=True))
-        paths, rounds, ovf, dlv = relay(state(), starts,
-                                        seed_from_key(key0))
+                                   mailbox_cap=cap, counters=True))
+        paths, rounds, ovf, dlv, _ = relay(state(), starts,
+                                           seed_from_key(key0))
         rep[str(cap)] = {
             "exact": bool(np.array_equal(np.asarray(paths), single)),
             "rounds": int(rounds), "overflow": int(ovf),
@@ -77,16 +77,18 @@ for sched in ("bulk", "overlap"):
     eng = DynamicWalkEngine(state(), cfg, params, mesh=mesh,
                             relay_overlap=overlap, seed=11)
     relay = jax.jit(make_relay(bk, cfg, params, mesh, overlap=overlap,
-                               delivered=True))
-    want = {"rounds": 0, "overflow": 0, "delivered": [0] * S}
+                               counters=True))
+    want = {"rounds": 0, "overflow": 0, "segment_steps": 0,
+            "delivered": [0] * S}
     programs = []
     for b in range(3):
         key = jax.random.key(100 + b)
         paths = eng.walk(jnp.roll(starts, 5 * b), key=key)
-        _, r, o, d = relay(state(), jnp.roll(starts, 5 * b),
-                           seed_from_key(key))
+        _, r, o, d, g = relay(state(), jnp.roll(starts, 5 * b),
+                              seed_from_key(key))
         want["rounds"] += int(r)
         want["overflow"] += int(o)
+        want["segment_steps"] += int(g)
         want["delivered"] = [x + y for x, y in
                              zip(want["delivered"], crossings(paths))]
         programs.append(eng.walk_cache_size())
@@ -97,12 +99,27 @@ for sched in ("bulk", "overlap"):
                              relay_overlap=overlap, seed=11)
     low = (1 << 30) - 1
     eng2._relay_tally = jax.device_put(
-        jnp.zeros((2, 2 + S), jnp.int32).at[1].set(low),
+        jnp.zeros((2, 3 + S), jnp.int32).at[1].set(low),
         NamedSharding(mesh, P()))
     paths = eng2.walk(starts, key=jax.random.key(100))
     rep["carry"] = {"stats": eng2.relay_stats(), "low": low,
                     "numpy": crossings(paths)}
     out[sched] = rep
+# the pallas segment kernel's own step counts, on the overlapped relay
+peng = DynamicWalkEngine(state(), cfg, params, mesh=mesh, seed=11,
+                         backend="pallas")
+prelay = jax.jit(make_relay(get_backend("pallas"), cfg, params, mesh,
+                            overlap=True, counters=True))
+per = []
+for b in range(2):
+    key = jax.random.key(200 + b)
+    peng.walk(jnp.roll(starts, 3 * b), key=key)
+    _, r, _, _, g = prelay(state(), jnp.roll(starts, 3 * b),
+                           seed_from_key(key))
+    per.append([int(r), int(g)])
+Wl = slot_count(W, S)
+out["pallas"] = {"stats": peng.relay_stats(), "per_batch": per,
+                 "tiles": -(-Wl // min(256, Wl))}
 try:
     DynamicWalkEngine(state(), cfg, params).relay_stats()
     out["single"] = "no error"
@@ -153,6 +170,7 @@ def test_relay_stats_sums_the_walks(report, schedule):
     assert stats["batches"] == 3
     assert stats["rounds"] == want["rounds"]
     assert stats["overflow"] == want["overflow"]
+    assert stats["segment_steps"] == want["segment_steps"]
     assert stats["delivered_per_shard"] == want["delivered"]
     assert stats["delivered"] == sum(want["delivered"])
     assert got["programs"][-1] == got["programs"][-2]
@@ -166,6 +184,31 @@ def test_relay_stats_carry_past_one_limb(report, schedule):
     assert got["stats"]["delivered"] == sum(per)
     assert got["stats"]["rounds"] > got["low"]
     assert got["stats"]["overflow"] >= got["low"]
+    assert got["stats"]["segment_steps"] > got["low"]
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_relay_stats_segment_steps(report, backend):
+    """``segment_steps`` sums each walk's segment-kernel loop steps, and
+    is at most ``rounds × shards × tiles × L``: the reference scan runs
+    every step of its one tile; the kernel's tiles run only the steps
+    their walkers occupy, so it falls short."""
+    if backend == "reference":
+        got = report["overlap"]["engine"]
+        stats, want = got["stats"], got["want"]["segment_steps"]
+        tiles = 1
+    else:
+        got = report["pallas"]
+        stats, tiles = got["stats"], got["tiles"]
+        want = sum(g for _, g in got["per_batch"])
+        assert stats["rounds"] == sum(r for r, _ in got["per_batch"])
+    assert stats["segment_steps"] == want
+    bound = stats["rounds"] * 4 * tiles * 8
+    assert 0 < stats["segment_steps"] <= bound
+    if backend == "reference":
+        assert stats["segment_steps"] == bound
+    else:
+        assert stats["segment_steps"] < bound
 
 
 def test_relay_stats_needs_a_mesh(report):
