@@ -1,8 +1,8 @@
-"""Assigned architectures (+ the paper's own bingo-walk workload).
+"""The LM zoo's architectures; the walk engine uses none of them.
 
 ``get_config(arch)`` returns the full published configuration;
 ``smoke_config(arch)`` a reduced same-family config for CPU tests;
-``cells(arch)`` the (shape, run/skip) matrix for the dry-run.
+``cells(arch)`` the (shape, run/skip) matrix.
 """
 
 from repro.configs.registry import (ARCHS, CELLS, cells, get_config,

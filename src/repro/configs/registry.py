@@ -1,4 +1,4 @@
-"""Architecture registry + the 40-cell (arch × shape) dry-run matrix."""
+"""Architecture registry + the 40-cell (arch × shape) matrix."""
 
 from __future__ import annotations
 

@@ -1,10 +1,9 @@
 """Pluggable engine backends — one interface, two implementations.
 
 Every layer that touches the BINGO sampling space — drawing a sample
-(the walk scan, node2vec proposals, the distributed walk cell,
-benchmarks, serving) or *mutating* it (batched §5.2 update rounds) —
-goes through an ``EngineBackend`` looked up from ``cfg.backend``
-(DESIGN.md §7/§9):
+(the walk scan, node2vec proposals, the relay's segments, serving) or
+*mutating* it (batched §5.2 update rounds) — goes through an
+``EngineBackend`` looked up from ``cfg.backend`` (DESIGN.md §7/§9):
 
   * ``"reference"`` — the pure-jnp engine (``core/sampler.py`` sampling,
     ``core/updates.py`` updates): alias pick + materialized-group /
@@ -50,7 +49,8 @@ frontier record when it samples a remote (``-(g+2)``-encoded)
 neighbor.  The reference implementation is the windowed jnp scan
 (``kernels/ref.py:walk_segment_ref``), the pallas one the megakernel's
 ``segment=True`` entry — bit-exact against each other, which is what
-lets ``launch/walk_cell.py:walk_relay`` stitch cross-shard whole walks
+lets the relay (``distributed/relay.py:make_relay``, which
+``DynamicWalkEngine(mesh=...)`` runs) stitch cross-shard whole walks
 that are bit-identical to the single-shard walk.
 
 ``SamplerBackend`` remains as an alias of ``EngineBackend`` for callers

@@ -18,8 +18,8 @@ adjacency so that comparisons isolate the *sampling-space* cost:
     full neighbor row: O(d) work *per sample*, zero update cost.
 
 Complexity counters (`*_ops`) return the abstract work the complexity table
-(paper Table 1) predicts, so `benchmarks/bench_complexity.py` can plot
-ops-vs-degree without trusting CPU wall-clock noise.
+(paper Table 1) predicts, as exact counts rather than wall-clock times.
+Only `tests/test_baselines.py` reaches this module.
 """
 
 from __future__ import annotations

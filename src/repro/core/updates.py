@@ -444,7 +444,7 @@ def make_updater(cfg: BingoConfig, backend: Optional[str] = None,
     (``donate_argnums=0``) and threaded through, so XLA aliases the full
     ``BingoState`` buffers input→output and repeated update rounds never
     copy the tables — callers rebind ``st, stats = run(st, ...)``
-    (``serve/dynwalk.py``, ``launch/train.py``, benchmarks).  The round
+    (``serve/dynwalk.py``).  The round
     is applied through the ``EngineBackend`` named by ``backend``
     (default ``cfg.backend``): the jnp pipeline on the reference
     backend, one update-megakernel launch on pallas.

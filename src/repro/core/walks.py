@@ -32,8 +32,7 @@ exact masked-ITS lane pass whenever the O(1) happy path cannot realize
 Eq. 2 alone — the decimal group in fp mode, and rejected
 digit-acceptance proposals for radix bases > 2 — so the sampled
 distribution is identical across backends in every mode.  Pass
-``backend=`` (and/or ``whole_walk=False``) explicitly to override
-``cfg.backend`` for one call (benchmarks comparing the paths do this).
+``backend=`` explicitly to override ``cfg.backend`` for one call.
 """
 
 from __future__ import annotations
@@ -151,11 +150,11 @@ def scan_walk(bk, state: BingoState, cfg: BingoConfig, starts, key,
 
     The reference whole-walk implementation (every step gathers rows,
     launches one backend sample, and round-trips walker state through
-    XLA) and the only path for node2vec.  Production deepwalk/ppr/simple
-    normally go whole-walk instead — ``random_walk`` dispatches to
+    XLA) and the only path for node2vec.  deepwalk/ppr/simple go
+    whole-walk instead — ``random_walk`` dispatches to
     ``bk.sample_walk`` (the pallas megakernel, DESIGN.md §8) when the
-    backend has it; benchmarks call ``scan_walk`` directly to measure
-    the per-step path side by side.
+    backend has it; tests call ``scan_walk`` directly for the per-step
+    path.
     """
     B = starts.shape[0]
     alive0 = state.deg[starts] > 0
@@ -188,7 +187,7 @@ def scan_walk(bk, state: BingoState, cfg: BingoConfig, starts, key,
 
 def random_walk(state: BingoState, cfg: BingoConfig, starts, key,
                 params: WalkParams, backend: Optional[str] = None,
-                whole_walk: Optional[bool] = None, uniforms=None):
+                uniforms=None):
     """Run a batch of walks; returns ``(B, length + 1)`` int32 paths.
 
     Column 0 holds the start vertices; terminated walkers pad with -1.
@@ -201,9 +200,8 @@ def random_walk(state: BingoState, cfg: BingoConfig, starts, key,
     backend that is one persistent megakernel launch for all L steps
     (``kernels/walk_fused.py``, DESIGN.md §8) instead of L per-step
     launches.  node2vec always takes the per-step ``scan_walk`` path
-    (its Eq. 1 rejection needs the previous hop's rows).  Force with
-    ``whole_walk=True`` (raises if the backend can't) or pin the
-    per-step path with ``whole_walk=False`` (benchmark comparisons).
+    (its Eq. 1 rejection needs the previous hop's rows), as does every
+    kind on a backend without ``sample_walk``.
 
     ``uniforms`` (L, B, 6) float32 pins the exact per-(walker, step)
     uniform stream (DESIGN.md §10): both builtin backends then draw
@@ -214,16 +212,13 @@ def random_walk(state: BingoState, cfg: BingoConfig, starts, key,
     """
     bk = get_backend(cfg.backend if backend is None else backend)
     can_whole = hasattr(bk, "sample_walk")
-    if whole_walk is True and not can_whole:
-        raise ValueError(
-            f"backend {bk.name!r} has no sample_walk whole-walk support")
     if uniforms is not None:
-        if params.kind == "node2vec" or whole_walk is False or not can_whole:
+        if params.kind == "node2vec" or not can_whole:
             raise ValueError(
                 "fed uniforms require the whole-walk path "
                 "(deepwalk/ppr/simple through sample_walk)")
         return bk.sample_walk(state, cfg, starts, key, params, u=uniforms)
-    if whole_walk is not False and can_whole and params.kind != "node2vec":
+    if can_whole and params.kind != "node2vec":
         return bk.sample_walk(state, cfg, starts, key, params)
     return scan_walk(bk, state, cfg, starts, key, params)
 
@@ -251,19 +246,18 @@ def ppr(state, cfg, starts, key, max_length: int = 400,
 
 
 def make_walker(state: BingoState, cfg: BingoConfig, params: WalkParams,
-                backend: Optional[str] = None,
-                whole_walk: Optional[bool] = None):
-    """Jitted walk closure (cfg/params/backend static) for benchmarks.
+                backend: Optional[str] = None):
+    """Jitted walk closure (cfg/params/backend static): the one-device
+    walk program of ``DynamicWalkEngine``.
 
     Returns ``run(st, starts, key) -> (st, path)``: the state is donated
     (``donate_argnums=0``) and threaded through unchanged, so XLA aliases
     the full ``BingoState`` buffers input→output and repeated walk calls
-    never copy them — callers rebind ``st, path = run(st, starts, key)``
-    (``benchmarks/common.py:walk_rate``).
+    never copy them — callers rebind ``st, path = run(st, starts, key)``.
     """
     # named ``engine_walk`` in traces, like the relay walk on a mesh
     @functools.partial(jax.jit, donate_argnums=0)
     def engine_walk(st, starts, key):
         return st, random_walk(st, cfg, starts, key, params,
-                               backend=backend, whole_walk=whole_walk)
+                               backend=backend)
     return engine_walk

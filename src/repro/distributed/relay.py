@@ -323,8 +323,8 @@ def relay_local(bk, lcfg, params, state, walkers, seed, u=None, *,
     re-enqueues observed (both replicated scalars).  With
     ``diagnostics=True`` a fourth replicated scalar is appended: the
     peak number of slots in use on any shard in any round (resident
-    walkers + pinned path rows) — the allocator-pressure signal
-    benchmarks record.
+    walkers + pinned path rows) — the allocator-pressure signal the
+    chaos harness and the compaction tests read.
 
     Fault-injection hooks (DESIGN.md §11 — ``distributed/chaos.py``):
     ``exchange_fn(payload, cap=, r=, channel=)`` replaces the mailbox
@@ -671,10 +671,10 @@ def make_relay(bk, cfg, params, mesh, *, mailbox_cap: int | None = None,
     ``exchange_fn``/``census`` thread to ``relay_local`` —
     the chaos harness (``distributed/chaos.py``) swaps the mailbox
     all_to_all and reads the (distinct-finished, pending-at-exit,
-    faults) census outputs it appends.  Used by the ``walk_relay`` /
-    ``walk_relay_2d`` launch cells, the sharded ``DynamicWalkEngine``,
-    benchmarks and tests, so the divisibility validation and spec
-    plumbing live in exactly one place.
+    faults) census outputs it appends.  Used by the sharded
+    ``DynamicWalkEngine`` (``serve/dynwalk.py``), the chaos harness and
+    tests, so the divisibility validation and spec plumbing live in
+    exactly one place.
     """
 
     axes = tuple(mesh.axis_names)

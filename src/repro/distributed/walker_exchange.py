@@ -19,8 +19,7 @@ records** ``(home-tag, wid, slot, path…)`` — a finished segment's
 columns routed to the walker's *home* shard (the tag is
 ``route_tag(home_shard, shard_size)``, a vertex the home shard owns),
 with the sender's slot index riding along so overflow re-pins to the
-slot it came from.  The per-step engine ships ``(vertex, walker-id)``
-so hops keep their identity across shards.  Mailbox overflow is
+slot it came from.  Mailbox overflow is
 *never* a silent drop: entries beyond a destination's capacity are
 returned to the sender (``leftover``) with an overflow count, and the
 relay re-enqueues them next round — conservation is exact
@@ -42,10 +41,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
-__all__ = ["exchange_walkers", "make_walk_step", "merge_into_free",
-           "route_tag"]
+__all__ = ["exchange_walkers", "merge_into_free", "route_tag"]
 
 
 def merge_into_free(buf, rows, mask):
@@ -133,34 +130,3 @@ def exchange_walkers(payload, shard_size: int, num_shards: int,
         return arrived[:, 0], leftover[:, 0], overflow
     return arrived, leftover, overflow
 
-
-def make_walk_step(sample_local, shard_size: int, num_shards: int,
-                   mesh, axis: str = "data"):
-    """Build a shard_mapped distributed walk step that keeps identity.
-
-    ``sample_local(vertices_local, key) -> next_global_vertex`` samples
-    the next hop for walkers whose *current* vertex lives on this shard
-    (callers close over the vertex-sharded BingoState).  The step state
-    is (Wl, 2) int32 ``[global vertex, walker id]`` rows (-1 rows are
-    empty): the id field rides the mailbox with the vertex, so a hop
-    arriving on another shard still knows *which* walker it advances —
-    the per-step twin of the relay's ``(vertex, step, wid)`` payload.
-    Mailbox leftovers are returned alongside so callers can re-enqueue
-    (a bare step has no next round to retry in).
-    """
-    def step(walkers, key):
-        nxt = sample_local(walkers[:, 0], key)
-        live = (walkers[:, 0] >= 0) & (nxt >= 0)
-        payload = jnp.stack(
-            [jnp.where(live, nxt, -1), jnp.where(live, walkers[:, 1], -1)],
-            axis=-1)
-        arrived, leftover, overflow = exchange_walkers(
-            payload, shard_size, num_shards, axis)
-        return arrived, leftover, overflow
-
-    return jax.shard_map(
-        step, mesh=mesh,
-        in_specs=(P(axis), P()),
-        out_specs=(P(axis), P(axis), P()),
-        check_vma=False,
-    )

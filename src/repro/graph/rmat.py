@@ -2,8 +2,8 @@
 
 The paper evaluates on five real-world power-law graphs (Amazon … Twitter)
 and cites R-MAT [5] as the reason degree-derived biases follow a power law.
-This container has no internet, so the benchmark datasets are R-MAT graphs
-with matched skew; the *dry-run* exercises production scale separately.
+The tests use R-MAT graphs with matched skew, generated from a seed; the
+chip benchmark's Kronecker graphs are generated in ``chipbench/gen.py``.
 
 Host-side data preparation, so plain numpy: this is the data pipeline's CPU
 stage (the same role the paper's CPU-side batching plays in Fig. 10(a)).

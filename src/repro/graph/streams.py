@@ -73,8 +73,8 @@ def make_update_stream(src: np.ndarray, dst: np.ndarray, w: np.ndarray,
     """Build the paper's update workload from a full edge list.
 
     ``mode``: ``insertion`` | ``deletion`` | ``mixed`` (§6.1 "Dynamic
-    updates").  ``batch_size`` is the paper's BATCHSIZE (100K at full scale;
-    laptop benchmarks shrink it proportionally).
+    updates").  ``batch_size`` is the paper's BATCHSIZE (100K at full
+    scale; tests shrink it with the graph).
 
     Inputs are validated (``validate_edges``): NaN/inf/non-positive
     weights and out-of-range vertex ids (negative; ``>= num_vertices``
@@ -256,9 +256,8 @@ def rounds_on_device(stream: UpdateStream, *, prefetch: int = 2,
 
     ``jax.device_put`` is asynchronous, so keeping ``prefetch`` rounds
     in flight overlaps the numpy→device upload of round r+1..r+prefetch
-    with the consumer's work on round r — update benchmarks measure the
-    update pipeline, not host transfers (the same reason the training
-    input pipeline prefetches batches).  ``coalesce > 1`` concatenates
+    with the consumer's work on round r, so the consumer waits on the
+    update pipeline, not on host transfers.  ``coalesce > 1`` concatenates
     that many consecutive rounds into one larger batch before upload —
     the serving-side lever that trades update latency for the §5.2
     batched-path throughput (``serve/dynwalk.py``).

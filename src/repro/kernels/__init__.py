@@ -19,14 +19,13 @@ Kernels:
     double-buffered VMEM; bit-exact against ``core/updates.py``
     (DESIGN.md §9 — the production batched-update path);
   * ``walk_sample``     — fused hierarchical BINGO sampling, one step per
-    launch (paper §4.1's O(1) sampling claim; node2vec proposals and the
-    distributed per-step exchange cell still run through it);
+    launch (paper §4.1's O(1) sampling claim; node2vec proposals still
+    run through it);
   * ``alias_build``     — batched Vose alias-table construction over the
     K-entry inter-group rows (paper §4.2's O(K) update claim);
   * ``radix_hist``      — Eq. 4 radix histograms W(p_k) for group rebuild;
-  * ``flash_attention`` — blockwise attention for the LM-side 32k-prefill
-    cells (runtime path; dry-run cells use the jnp reference so HLO
-    cost_analysis sees the true FLOPs — see DESIGN.md §6).
+  * ``flash_attention`` — blockwise attention for the LM zoo, which the
+    walk engine does not use (DESIGN.md §4).
 """
 
 from repro.kernels.ops import (alias_build, flash_attention, radix_hist,

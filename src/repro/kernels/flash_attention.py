@@ -10,10 +10,9 @@ is 64–128 for every assigned arch).
 GQA is handled in the BlockSpec index maps — query head h reads KV head
 h // (H / Hkv) — so no repeated KV materialization in HBM.
 
-NOTE (DESIGN.md §6): dry-run/roofline cells lower the jnp reference
-(`ref.attention_ref`) so `cost_analysis()` sees true attention FLOPs;
-this kernel is the runtime path and is validated against the reference in
-interpret mode.
+The kernel is validated against the jnp reference (`ref.attention_ref`)
+in interpret mode.  Part of the LM zoo, which the walk engine does not
+use (DESIGN.md §4).
 """
 
 from __future__ import annotations

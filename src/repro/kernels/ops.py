@@ -2,8 +2,8 @@
 
 On a TPU backend the wrappers dispatch to the compiled kernels; everywhere
 else (this CPU container, unit tests) they run the same kernel bodies in
-interpret mode.  ``force_ref=True`` routes to the pure-jnp oracle — the
-dry-run/roofline path uses it so HLO cost analysis sees real FLOPs.
+interpret mode.  ``force_ref=True`` routes to the pure-jnp oracle on any
+platform (``chip_smoke.py`` checks the compiled kernels against it).
 """
 
 from __future__ import annotations
@@ -147,8 +147,7 @@ def update_fused(state, cfg, is_insert, u, v, w, active=None, *,
     The oracle is ``core/updates.py:batched_update`` itself — the fused
     path must (and ``tests/test_update_fused.py`` asserts it does)
     produce a bit-identical ``BingoState`` and ``UpdateStats``.
-    ``force_ref=True`` routes to it directly (dry-run/roofline cells,
-    where HLO cost analysis needs real FLOPs).
+    ``force_ref=True`` routes to it directly.
     """
     if force_ref:
         from repro.core.updates import batched_update

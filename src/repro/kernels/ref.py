@@ -151,9 +151,8 @@ def walk_fused_ref(prob, alias, bias, nbr, deg, frac, starts, u=None, *,
     uniforms are the counter-based ``(seed, walker, t)`` hash stream
     (``hash_uniforms_ref``) — bit-identical to what the megakernel
     draws in hash mode, so kernel == oracle holds on both PRNG paths.
-    Also the roofline/cost-analysis stand-in
-    (``ops.walk_fused(force_ref=True)``) since Pallas bodies are opaque
-    to HLO cost analysis.  Returns the (B, L+1) int32 path.
+    ``ops.walk_fused(force_ref=True)`` runs it on any platform.  Returns
+    the (B, L+1) int32 path.
     """
     B = starts.shape[0]
     if u is None:
@@ -296,7 +295,7 @@ def attention_ref_chunked(q, k, v, *, causal=True, window=0, scale=None,
     """Query-chunked attention for long prefill: scans over q blocks so at
     most a (B, H, q_chunk, T) logits tile is live — the jnp stand-in for
     the Pallas flash kernel's memory profile (its FLOPs live in a scan
-    body; specs.attn_flops_correction re-multiplies them for §Roofline).
+    body).
     """
     B, H, S, D = q.shape
     qc = min(q_chunk, S)

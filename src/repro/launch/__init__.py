@@ -1,1 +1,1 @@
-"""Launch layer: production mesh, dry-run, roofline, train/serve drivers."""
+"""Program start-up: the persistent compile cache (``compile_cache``)."""

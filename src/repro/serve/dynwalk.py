@@ -22,9 +22,8 @@ dispatched through the configured ``EngineBackend`` (DESIGN.md §9):
     coalesce several low-latency rounds into one §5.2 batched round —
     the latency/throughput lever.
 
-This replaces the per-callsite ``jax.jit(batched_update)`` wrappers the
-launch/ layer used to carry: "mutate graph, then walk" is one engine
-object, and the state buffers are aliased across the whole session.
+"Mutate graph, then walk" is one engine object, and the state buffers
+are aliased across the whole session.
 
 **Sharded mode** (DESIGN.md §10): pass ``mesh=`` and the engine serves
 the same surface off a vertex-partitioned state (§9.1).  Updates are
@@ -125,8 +124,7 @@ class DynamicWalkEngine:
 
     def __init__(self, state: BingoState, cfg: BingoConfig,
                  params: WalkParams = WalkParams(), *,
-                 backend: Optional[str] = None,
-                 whole_walk: Optional[bool] = None, seed: int = 0,
+                 backend: Optional[str] = None, seed: int = 0,
                  mesh=None, mailbox_cap: Optional[int] = None,
                  guard=None, walk_buckets=None, defer_guard: bool = False,
                  walker_axes=(), relay_overlap: bool = True):
@@ -134,7 +132,6 @@ class DynamicWalkEngine:
         self.params = params
         self._state = state
         self._backend = backend
-        self._whole_walk = whole_walk
         self._mesh = mesh
         self._mailbox_cap = mailbox_cap
         self._relay_overlap = relay_overlap
@@ -239,8 +236,7 @@ class DynamicWalkEngine:
                 update = make_updater(tcfg, backend=self._backend,
                                       with_active=True)
                 walk = make_walker(self._state, tcfg, self.params,
-                                   backend=self._backend,
-                                   whole_walk=self._whole_walk)
+                                   backend=self._backend)
             else:
                 update, walk = self._sharded_programs(tcfg)
             self._tier_progs[t] = (update, walk)

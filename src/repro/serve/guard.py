@@ -86,8 +86,8 @@ def valid_lanes(cfg: BingoConfig, u, v):
 
     The one guard check shard-local pipelines cannot perform: their
     ``cfg.num_vertices`` is the shard size while neighbor ids stay
-    global.  Used by the sharded update cell (``launch/walk_cell.py``)
-    and the classifier below.
+    global.  Used by the classifier below, which checks a sharded
+    engine's lanes against the global ``cfg``.
     """
     V = cfg.num_vertices
     return (u >= 0) & (u < V) & (v >= 0) & (v < V)

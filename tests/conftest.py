@@ -1,5 +1,6 @@
-"""Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches must
-see the single real CPU device; only launch/dryrun.py forces 512 hosts."""
+"""Shared fixtures. NOTE: no XLA_FLAGS here — tests see the single real
+CPU device unless the caller sets ``--xla_force_host_platform_device_count``
+(the multi-device tests skip without it)."""
 
 import gc
 

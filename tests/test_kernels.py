@@ -521,9 +521,8 @@ def test_whole_walk_is_one_pallas_call():
     assert _count_prims(fused, "pallas_call", inside_loops_only=True) == 0
 
     step = jax.make_jaxpr(
-        lambda s, k: walks.random_walk(st, cfg, s, k, params,
-                                       backend="pallas",
-                                       whole_walk=False))(starts, key)
+        lambda s, k: walks.scan_walk(get_backend("pallas"), st, cfg, s, k,
+                                     params))(starts, key)
     scans = [e for e in step.jaxpr.eqns if e.primitive.name == "scan"]
     assert len(scans) == 1 and scans[0].params["length"] == 80
     assert _count_prims(step, "pallas_call", inside_loops_only=True) == 1

@@ -380,8 +380,7 @@ def test_padded_cohort_bit_equal_on_counter_prng_path():
 
     def run(buckets):
         eng = DynamicWalkEngine(from_edges(cfg, src, dst, w), cfg,
-                                params, seed=11, whole_walk=True,
-                                walk_buckets=buckets)
+                                params, seed=11, walk_buckets=buckets)
         return np.asarray(eng.walk(starts))
 
     np.testing.assert_array_equal(run(None), run((8, 16)))

@@ -253,9 +253,9 @@ def _lowered(program):
         low = make_updater(cfg, with_active=True).lower(
             st, *lanes, jnp.ones(B, bool))
     elif program == "engine_walk":
-        low = make_walker(st, cfg, WalkParams(kind="deepwalk", length=4),
-                          whole_walk=True).lower(
-            st, jnp.arange(8, dtype=jnp.int32), jax.random.key(0))
+        low = make_walker(st, cfg, WalkParams(kind="deepwalk", length=4)
+                          ).lower(st, jnp.arange(8, dtype=jnp.int32),
+                                  jax.random.key(0))
     else:
         low = make_classifier(cfg).lower(st, *lanes)
     return low.as_text(debug_info=True)

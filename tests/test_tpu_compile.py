@@ -1,4 +1,4 @@
-"""The main-path kernels compile for a TPU v5e at production widths.
+"""The main-path kernels and the relay compile for a TPU v5e.
 
 Interpret mode cannot see what the chip's compiler refuses: lane slices
 off the 128-lane tiling, primitives Mosaic does not lower (``cumsum``,
@@ -8,6 +8,11 @@ needed — at C=1024, bias_bits=16, L=80 and a 256-walker tile, and check
 that the program holds a Mosaic kernel and fits the chip's memory.  The
 tables are cut to a few thousand rows: V sets only the HBM operand size,
 not what the kernel does.
+
+``test_relay_compiles`` compiles the engine's whole relay program at the
+four-chip cell's full size (``chipbench/configs/g500-s19x4.json``) over
+the described ``v5e:2x2``: the segment kernel, the all_to_all exchanges,
+and one chip's share of the state within its HBM.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test collection imports this file
@@ -38,12 +43,13 @@ from repro.kernels.walk_sample import (walk_sample_pallas,
 C, BIAS_BITS, L, BT = 1024, 16, 80, 256
 V = 4096                    # table rows handed to the kernels
 HBM_BYTES = 16 * 2**30      # one v5e chip
+ROWS_PER_CHIP = 131072      # g500-s19x4: 2^19 vertex rows over 4 chips
+RELAY_WALKERS = 16384       # the corpus mix's batch
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -52,19 +58,25 @@ def one_chip():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", cache)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args, kernel):
-    """Compile ``fn`` for the chip; its Mosaic kernel is the custom call
-    named ``kernel`` (the ``pallas_call``'s ``name``, which traces
-    show)."""
-    compiled = jax.jit(fn).lower(*args).compile()
+def _compile(fn, *args, kernel, **jit):
+    """Compile ``fn`` (``jax.jit(fn, **jit)``) for the chip; its Mosaic
+    kernel is the custom call named ``kernel`` (the ``pallas_call``'s
+    ``name``, which traces show).  Returns the compiled HLO text."""
+    compiled = jax.jit(fn, **jit).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert re.search(rf"%{kernel}(\.\d+)? = [^\n]*custom-call", text), kernel
@@ -72,6 +84,7 @@ def _compile(fn, *args, kernel):
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert used < HBM_BYTES
+    return text
 
 
 def _tables(s, base_log2, fp):
@@ -161,6 +174,40 @@ def test_update_fused_compiles(one_chip, capacity, adaptive, fp):
         jnp.float32 if fp else jnp.int32)]
     _compile(lambda st, i, u, v, w: update_fused_pallas(st, cfg, i, u, v, w),
              state, *lanes, kernel="update_round")
+
+
+@pytest.mark.parametrize("kind", ["deepwalk", "ppr", "simple"])
+def test_relay_compiles(v5e, monkeypatch, kind):
+    """The sharded engine's relay (``make_relay`` as
+    ``DynamicWalkEngine(mesh=...)`` builds it: overlapped rounds, device
+    counters) for g500-s19x4 — 131,072 rows of C=1024 a chip, 16-bit
+    integer biases, 16,384 walkers of 80 steps — compiles over the four
+    described chips with the segment kernel, the walker exchange's
+    all_to_all, and one chip's share within 16 GiB.  The state is donated
+    and handed back, as the engine's ``engine_walk`` does, so the whole
+    state counts, not only the tables the relay reads."""
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+    from repro.core.backend import get_backend
+    from repro.core.walks import WalkParams
+    from repro.distributed.relay import make_relay
+    from repro.kernels import ops
+    # the ops wrappers would pick interpret mode on this host
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    mesh = Mesh(np.array(v5e.devices), ("v",), axis_types=(AxisType.Auto,))
+    cfg = BingoConfig(num_vertices=len(v5e.devices) * ROWS_PER_CHIP,
+                      capacity=C, bias_bits=BIAS_BITS, fp_bias=False)
+    params = WalkParams(kind, L, stop_prob=1 / 80 if kind == "ppr" else 0.0)
+    relay = make_relay(get_backend("pallas"), cfg, params, mesh,
+                       overlap=True, counters=True)
+    state = jax.tree.map(
+        lambda x: _spec(NamedSharding(mesh, P("v")), x.shape, x.dtype),
+        jax.eval_shape(lambda: empty_state(cfg)))
+    whole = NamedSharding(mesh, P())
+    text = _compile(lambda st, w, s: (st, relay(st, w, s)), state,
+                    _spec(whole, (RELAY_WALKERS,), jnp.int32),
+                    _spec(whole, (1,), jnp.int32), kernel="walk_segment",
+                    donate_argnums=0)
+    assert "all-to-all" in text
 
 
 @pytest.mark.parametrize("V_,adaptive,fp", [

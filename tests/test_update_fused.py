@@ -206,9 +206,9 @@ def test_active_mask_and_engine_protocol():
 
 
 def test_make_updater_threads_donated_state():
-    """The shared updater closure (launch/train, serve/dynwalk,
-    benchmarks): donated state threads through repeated rounds and ends
-    bit-identical to the undonated reference chain."""
+    """The shared updater closure (serve/dynwalk): donated state threads
+    through repeated rounds and ends bit-identical to the undonated
+    reference chain."""
     V, C = 10, 12
     cfg = BingoConfig(num_vertices=V, capacity=C, bias_bits=4)
     src, dst, w = random_graph(V, C, max_bias=15, seed=6, density=0.4)
